@@ -41,6 +41,35 @@ _lock = named_lock("native.build_lock")
 _lib: ctypes.CDLL | None = None
 _lib_tried = False
 
+# Which decoder really served: process-wide counts behind the /stats
+# "decode" block. A missing compiler or libjpeg turns every JPEG over to
+# PIL with one log line; ``pil_jpeg_decodes_total`` is where that shows.
+_count_lock = named_lock("native.counters_lock")
+_decodes = {"native_decodes_total": 0, "pil_decodes_total": 0,
+            "pil_jpeg_decodes_total": 0}
+
+
+def count_pil_decode(data: bytes) -> None:
+    """One successful PIL decode (ops.image.decode_image calls this)."""
+    with _count_lock:
+        _decodes["pil_decodes_total"] += 1
+        if data[:2] == b"\xff\xd8":
+            _decodes["pil_jpeg_decodes_total"] += 1
+
+
+def _count_native_decode() -> None:
+    with _count_lock:
+        _decodes["native_decodes_total"] += 1
+
+
+def stats() -> dict:
+    """The /stats "decode" block: is the extension loaded, and how many
+    images each decoder has served in this process."""
+    with _count_lock:
+        out = dict(_decodes)
+    out["native"] = _lib is not None
+    return out
+
 
 def _build(src: Path, out: Path) -> None:
     """Compile to a temp path and atomically rename into place, so
@@ -228,6 +257,7 @@ def decode_packed_into(
     )
     if rc != 0:
         return None
+    _count_native_decode()
     return oh.value, ow.value
 
 
@@ -262,6 +292,7 @@ def decode_into_row(
     )
     if rc != 0:
         return None
+    _count_native_decode()
     return oh.value, ow.value
 
 

@@ -24,15 +24,11 @@ The fix is a ``jax.custom_vjp``:
 
 from __future__ import annotations
 
-import os
-import warnings
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ..utils.locks import named_lock
 
 
 def _conv(x, kernel, strides, padding):
@@ -120,13 +116,11 @@ depthwise_conv2d.defvjp(_fwd, _bwd)
 #     batch-8 28×28×192 layer — and depthwise layers dominate MobileNetV2
 #     CPU serve time.
 #   * "pallas": the Mosaic kernel in ops/pallas_depthwise.py (stride-1
-#     only) — one VMEM-resident pass per image on TPU.
-# "auto" trial-compiles the pallas kernel once per process and falls back
-# to "xla" with a warning if Mosaic rejects it (same contract as the
-# pallas preprocess kernel).
-
-_impl_cache: dict[str, bool] = {}
-_impl_lock = named_lock("ops.kernel_cache")
+#     only) — one VMEM-resident pass per row tile on TPU.
+# "auto" is decided by platform and shape alone: the pallas kernel for a
+# stride-1 layer on a TPU, the XLA path everywhere else. There is no trial
+# compile and no fallback — if Mosaic refuses the kernel at a shape the
+# engine serves, the engine's warmup raises with the compiler's message.
 
 
 def _shift_mac(x, kernel_c, strides, padding):
@@ -159,51 +153,23 @@ def _shift_mac(x, kernel_c, strides, padding):
     return acc
 
 
-def pallas_fused_ok() -> bool:
-    """Trial-compile the Mosaic fused-dw kernel once per process (tiny
-    probe shapes); cache the verdict. The compile runs OUTSIDE the cache
-    lock — a racing duplicate costs one extra trial, a blocking call under
-    a declared lock is a twdlint finding."""
-    with _impl_lock:
-        hit = _impl_cache.get("pallas_dw")
-    if hit is not None:
-        return hit
-    ok = False
-    if jax.default_backend() == "tpu" and os.environ.get("TWD_NO_PALLAS") != "1":
-        try:
-            from .pallas_depthwise import fused_dw_call
-
-            x = jnp.zeros((1, 10, 10, 8), jnp.float32)
-            k = jnp.zeros((9, 8), jnp.float32)
-            b = jnp.zeros((1, 8), jnp.float32)
-            jax.block_until_ready(fused_dw_call(x, k, b, kh=3, kw=3, relu6=True))
-            ok = True
-        except Exception as e:  # Mosaic rejection → serve on the XLA path
-            warnings.warn(
-                f"pallas fused-depthwise unavailable ({type(e).__name__}: {e}); "
-                "falling back to the XLA shift-MAC path", RuntimeWarning)
-    with _impl_lock:
-        _impl_cache["pallas_dw"] = ok
-    return ok
-
-
 def fused_depthwise_bn(x, kernel, scale, bias, strides=(1, 1), padding="SAME",
                        relu6=True, impl="auto"):
     """Fused dwconv(+BN+relu6): x [B,H,W,C] ⊛ kernel [kh,kw,1,C], then the
     folded per-channel affine (``scale``/``bias``, shape [C]) and an
     optional relu6 clamp — one op, no intermediate activations.
 
-    ``impl``: "auto" (pallas on TPU when it trial-compiles, else XLA),
+    ``impl``: "auto" (pallas for a stride-1 layer on a TPU, else XLA),
     "xla", "pallas", or "pallas_interpret" (tests: Mosaic semantics on CPU).
     """
     kh, kw = kernel.shape[:2]
     acc = jnp.promote_types(x.dtype, jnp.float32)
     kf = (kernel[:, :, 0, :] * scale).astype(acc)  # BN scale folds into k
-    use_pallas = (
+    use_pallas = strides == (1, 1) and (
         impl in ("pallas", "pallas_interpret")
-        or (impl == "auto" and strides == (1, 1) and pallas_fused_ok())
+        or (impl == "auto" and jax.default_backend() == "tpu")
     )
-    if use_pallas and strides == (1, 1):
+    if use_pallas:
         from .pallas_depthwise import fused_dw_call
 
         if isinstance(padding, str):

@@ -1315,7 +1315,7 @@ class Batcher:
         s = canvas_side(key)
         px_real = sum(l.hw[0] * l.hw[1] for l in ready if l.hw)
         if slab is not None and getattr(slab, "is_ragged", False):
-            px_dispatched = slab.rows_shipped() * s * s
+            px_dispatched = slab.rows_shipped(bucket) * s * s
         else:
             px_dispatched = bucket * s * s
         with self._cond:

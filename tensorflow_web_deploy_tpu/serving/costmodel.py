@@ -27,7 +27,7 @@ Three layers:
 
 3. **Backend peaks** (:func:`backend_peak`): on TPU the per-chip dense
    bf16 peak and HBM bandwidth come from the spec-sheet table keyed by
-   PJRT ``device_kind`` (same table bench.py has always used for MFU).
+   PJRT ``device_kind`` (:data:`DEVICE_PEAKS`; an unlisted kind raises).
    On the CPU dev mesh there is no spec sheet, so the peak is CALIBRATED
    ONCE per process: a jitted f32 matmul measures achievable FLOP/s and a
    jitted streaming add measures achievable bytes/s, cached under
@@ -47,34 +47,29 @@ import time
 
 from ..utils.locks import named_lock
 
-# Peak dense bf16 TFLOP/s and HBM GB/s per chip, keyed by PJRT device_kind
-# prefix (public spec-sheet numbers; longest prefix wins). bench.py imports
-# this table — one source of truth for MFU denominators.
-PEAK_BF16_TFLOPS = {
-    "TPU v2": 46.0,
-    "TPU v3": 123.0,
-    "TPU v4": 275.0,
-    "TPU v5 lite": 197.0,  # v5e
-    "TPU v5e": 197.0,
-    "TPU v5p": 459.0,
-    "TPU v5": 459.0,
-    "TPU v6 lite": 918.0,  # v6e / Trillium
-    "TPU v6e": 918.0,
-    "TPU v7": 2307.0,
+# Peak dense bf16 TFLOP/s and HBM GB/s of one chip, keyed by the exact
+# PJRT ``device_kind`` (Google Cloud documentation, system architecture
+# page of each generation; the v5e row is "TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s). The one table every MFU and roofline denominator comes from —
+# bench.py imports it. A TPU that is not listed is an error, not a default.
+DEVICE_PEAKS = {
+    "TPU v4": (275.0, 1228.0),
+    "TPU v5 lite": (197.0, 819.0),  # v5e
+    "TPU v5p": (459.0, 2765.0),
+    "TPU v6 lite": (918.0, 1640.0),  # v6e / Trillium
 }
 
-PEAK_HBM_GBPS = {
-    "TPU v2": 700.0,
-    "TPU v3": 900.0,
-    "TPU v4": 1228.0,
-    "TPU v5 lite": 819.0,
-    "TPU v5e": 819.0,
-    "TPU v5p": 2765.0,
-    "TPU v5": 2765.0,
-    "TPU v6 lite": 1640.0,
-    "TPU v6e": 1640.0,
-    "TPU v7": 7370.0,
-}
+
+def device_peak(device_kind: str) -> tuple[float, float]:
+    """(peak bf16 FLOP/s, peak HBM bytes/s) of one ``device_kind`` chip."""
+    try:
+        tf, gb = DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s and bytes/s known for device_kind "
+            f"{device_kind!r}: add its row to costmodel.DEVICE_PEAKS "
+            f"(known: {sorted(DEVICE_PEAKS)})") from None
+    return tf * 1e12, gb * 1e9
 
 
 def compute_dtype(dtype: str) -> str:
@@ -85,16 +80,6 @@ def compute_dtype(dtype: str) -> str:
     bandwidth ceiling), not FLOPs. So int8 and bf16 share a compute peak;
     only float32 computes at full width."""
     return "float32" if dtype == "float32" else "bfloat16"
-
-
-def _table_lookup(table: dict, device_kind: str):
-    best = None
-    for prefix, peak in table.items():
-        if device_kind.startswith(prefix) and (
-            best is None or len(prefix) > len(best[0])
-        ):
-            best = (prefix, peak)
-    return best[1] if best else None
 
 
 # ------------------------------------------------------------ layer tape
@@ -509,17 +494,14 @@ def backend_peak(dtype: str = "bfloat16") -> dict:
         return cached
     if backend == "tpu":
         kind = jax.devices()[0].device_kind
-        tf = _table_lookup(PEAK_BF16_TFLOPS, kind)
-        gb = _table_lookup(PEAK_HBM_GBPS, kind)
-        if tf and cdtype == "float32":
-            tf = tf / 2.0
+        flops, bw = device_peak(kind)
+        if cdtype == "float32":
+            flops /= 2.0
         peak = {
-            "flops_per_chip": (tf or 0.0) * 1e12,
-            "bytes_per_s_per_chip": (gb or 0.0) * 1e9,
+            "flops_per_chip": flops,
+            "bytes_per_s_per_chip": bw,
             "source": f"tpu-table:{kind}:{cdtype}",
         }
-        if not tf:
-            peak["source"] = f"tpu-unknown:{kind}"
     else:
         host = _calibrate_cpu(cdtype)
         n_dev = len(jax.devices())

@@ -489,10 +489,15 @@ class App:
                 engine = self.engine
                 ok = engine is not None and engine.healthcheck()
                 status = "200 OK" if ok else "503 Service Unavailable"
+                # The device as the engine's own mesh reports it — what
+                # chip_smoke.py prints, without importing jax itself.
+                devs = (list(engine.mesh.devices.flat)
+                        if engine is not None else [None])
                 body = json.dumps({
                     "ok": ok,
-                    "devices": (len(engine.mesh.devices.flatten())
-                                if engine is not None else 0),
+                    "devices": len(devs) if engine is not None else 0,
+                    "platform": getattr(devs[0], "platform", None),
+                    "device_kind": getattr(devs[0], "device_kind", None),
                 }).encode()
                 ctype = "application/json"
             elif path == "/models" and method == "GET":
@@ -608,6 +613,8 @@ class App:
             snap["http"] = self.http_counters.snapshot()
         if hasattr(engine, "staging_stats"):
             snap["staging"] = engine.staging_stats()
+        if hasattr(engine, "device_memory"):
+            snap["device_memory"] = engine.device_memory()
         # Per-stage span aggregates: cumulative count/total_ms per stage
         # (diffable across snapshots — loadgen's stage attribution) plus
         # interpolated p50/p99 from the histogram buckets.
@@ -618,6 +625,11 @@ class App:
         # gauges, plus the batcher's padding-waste fractions — the numbers
         # the bench and profile_serve roofline tables are sourced from.
         snap["economics"] = self._economics()
+        # Which image decoder served: the native extension, or PIL (the
+        # path a missing compiler or libjpeg silently leaves JPEGs on).
+        from .. import native
+
+        snap["decode"] = native.stats()
         # Content-addressed response cache: hit/miss/coalesce counters,
         # live byte/entry gauges, and per-model usage.
         snap["cache"] = self.cache.stats()

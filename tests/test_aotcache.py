@@ -29,6 +29,29 @@ from tensorflow_web_deploy_tpu.serving.engine import InferenceEngine
 from tensorflow_web_deploy_tpu.utils.config import ModelConfig, ServerConfig
 
 
+# _trivial_compiled's jit carries no sharding: it compiles for device 0.
+_DEV0 = jax.devices()[:1]
+
+
+@pytest.fixture(autouse=True)
+def _jax_persistent_cache_off():
+    """On the TPU the AOT cache and JAX's persistent compilation cache
+    compose (chip_smoke.py's restart phase); on XLA:CPU an executable that
+    JAX rebuilt from its own cache re-serializes without its object code,
+    and the AOT entry written from it fails at its first execution in the
+    next process. These tests boot engines twice on one AOT directory on
+    the CPU, so JAX's cache stays off around them — here, in the test, not
+    through an option of the program."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 def _trivial_compiled():
     fn = jax.jit(lambda x: x * 2.0 + 1.0)
     return fn.lower(jax.ShapeDtypeStruct((8,), jnp.float32)).compile()
@@ -55,7 +78,7 @@ def test_roundtrip_trivial_fn(tmp_path):
     compiled = _trivial_compiled()
     assert cache.store(_key(), compiled)
     assert cache.entry_count() == 1
-    exe = cache.load(_key())
+    exe = cache.load(_key(), _DEV0)
     assert exe is not None
     x = jnp.arange(8, dtype=jnp.float32)
     np.testing.assert_array_equal(np.asarray(exe(x)), np.asarray(compiled(x)))
@@ -67,7 +90,7 @@ def test_roundtrip_trivial_fn(tmp_path):
 def test_absent_entry_is_miss_not_corrupt(tmp_path):
     cache = AotCache(str(tmp_path))
     before = aotcache.stats()
-    assert cache.load(_key()) is None
+    assert cache.load(_key(), _DEV0) is None
     d = _stats_delta(before, aotcache.stats())
     assert d["misses_total"] == 1 and d["corrupt_total"] == 0
 
@@ -79,7 +102,7 @@ def test_key_field_change_is_a_different_entry(tmp_path):
     cache.store(_key(), _trivial_compiled())
     before = aotcache.stats()
     for drift in ({"v": 2}, {"device_kind": "TPU v4"}, {"jax": "0.0.1"}):
-        assert cache.load(_key(**drift)) is None
+        assert cache.load(_key(**drift), _DEV0) is None
     d = _stats_delta(before, aotcache.stats())
     assert d["misses_total"] == 3 and d["corrupt_total"] == 0
 
@@ -90,7 +113,7 @@ def test_garbage_file_is_corrupt_and_survivable(tmp_path):
     (path,) = [tmp_path / f for f in os.listdir(tmp_path)]
     path.write_bytes(b"garbage, definitely not an executable")
     before = aotcache.stats()
-    assert cache.load(_key()) is None  # degrade, never raise
+    assert cache.load(_key(), _DEV0) is None  # degrade, never raise
     d = _stats_delta(before, aotcache.stats())
     assert d["corrupt_total"] == 1 and d["misses_total"] == 0
 
@@ -101,7 +124,7 @@ def test_truncated_file_is_corrupt(tmp_path):
     (path,) = [tmp_path / f for f in os.listdir(tmp_path)]
     path.write_bytes(path.read_bytes()[: len(path.read_bytes()) // 2])
     before = aotcache.stats()
-    assert cache.load(_key()) is None
+    assert cache.load(_key(), _DEV0) is None
     assert _stats_delta(before, aotcache.stats())["corrupt_total"] == 1
 
 
@@ -114,10 +137,10 @@ def test_body_key_mismatch_is_corrupt(tmp_path):
     cache.store(key_a, _trivial_compiled())
     shutil.copyfile(cache._path(key_a), cache._path(key_b))
     before = aotcache.stats()
-    assert cache.load(key_b) is None
+    assert cache.load(key_b, _DEV0) is None
     assert _stats_delta(before, aotcache.stats())["corrupt_total"] == 1
     # The honest entry is untouched.
-    assert cache.load(key_a) is not None
+    assert cache.load(key_a, _DEV0) is not None
 
 
 def test_store_is_atomic_no_temp_droppings(tmp_path):
@@ -146,19 +169,84 @@ def test_stats_shape():
         assert k in s
 
 
-def test_persistent_cache_policy_excludes_compilation_cache():
-    """A process that writes the AOT cache must not also enable jax's
-    persistent compilation cache: an executable XLA rebuilt from its own
-    cache re-serializes without its jitted object code on CPU, and the
-    resulting AOT entries deserialize only in the writing process
-    (observed live as warm-boot "Symbols not found" corrupts on exactly
-    the expensive serve executables). server.py routes its choice through
-    pick_persistent_cache — exactly one cache on at a time."""
-    from tensorflow_web_deploy_tpu.utils.env import pick_persistent_cache
+def test_submesh_executable_roundtrips(tmp_path):
+    """An executable compiled for a 2-device sub-mesh of the 8-device test
+    mesh must load back onto exactly those devices and run. (Loaded
+    without ``execution_devices`` it comes back expecting a shard per
+    device of the whole backend and fails at dispatch.) This is the CPU
+    backend's behaviour: a TPU assigns such an executable to devices
+    0..n-1 whatever it is told, so the engine keeps replicas that are
+    sub-meshes of several devices out of the cache (``loadable_on``)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    assert pick_persistent_cache(".jax_cache", "/tmp/aot") is None
-    assert pick_persistent_cache(".jax_cache", None) == ".jax_cache"
-    assert pick_persistent_cache(None, None) is None
+    devices = jax.devices()[2:4]  # not the first two: ids must round-trip
+    sh = NamedSharding(Mesh(np.array(devices), ("data",)), P("data"))
+    fn = jax.jit(lambda x: x * 2.0 + 1.0, in_shardings=sh, out_shardings=sh)
+    compiled = fn.lower(jax.ShapeDtypeStruct((8,), jnp.float32)).compile()
+    cache = AotCache(str(tmp_path))
+    key = _key(device_ids=[d.id for d in devices])
+    assert cache.store(key, compiled)
+    before = aotcache.stats()
+    exe = cache.load(key, devices)
+    assert exe is not None
+    assert _stats_delta(before, aotcache.stats())["corrupt_total"] == 0
+    x = jax.device_put(jnp.arange(8, dtype=jnp.float32), sh)
+    out = exe(x)
+    assert set(out.sharding.device_set) == set(devices)
+    np.testing.assert_array_equal(np.asarray(out), np.arange(8) * 2.0 + 1.0)
+
+
+def test_single_device_executable_loads_onto_its_own_device(tmp_path):
+    """What a ``replicas=N`` engine stores per one-chip replica: compiled
+    for device 3, it must come back assigned to device 3, not device 0."""
+    from jax.sharding import SingleDeviceSharding
+
+    dev = jax.devices()[3]
+    sh = SingleDeviceSharding(dev)
+    fn = jax.jit(lambda x: x * 2.0 + 1.0, in_shardings=sh, out_shardings=sh)
+    compiled = fn.lower(jax.ShapeDtypeStruct((8,), jnp.float32)).compile()
+    cache = AotCache(str(tmp_path))
+    assert aotcache.loadable_on([dev]) and aotcache.loadable_on(jax.devices())
+    assert not aotcache.loadable_on(jax.devices()[2:4])
+    assert cache.store(_key(device_ids=[dev.id]), compiled)
+    exe = cache.load(_key(device_ids=[dev.id]), [dev])
+    out = exe(jax.device_put(jnp.arange(8, dtype=jnp.float32), sh))
+    assert out.sharding.device_set == {dev}
+    np.testing.assert_array_equal(np.asarray(out), np.arange(8) * 2.0 + 1.0)
+
+
+def test_compile_cache_rule(tmp_path):
+    """The one compile-cache rule (utils/env.py): JAX's persistent cache
+    is always on; where JAX_COMPILATION_CACHE_DIR is set the program sets
+    no directory in code (JAX reads the variable itself), and where it is
+    not the cache lives at an absolute path under the checkout, whatever
+    the working directory. Run in fresh interpreters: the rule is about a
+    process's start, and this process must not keep the directory."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys; sys.path.insert(0, %r); import jax; "
+            "from tensorflow_web_deploy_tpu.utils import env; "
+            "before = jax.config.jax_compilation_cache_dir; "
+            "env.enable_compilation_cache(); "
+            "print(repr((before, jax.config.jax_compilation_cache_dir, "
+            "jax.config.jax_enable_compilation_cache)))" % repo)
+
+    def run(extra_env):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        out = subprocess.run([sys.executable, "-c", code], cwd=tmp_path,
+                             env={**env, **extra_env}, capture_output=True,
+                             text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return eval(out.stdout.strip().splitlines()[-1])
+
+    before, after, enabled = run({"JAX_COMPILATION_CACHE_DIR": "/some/dir"})
+    assert before == after == "/some/dir" and enabled  # JAX's own reading
+    before, after, enabled = run({})
+    assert before is None and enabled
+    assert os.path.isabs(after) and after == os.path.join(repo, ".jax_cache")
 
 
 # ------------------------------------------------------------ end-to-end
@@ -363,7 +451,7 @@ def test_aotcache_lock_rides_declared_hierarchy(tmp_path):
         try:
             cache = AotCache(str(tmp_path))
             cache.store(_key(), _trivial_compiled())
-            assert cache.load(_key()) is not None
+            assert cache.load(_key(), _DEV0) is not None
             aotcache.stats(cache)
         finally:
             aotcache._lock = plain

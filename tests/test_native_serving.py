@@ -230,8 +230,8 @@ def _mk_engine(packed, task="classify", wire="rgb"):
 @pytest.mark.parametrize("wire", ["rgb", "yuv420"])
 @pytest.mark.parametrize("task", ["classify", "detect"])
 def test_packed_io_matches_unpacked(rng, task, wire):
-    """packed_io=True (one buffer in, one packed f32 array out — 3 relay
-    round trips instead of 5) must be bit-compatible with the plain path,
+    """packed_io=True (one buffer in, one packed f32 array out — 3
+    host↔device hops instead of 5) must be bit-compatible with the plain path,
     including the uint16 hw trailer decode for non-square valid regions."""
     s = 96 if task == "classify" else 128
     n = 5

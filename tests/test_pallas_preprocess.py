@@ -34,6 +34,40 @@ def test_pallas_matches_xla_yuv_path(rng, mode):
     np.testing.assert_allclose(got, ref, atol=atol)
 
 
+def test_pallas_row_tiled_canvas_matches_xla(rng):
+    """A canvas that is a multiple of 128 streams through the kernel in
+    row tiles (two of 512 rows at 1024), each with its own U and V blocks;
+    the accumulated planes must equal the one-pass XLA resize."""
+    import jax
+
+    from tensorflow_web_deploy_tpu.ops.pallas_preprocess import row_tile
+
+    s = 1024
+    assert row_tile(s) == 512
+    packed = _pack(rng, 2, s)
+    hws = np.array([[s, s], [700, 333]], np.int32)  # second: tile 1 half empty
+    ref = np.asarray(
+        jax.jit(make_preprocess_fn(32, 32, "inception", wire="yuv420",
+                                   resize="matmul"))(packed, hws)
+    )
+    got = np.asarray(preprocess_i420(packed, hws, 32, 32, "inception",
+                                     interpret=True))
+    np.testing.assert_allclose(got, ref, atol=1e-5)
+
+
+def test_pallas_rejects_canvas_that_cannot_fit_vmem():
+    from tensorflow_web_deploy_tpu.ops.pallas_preprocess import row_tile
+    from tensorflow_web_deploy_tpu.utils.config import ModelConfig, ServerConfig
+
+    assert row_tile(96) == 96 and row_tile(2048) == 512
+    with pytest.raises(ValueError, match="multiple of 128"):
+        row_tile(1100)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        ServerConfig(model=ModelConfig(name="m", source="native"),
+                     wire_format="yuv420", resize="pallas",
+                     canvas_buckets=(512, 1100))
+
+
 def test_pallas_rejects_bad_shapes_and_modes(rng):
     packed = _pack(rng, 1, 64)
     hws = np.array([[64, 64]], np.int32)

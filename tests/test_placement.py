@@ -5,7 +5,7 @@ The multichip tests run against the 8-device virtual CPU mesh
 (``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — conftest.py
 sets it before jax initializes for the tier-1 run; tools/check.sh's
 multichip smoke stage runs this file standalone with the flag set
-explicitly, since jax 0.4.37 has no ``jax_num_cpu_devices`` config).
+explicitly).
 
 What must hold, per the mesh-wide-serving acceptance:
 

@@ -28,9 +28,6 @@ def test_profile_serve_cpu(tmp_path):
         # not a regression in this repo.
         pytest.skip("xprof not installed")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    from tensorflow_web_deploy_tpu.utils.env import strip_tpu_plugin_paths
-
-    strip_tpu_plugin_paths(env)
     # Single CPU device: under the conftest's 8-fake-device flag the xprof
     # conversion yields no per-device op rows; the tool's real CPU use is
     # single-device anyway.

@@ -95,6 +95,41 @@ def test_unpack_ragged_reconstructs_padded_canvases(rng):
     np.testing.assert_array_equal(np.asarray(hws), ref_hw)
 
 
+def test_unpack_ragged_tight_arena_end(rng):
+    """The unpack reads each canvas row as one 3s-byte window; the last
+    rows of the last image start less than 3s bytes before the end of an
+    arena with no slack, and a clamped window would shift their pixels."""
+    s, imgs = 32, _mixed_images(rng, 32, n=4)[1:]  # ends on the 17x23 image
+    arena = np.concatenate([im.reshape(-1) for im in imgs])
+    meta = np.zeros((3, 4), np.int32)
+    off = 0
+    for i, im in enumerate(imgs):
+        meta[i] = (off, im.shape[0], im.shape[1], 1)
+        off += im.size
+    assert off == arena.size
+    canvases, hws = unpack_ragged(arena, meta, s)
+    ref_c, ref_hw = _padded(imgs, s)
+    np.testing.assert_array_equal(np.asarray(canvases), ref_c)
+    np.testing.assert_array_equal(np.asarray(hws), ref_hw)
+
+
+def test_rows_shipped_yields_only_warmed_shapes():
+    """The batcher leases top-capacity slabs and dispatch re-buckets: the
+    shipped-rows quantization must follow the DISPATCH bucket, or a batch
+    ships a (bucket, rows) shape that warmup never compiled and the
+    request path pays the compile (found by chip_smoke.py's burst)."""
+    from tensorflow_web_deploy_tpu.serving.engine import RaggedSlab
+
+    slab = RaggedSlab(canvas_s=16, bucket=32)
+    for bucket in (1, 2, 4, 8, 16, 32):
+        q = max(1, bucket // 8)
+        warmed = set(range(q, bucket + 1, q))  # engine._warm_executables
+        for used in range(0, bucket * slab.row_bytes + 1, slab.row_bytes // 3):
+            slab.used = used
+            assert slab.rows_shipped(bucket) in warmed, (bucket, used)
+            assert slab.rows_shipped(bucket) * slab.row_bytes >= used
+
+
 def test_unpack_ragged_invalid_rows_are_1x1_zero(rng):
     s = 16
     arena = (rng.rand(s * s * 3) * 255).astype(np.uint8)

@@ -1,0 +1,89 @@
+"""The Pallas kernels of the serving path, compiled by Mosaic for a v5e
+that is described and not attached — no chip, about two seconds each.
+
+Interpret mode (tests/test_quant.py, tests/test_pallas_preprocess.py) pins
+what the kernels compute; it cannot see what the TPU compiler refuses. Both
+kernels had passed every interpret-mode test and were refused here at
+serving shapes for more VMEM than a kernel may use (25.6 MB for one padded
+114×114×32 image, 18 MB for one 2048 canvas, against 16 MB), which is why
+they are tiled over rows. A compile that passes is not a chip run: it says
+nothing about results or times (chip_smoke.py does the running).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from tensorflow_web_deploy_tpu.ops.depthwise import fused_depthwise_bn
+from tensorflow_web_deploy_tpu.ops.pallas_preprocess import preprocess_i420
+
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip's sharding; skipped only where this
+    installation cannot describe the topology at all."""
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except RuntimeError as e:
+        # Exactly what jax raises where libtpu is not installed; any other
+        # failure to describe the chip is a failure of the test.
+        if str(e).startswith("JAX TPU support not installed; cannot generate TPU topology."):
+            pytest.skip(str(e))
+        raise
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to JAX's persistent cache
+    but cannot be read back without the chip (the next one warns and
+    compiles again): keep the cache out of these tests."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# The stride-1 depthwise layers of MobileNetV2 at width 1.0, 224 px,
+# batch 32: the largest map, the one that overflowed next, and the two
+# widest channel counts (none a multiple of 128).
+@pytest.mark.parametrize("shape", [(32, 112, 112, 32), (32, 56, 56, 144),
+                                   (32, 14, 14, 576), (32, 7, 7, 960)])
+def test_fused_depthwise_compiles_for_v5e(v5e, shape):
+    """The engine's own path for a bf16/int8 model: bf16 activations in,
+    XLA pads and casts to f32, the Mosaic kernel, bf16 out."""
+    c = shape[-1]
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)
+    k = jax.ShapeDtypeStruct((3, 3, 1, c), jnp.float32, sharding=v5e)
+    v = jax.ShapeDtypeStruct((c,), jnp.float32, sharding=v5e)
+    compiled = jax.jit(
+        lambda x, k, s, b: fused_depthwise_bn(x, k, s, b, impl="pallas")
+    ).lower(x, k, v, v).compile()
+    assert compiled.as_text().count(KERNEL) == 1
+
+
+# The smallest default canvas bucket and the largest the server accepts
+# with --resize pallas (2048 streams through VMEM in 512-row tiles).
+@pytest.mark.parametrize("canvas", [256, 2048])
+def test_preprocess_compiles_for_v5e(v5e, canvas):
+    packed = jax.ShapeDtypeStruct((32, canvas * 3 // 2, canvas), jnp.uint8, sharding=v5e)
+    hws = jax.ShapeDtypeStruct((32, 2), jnp.int32, sharding=v5e)
+    compiled = jax.jit(
+        lambda p, hw: preprocess_i420(p, hw, 299, 299, "inception")
+    ).lower(packed, hws).compile()
+    assert compiled.as_text().count(KERNEL) == 1
+    # One program's HBM, against the 16 GB of a v5e.
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes < 1 << 30

@@ -104,10 +104,10 @@ if [[ "${1:-}" == "--fast" ]]; then
 fi
 
 echo "== multichip smoke (8-device virtual CPU mesh: placement + routing) =="
-# jax 0.4.37 has no jax_num_cpu_devices config, so the 8 virtual devices
-# MUST come from XLA_FLAGS before jax initializes — set explicitly here
-# (conftest.py also appends it, but the smoke documents the requirement
-# and survives a conftest regression).
+# The 8 virtual devices must exist before jax initializes — set
+# explicitly here through XLA_FLAGS (conftest.py sets jax_num_cpu_devices
+# too, but the smoke documents the requirement and survives a conftest
+# regression).
 timeout -k 10 300 env JAX_PLATFORMS=cpu TWD_DEBUG_LOCKS=1 \
     XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m pytest tests/test_placement.py -q -p no:cacheprovider

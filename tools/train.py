@@ -137,7 +137,7 @@ def main(argv=None) -> int:
         sys.exit(f"--model {args.model} is a {spec_task} model; "
                  "the trainer supports classify zoo models")
 
-    enable_compilation_cache(".jax_cache")
+    enable_compilation_cache()
 
     if args.data:
         data = FolderData(args.data, args.input_size, args.batch, args.seed)
