@@ -144,8 +144,6 @@ def parse_args(argv=None):
                    help="on-device resize: separable-bilinear MXU matmuls (default), "
                         "dynamic-index gathers, or the fused pallas kernel "
                         "(requires --wire-format yuv420)")
-    p.add_argument("--profile", action="store_true",
-                   help="enable jax profiler server on port 9999")
     p.add_argument("--ckpt", default=None,
                    help="serving export from tools/train.py (orbax dir); "
                         "serves fine-tuned weights with --model native:<name>")
@@ -346,11 +344,6 @@ def main(argv=None):
         level=args.log_level.upper(),
         format="%(asctime)s %(levelname)s %(name)s: %(message)s",
     )
-    if args.profile:
-        import jax
-
-        jax.profiler.start_server(9999)
-
     from tensorflow_web_deploy_tpu.serving.http import (
         make_http_server, shutdown_gracefully,
     )

@@ -129,28 +129,31 @@ def unpack_ragged(arena, meta, s: int):
     on a TPU (4 GB of temporaries at canvas 512 × batch 32, and past the
     16 GB of a v5e from canvas 1024 on; found compiling for the chip).
     """
-    flat = jnp.asarray(arena).reshape(-1)  # eager numpy callers trace too
-    meta = jnp.asarray(meta)
-    row = 3 * s
-    # A window that would run past the arena's end is clamped back by
-    # dynamic_slice, which would shift the last image's rows: give every
-    # valid row's window room to end inside the buffer.
-    flat = jnp.concatenate([flat, jnp.zeros((row,), jnp.uint8)])
-    y = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
-    xb = jax.lax.broadcasted_iota(jnp.int32, (1, row), 1)
+    # named_scope: the ops' metadata carries the phase; the module's name
+    # (the caller's jit) is untouched.
+    with jax.named_scope("unpack"):
+        flat = jnp.asarray(arena).reshape(-1)  # eager numpy callers trace too
+        meta = jnp.asarray(meta)
+        row = 3 * s
+        # A window that would run past the arena's end is clamped back by
+        # dynamic_slice, which would shift the last image's rows: give every
+        # valid row's window room to end inside the buffer.
+        flat = jnp.concatenate([flat, jnp.zeros((row,), jnp.uint8)])
+        y = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
+        xb = jax.lax.broadcasted_iota(jnp.int32, (1, row), 1)
 
-    def one(m):
-        off, h, w, valid = m[0], m[1], m[2], m[3]
-        starts = off + y[:, 0] * (w * 3)
-        rows = jax.vmap(
-            lambda st: jax.lax.dynamic_slice(flat, (st,), (row,)))(starts)
-        mask = (valid > 0) & (y < h) & (xb < w * 3)
-        return jnp.where(mask, rows, jnp.uint8(0)).reshape(s, s, 3)
+        def one(m):
+            off, h, w, valid = m[0], m[1], m[2], m[3]
+            starts = off + y[:, 0] * (w * 3)
+            rows = jax.vmap(
+                lambda st: jax.lax.dynamic_slice(flat, (st,), (row,)))(starts)
+            mask = (valid > 0) & (y < h) & (xb < w * 3)
+            return jnp.where(mask, rows, jnp.uint8(0)).reshape(s, s, 3)
 
-    canvases = jax.vmap(one)(meta)
-    ok = meta[:, 3] > 0
-    hws = jnp.where(ok[:, None], meta[:, 1:3], jnp.ones((1, 2), jnp.int32))
-    return canvases, hws.astype(jnp.int32)
+        canvases = jax.vmap(one)(meta)
+        ok = meta[:, 3] > 0
+        hws = jnp.where(ok[:, None], meta[:, 1:3], jnp.ones((1, 2), jnp.int32))
+        return canvases, hws.astype(jnp.int32)
 
 
 # --------------------------------------------------------------------------

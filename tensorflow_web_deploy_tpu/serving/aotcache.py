@@ -119,6 +119,35 @@ def stats(cache: "AotCache | None" = None) -> dict:
     return out
 
 
+# Every backend compile of the process, through this cache or not: JAX
+# records one duration event per executable it asks the backend for (a
+# jitted call's first use, a ``.lower().compile()``, a rebuild out of JAX's
+# persistent cache, which is then the retrieval's time). The AOT counters
+# above see only what the engine compiles through this module; a stray
+# ``jax.jit`` that compiles while serving shows only here.
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_backend_compiles = {"backend_compiles_total": 0,
+                     "backend_compile_s_total": 0.0}
+
+
+def _on_event_duration(event: str, duration_s: float, **_kw) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
+        with _lock:
+            _backend_compiles["backend_compiles_total"] += 1
+            _backend_compiles["backend_compile_s_total"] += float(duration_s)
+
+
+# Registered once, at import: the engine imports this module before it
+# builds anything, so the count covers the process's whole life.
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+
+
+def backend_compile_stats() -> dict:
+    """The /stats "compile" block (cumulative, monotonic)."""
+    with _lock:
+        return dict(_backend_compiles)
+
+
 def key_digest(key: dict) -> str:
     """Stable content address of a key dict: SHA-256 over its canonical
     JSON (sorted keys, no whitespace). Keys must be JSON-plain —

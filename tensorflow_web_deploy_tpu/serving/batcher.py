@@ -133,11 +133,16 @@ import numpy as np
 
 from ..utils.locks import named_condition
 from ..utils.metrics import RollingStats
-from ..utils.tracing import canvas_side
+from ..utils.tracing import canvas_side, stage
 from .chaos import ChaosError
 from .overload import DEFAULT_TENANT, DeadlineExceeded, QuotaExceeded
 
 log = logging.getLogger("tpu_serve.batcher")
+
+# Why a builder sealed (rec["reason"], lifecycle.by_reason): every slot
+# leased; the ragged arena out of bytes for the next image; the batch window
+# over with a pipeline slot free; flush_bulk(); shutdown's drain.
+SEAL_REASONS = ("full", "arena", "window", "flush", "drain")
 
 # Slot-lease states. PENDING: lessee still decoding. READY: committed, row
 # valid. HOLE: abandoned (released, expired, or shutdown) — padded at seal.
@@ -214,7 +219,7 @@ class _Builder:
 
     __slots__ = ("key", "slab", "capacity", "leases", "opened_at", "deadline",
                  "accepting", "dispatched", "n_pending", "n_ready", "n_holes",
-                 "replica", "bulk", "tenant")
+                 "replica", "bulk", "tenant", "reason")
 
     def __init__(self, key, slab, capacity: int, deadline: float,
                  bulk: bool = False):
@@ -230,6 +235,10 @@ class _Builder:
         self.opened_at = time.monotonic()
         self.deadline = deadline
         self.accepting = True
+        # Why the builder stopped accepting (SEAL_REASONS), set once by
+        # _close_builder_locked; rides the batch record and the
+        # per-reason lifecycle counters.
+        self.reason: str | None = None
         self.dispatched = False
         self.n_pending = 0
         self.n_ready = 0
@@ -318,6 +327,12 @@ class Batcher:
         self._bulk_gated_since: float | None = None
         self._bulk_starvation_total = 0
         self._staged = hasattr(engine, "acquire_staging")
+        # The real engine stamps its own span stages and takes the batch's
+        # record (``rec=``): it names its profiler annotations by
+        # rec["seq"] and writes t_put/t_pre/h2d_bytes/d2h_bytes into it.
+        # Fakes and embedders with the plain signatures never see either
+        # keyword.
+        self._engine_takes_rec = getattr(engine, "supports_span_tracing", False)
         # Decode-into-slab is offered to callers (http.py) only when the
         # engine's slabs speak the slot-lease API; otherwise submit() is
         # the entry point and staging is write_row/stack at seal time.
@@ -428,6 +443,27 @@ class Batcher:
         # decode(N+1)∥execute(N) tests read.
         self._batch_seq = 0
         self._timeline: deque = deque(maxlen=512)
+        # Cumulative lifecycle counters (/stats → batcher.lifecycle), all
+        # monotonic, all updated under self._cond: _hand_off and _batch_done
+        # take it anyway, _launch takes it once more for its stamp. A
+        # batch's four phases (open → seal → launch → launched → done) sum
+        # to t_done - t_open; fetch_wait is the part of inflight that its
+        # completion thread spent on it. The starved clock runs while no
+        # batch stands between its t_launch and its t_done on any replica:
+        # stamped when that count goes 1→0, added when it goes 0→1 (exact
+        # to the time a completion thread takes from stamping t_done to
+        # _batch_done's lock).
+        self._life = {
+            "batches_total": 0,
+            "by_reason": dict.fromkeys(SEAL_REASONS, 0),
+            "open_s_total": 0.0, "launch_wait_s_total": 0.0,
+            "enqueue_s_total": 0.0, "inflight_s_total": 0.0,
+            "fetch_wait_s_total": 0.0,
+            "h2d_bytes_total": 0, "d2h_bytes_total": 0,
+            "starved_s_total": 0.0,
+        }
+        self._launched_now = 0
+        self._starved_since = time.monotonic()
         # Padding-waste accounting per (canvas bucket, batch bucket):
         # [batches, rows real, rows dispatched, real px (Σ h·w of committed
         # rows), canvas px (batch bucket × canvas²)]. Two waste axes: row
@@ -591,9 +627,8 @@ class Batcher:
         never shed (the job runner waits); their tenant rides the
         builder and is charged at the bulk gate's dispatch decision."""
         key = tuple(int(d) for d in row_shape)
-        t0 = time.monotonic()
-        with self._cond:
-            self._admit_locked(t0, bulk, deadline, tenant)
+        with stage(span, "lease_wait") as waited, self._cond:
+            self._admit_locked(waited.t0, bulk, deadline, tenant)
             b = self._open.get((key, bulk))
             if b is None:
                 b = self._new_builder_locked(key, bulk)
@@ -613,12 +648,9 @@ class Batcher:
             if b.slab is not None and hasattr(b.slab, "row"):
                 lease.row = b.slab.row(lease.index)
             if len(b.leases) >= b.capacity:
-                self._close_builder_locked(b)
+                self._close_builder_locked(b, "full")
             self._cond.notify_all()  # sealer: new deadline / full builder
-        waited = time.monotonic() - t0
-        if span is not None:
-            span.add("lease_wait", waited)
-        self.stats.record_lease_wait(waited)
+        self.stats.record_lease_wait(waited.t1 - waited.t0)
         return lease
 
     def lease_ragged(self, need_bytes: int, canvas_s: int, span=None,
@@ -635,9 +667,8 @@ class Batcher:
         per canvas row while large ones still get full batches. Admission
         (backlog/quota/deadline sheds, the blocking slot cap) is identical
         to :meth:`lease`."""
-        t0 = time.monotonic()
-        with self._cond:
-            self._admit_locked(t0, bulk, deadline, tenant)
+        with stage(span, "lease_wait") as waited, self._cond:
+            self._admit_locked(waited.t0, bulk, deadline, tenant)
             key = ("ragged", int(canvas_s))
             row_bytes = int(canvas_s) * int(canvas_s) * 3
             if need_bytes > row_bytes:
@@ -655,7 +686,7 @@ class Batcher:
                 # Out of bytes or slots: this batch is as packed as it
                 # gets — seal it now and start the next arena. (A fresh
                 # arena always fits: need ≤ row_bytes ≤ arena_bytes.)
-                self._close_builder_locked(b)
+                self._close_builder_locked(b, "arena")
                 self._cond.notify_all()
                 b = self._new_ragged_builder_locked(key, canvas_s, bulk)
                 got = b.slab.alloc(need_bytes)
@@ -674,12 +705,9 @@ class Batcher:
             lease.slab_held = True
             lease.row = view
             if b.slab.slots >= b.capacity:
-                self._close_builder_locked(b)
+                self._close_builder_locked(b, "full")
             self._cond.notify_all()  # sealer: new deadline / full builder
-        waited = time.monotonic() - t0
-        if span is not None:
-            span.add("lease_wait", waited)
-        self.stats.record_lease_wait(waited)
+        self.stats.record_lease_wait(waited.t1 - waited.t0)
         return lease
 
     def submit(self, canvas: np.ndarray, hw: tuple[int, int], span=None,
@@ -731,9 +759,10 @@ class Batcher:
         self._open[(key, bulk)] = b
         return b
 
-    def _close_builder_locked(self, b: _Builder):
+    def _close_builder_locked(self, b: _Builder, reason: str):
         if b.accepting:
             b.accepting = False
+            b.reason = reason
             if self._open.get((b.key, b.bulk)) is b:
                 del self._open[(b.key, b.bulk)]
             self._closing.append(b)
@@ -746,26 +775,25 @@ class Batcher:
 
     def _commit(self, lease: SlotLease, hw, canvas=None) -> Future:
         b = lease.builder
-        t0 = time.monotonic()
         # The slot write happens OUTSIDE the lock (it may be a full canvas
         # copy); the slot is exclusively this lessee's until commit.
-        if canvas is not None:
-            if b.slab is not None:
-                if getattr(b.slab, "is_ragged", False):
-                    # PIL-fallback path on the ragged wire: the decoded RGB
-                    # array copies TIGHT into the leased byte span (its size
-                    # was the lease's need_bytes), then the meta commit.
-                    lease.row[:] = np.ascontiguousarray(
-                        canvas, dtype=np.uint8).reshape(-1)
-                    b.slab.write_hw(lease.index, hw)
+        with stage(lease.span, "staging_write"):
+            if canvas is not None:
+                if b.slab is not None:
+                    if getattr(b.slab, "is_ragged", False):
+                        # PIL-fallback path on the ragged wire: the decoded
+                        # RGB array copies TIGHT into the leased byte span
+                        # (its size was the lease's need_bytes), then the
+                        # meta commit.
+                        lease.row[:] = np.ascontiguousarray(
+                            canvas, dtype=np.uint8).reshape(-1)
+                        b.slab.write_hw(lease.index, hw)
+                    else:
+                        b.slab.write_row(lease.index, canvas, hw)
                 else:
-                    b.slab.write_row(lease.index, canvas, hw)
-            else:
-                lease.canvas = np.asarray(canvas)
-        elif b.slab is not None and hasattr(b.slab, "write_hw"):
-            b.slab.write_hw(lease.index, hw)
-        if lease.span is not None:
-            lease.span.add("staging_write", time.monotonic() - t0)
+                    lease.canvas = np.asarray(canvas)
+            elif b.slab is not None and hasattr(b.slab, "write_hw"):
+                b.slab.write_hw(lease.index, hw)
         with self._cond:
             if lease.state == _PENDING:
                 lease.state = _READY
@@ -822,7 +850,7 @@ class Batcher:
         the right place to decide, not a timer guessing."""
         with self._cond:
             for b in [b for b in self._open.values() if b.bulk]:
-                self._close_builder_locked(b)
+                self._close_builder_locked(b, "flush")
             self._cond.notify_all()
 
     # -------------------------------------------------------------- sealing
@@ -1007,7 +1035,11 @@ class Batcher:
             # the device, the bulk batch keeps accepting and GROWS toward
             # bulk_max_batch — the gate's pressure buys batch efficiency.
             # The pending-decode wait is bounded — leases expire above.
-            if draining or len(b.leases) >= b.capacity or (
+            if draining:
+                self._close_builder_locked(b, "drain")
+            elif len(b.leases) >= b.capacity:
+                self._close_builder_locked(b, "full")
+            elif (
                 now >= b.deadline and not b.n_pending
                 and (self._bulk_gate_open_locked(now, consume=False,
                                                  tenant=b.tenant,
@@ -1015,7 +1047,7 @@ class Batcher:
                      if b.bulk
                      else self._depth_free_locked((b.key, False)))
             ):
-                self._close_builder_locked(b)
+                self._close_builder_locked(b, "window")
         for b in self._closing:
             self._expire_locked(b, now, grace)
         # Interactive builders first, always: the bulk class is strictly
@@ -1127,6 +1159,15 @@ class Batcher:
             return None  # nothing assembling: sleep until notified
         return max(0.0005, wake - now)
 
+    def _seal_wait_label_locked(self) -> str:
+        """The canvas of the builder the sealer waits on (``c4096``: the
+        first that closed, else the first that opened), for its
+        annotation; empty with none. What is open, closing and in flight is
+        in ``/stats``."""
+        b = self._closing[0] if self._closing else next(
+            iter(self._open.values()), None)
+        return f"c{canvas_side(b.key)}" if b is not None else ""
+
     def _seal_loop(self):
         while True:
             with self._cond:
@@ -1137,7 +1178,8 @@ class Batcher:
                         break
                     if not self._running and not self._open and not self._closing:
                         return  # drained: every builder dispatched/discarded
-                    self._cond.wait(timeout=self._next_wake_locked(now))
+                    with stage(None, "seal_wait", self._seal_wait_label_locked()):
+                        self._cond.wait(timeout=self._next_wake_locked(now))
             kind, b = action
             if kind == "dispatch":
                 self._hand_off(b)
@@ -1158,6 +1200,7 @@ class Batcher:
         harmless."""
         if b.slab is not None and hasattr(self.engine, "release_staging"):
             self.engine.release_staging(b.slab)
+        b.leases = []  # as in _launch: no builder↔lease cycle around the slab
 
     def _hand_off(self, b: _Builder):
         """Seal one builder and enqueue it for the launch pool. The sealer
@@ -1165,11 +1208,20 @@ class Batcher:
         the next batch proceeds while this one transfers), and the
         host→device transfer runs on a launch thread."""
         ready = [l for l in b.leases if l.state == _READY]
+        # The batch record: identity, then the lifecycle's stamps in order
+        # (all time.monotonic(); None until reached). The engine fills
+        # t_put (both device_puts returned: the copy is *enqueued*), t_pre
+        # (unpack enqueued), h2d_bytes and d2h_bytes where it is handed the
+        # record; trace_ids are the request spans that rode.
         rec = {
             "seq": 0, "key": b.key, "rows": len(ready), "bucket": None,
-            "replica": b.replica, "bulk": b.bulk,
+            "replica": b.replica, "bulk": b.bulk, "reason": b.reason,
+            "trace_ids": sorted({l.span.trace_id for l in ready
+                                 if l.span is not None}),
             "t_open": b.opened_at, "t_seal": time.monotonic(),
-            "t_launch": None, "t_launched": None, "t_done": None,
+            "t_launch": None, "t_put": None, "t_pre": None,
+            "t_launched": None, "t_fetch": None, "t_done": None,
+            "h2d_bytes": None, "d2h_bytes": None,
         }
         with self._cond:
             self._dec_pending_locked(b, len(ready))
@@ -1180,6 +1232,10 @@ class Batcher:
             self._batch_seq += 1
             rec["seq"] = self._batch_seq
             self._timeline.append(rec)
+            life = self._life
+            life["batches_total"] += 1
+            life["by_reason"][b.reason] += 1
+            life["open_s_total"] += rec["t_seal"] - rec["t_open"]
             self._cond.notify_all()  # lease() waiters + next seal decision
         self._launch_q.put((b, ready, rec))
 
@@ -1189,12 +1245,24 @@ class Batcher:
             self._sealed_total += 1
             self._cond.notify_all()  # lease() waiters + next seal decision
 
-    def _batch_done(self, mkey, replica: int = 0):
-        """One in-flight batch left the pipeline (fetched or failed): free
-        its ((bucket, bulk), replica) depth slot and wake the sealer — the
-        wakeup that also re-evaluates the bulk gate."""
+    def _batch_done(self, rec: dict):
+        """One in-flight batch left the pipeline (fetched or failed, its
+        ``t_done`` stamped): free its ((bucket, bulk), replica) depth slot,
+        close its lifecycle counters and wake the sealer — the wakeup that
+        also re-evaluates the bulk gate."""
+        mkey = (rec["key"], rec["bulk"])
         with self._cond:
-            slot = (mkey, replica)
+            life, t_done = self._life, rec["t_done"]
+            life["enqueue_s_total"] += rec["t_launched"] - rec["t_launch"]
+            life["inflight_s_total"] += t_done - rec["t_launched"]
+            if rec["t_fetch"] is not None:
+                life["fetch_wait_s_total"] += t_done - rec["t_fetch"]
+            life["h2d_bytes_total"] += rec["h2d_bytes"] or 0
+            life["d2h_bytes_total"] += rec["d2h_bytes"] or 0
+            self._launched_now -= 1
+            if self._launched_now == 0:
+                self._starved_since = t_done
+            slot = (mkey, rec["replica"])
             n = self._inflight_by_key.get(slot, 0) - 1
             if n > 0:
                 self._inflight_by_key[slot] = n
@@ -1219,15 +1287,24 @@ class Batcher:
         holes, one device_put, execute enqueue, async D2H start. Transfers
         of consecutive batches overlap because the pool has more than one
         thread and the sealer never waits for a launch to finish."""
-        t0 = time.monotonic()
-        rec["t_launch"] = t0
+        with self._cond:
+            # Read under the lock, so that the starved clock's 0→1 and 1→0
+            # transitions are ordered as their stamps are.
+            t0 = rec["t_launch"] = time.monotonic()
+            life = self._life
+            life["launch_wait_s_total"] += t0 - rec["t_seal"]
+            if self._launched_now == 0:
+                life["starved_s_total"] += max(0.0, t0 - self._starved_since)
+            self._launched_now += 1
         for l in ready:
             if l.span is not None:
                 # add_max: a multi-image request's legs ride concurrent
                 # batches; the stage merges as the slowest leg so the span's
                 # stage sum still tiles the request's wall time.
                 l.span.add_max("queue_wait", t0 - l.committed_at)
+                l.span.note_append("batches", rec["seq"])
         spans = [l.span for l in ready if l.span is not None]
+        traced = self._engine_takes_rec
         try:
             if self.chaos is not None and self.chaos.dispatch_fault():
                 # Inside the try: an injected dispatch error exercises
@@ -1248,13 +1325,15 @@ class Batcher:
                 # and embedders with the plain signatures never see the
                 # keyword.
                 kw = {"replica": b.replica} if self._route else {}
+                if traced:
+                    kw["rec"] = rec
                 if getattr(b.slab, "is_ragged", False):
                     # Ragged wire: ship the tight arena prefix + meta; the
                     # engine's jitted unpack stage rebuilds the canvases on
                     # device (spans gain device_preprocess there).
                     handle = self.engine.dispatch_ragged(b.slab, n,
                                                          spans=spans, **kw)
-                elif getattr(self.engine, "supports_span_tracing", False):
+                elif traced:
                     # The engine stamps device_transfer/device_dispatch
                     # itself (it owns the host→device transfer); spans=
                     # keeps staging-API fakes and embedders with the plain
@@ -1290,10 +1369,17 @@ class Batcher:
             # memory. Any aliased device read of dropped outputs is
             # harmless: nobody fetches them.
             self._recycle(b)
-            self._batch_done((b.key, b.bulk), b.replica)
+            self._batch_done(rec)
             return
         rec["t_launched"] = time.monotonic()
         rec["bucket"] = bucket
+        # The builder is done with its leases (``ready`` carries them on).
+        # Dropping the list breaks the builder↔lease reference cycle, which
+        # would otherwise keep the slab (up to 1.6 GB at canvas 4096 × batch
+        # 32, dropped by the pool's byte budget after every batch) alive
+        # until the next full garbage collection: tens of gigabytes of host
+        # memory in a 30 s window (PERF.md section 6, PR 27).
+        b.leases = []
         for l in ready:
             if l.span is not None:
                 # The compiled bucket this request's batch ran at — the
@@ -1344,14 +1430,17 @@ class Batcher:
                 delay = self.chaos.fetch_delay()
                 if delay > 0:
                     time.sleep(delay)
+            rec["t_fetch"] = time.monotonic()
             try:
-                outs = self.engine.fetch_outputs(handle)
+                if self._engine_takes_rec:
+                    outs = self.engine.fetch_outputs(handle, rec=rec)
+                else:
+                    outs = self.engine.fetch_outputs(handle)
             except Exception as e:
                 log.exception("fetch of batch of %d failed", len(ready))
                 self._fail(ready, e)
                 rec["t_done"] = time.monotonic()
-                self._batch_done((rec["key"], rec.get("bulk", False)),
-                                 rec.get("replica", 0))
+                self._batch_done(rec)
                 continue
             now = time.monotonic()
             rec["t_done"] = now
@@ -1374,8 +1463,7 @@ class Batcher:
                     device_s=now - t_launch,
                     batch_size=len(ready),
                 )
-            self._batch_done((rec["key"], rec.get("bulk", False)),
-                             rec.get("replica", 0))
+            self._batch_done(rec)
 
     def _fail(self, leases: list[SlotLease], e: Exception):
         now = time.monotonic()
@@ -1483,11 +1571,27 @@ class Batcher:
                 },
             }
 
+    def lifecycle_stats(self) -> dict:
+        """The ``/stats → batcher.lifecycle`` block: the cumulative
+        counters above plus ``now_s``, the clock they were read at, and the
+        starved clock brought up to it, so that a share is a delta over a
+        delta of two reads."""
+        with self._cond:
+            now = time.monotonic()
+            out = {**self._life, "by_reason": dict(self._life["by_reason"])}
+            if self._launched_now == 0:
+                out["starved_s_total"] += max(0.0, now - self._starved_since)
+            out["now_s"] = now
+            return out
+
     def batch_timeline(self) -> list[dict]:
         """Recent per-batch lifecycle records (monotonic stamps): builder
-        ``t_open`` → ``t_seal`` (assembly/decode window) → ``t_launch`` →
-        ``t_launched`` (host→device transfer + execute enqueue) →
-        ``t_done`` (outputs on host). In-flight batches carry None for
+        ``t_open`` → ``t_seal`` (assembly/decode window; ``reason`` says
+        why it sealed) → ``t_launch`` → ``t_put`` (both ``device_put``s
+        returned) → ``t_pre`` (unpack enqueued) → ``t_launched`` (execute
+        enqueued, D2H started) → ``t_fetch`` (a completion thread turned to
+        it) → ``t_done`` (outputs on host), with ``h2d_bytes``/``d2h_bytes``
+        and the ``trace_ids`` that rode. In-flight batches carry None for
         stages not reached yet. The raw material for overlap analysis —
         bench.py's ``pipeline`` block computes busy-time(decode ∥ execute)
         from exactly this."""

@@ -41,7 +41,7 @@ from ..ops.image import make_preprocess_fn, pad_to_canvas, rgb_to_yuv420_canvas
 from ..parallel import mesh as mesh_lib
 from ..utils.config import ModelConfig, ServerConfig
 from ..utils.locks import named_lock
-from ..utils.tracing import canvas_side
+from ..utils.tracing import canvas_side, stage
 from . import aotcache
 from .placement import parse_placement
 
@@ -311,6 +311,13 @@ class RaggedSlab:
                 self._idle_cb = None
         if cb is not None:  # outside the lock: cb takes the pool lock
             cb(self)
+
+
+def _batch_ids(rec: dict | None) -> dict:
+    """The identities a batch's profiler annotations carry as stats, from
+    the batcher's record of it (none for a dispatch outside the batcher:
+    warm-up, run_batch, the DAG executor)."""
+    return {} if rec is None else {"seq": rec["seq"], "rows": rec["rows"]}
 
 
 class _DeviceBatch:
@@ -739,8 +746,12 @@ class InferenceEngine:
                     # expansion into each kernel's first use (HBM reads stay
                     # 1 byte/weight; scale leaves never reach model_fn).
                     params = quant.dequantize_tree(params, dtype)
-                x = preprocess(canvases, hws).astype(dtype)
-                outs = model_fn(params, x, float_dtype=policy)
+                # named_scope: each op's metadata carries its phase
+                # (resize / forward / topk); the module keeps its name.
+                with jax.named_scope("resize"):
+                    x = preprocess(canvases, hws).astype(dtype)
+                with jax.named_scope("forward"):
+                    outs = model_fn(params, x, float_dtype=policy)
                 if task == "classify":
                     # Top-k on device: the host fetches k (score, index)
                     # pairs per image instead of the full class vector —
@@ -748,9 +759,11 @@ class InferenceEngine:
                     # are the scarce resource. Clamped at trace time: a
                     # 4-class fine-tune with the default topk=5 must serve,
                     # not crash on the first request.
-                    probs = outs[0].astype(jnp.float32)
-                    scores, idx = jax.lax.top_k(probs, min(topk, probs.shape[-1]))
-                    return (scores, idx.astype(jnp.int32))
+                    with jax.named_scope("topk"):
+                        probs = outs[0].astype(jnp.float32)
+                        scores, idx = jax.lax.top_k(
+                            probs, min(topk, probs.shape[-1]))
+                        return (scores, idx.astype(jnp.int32))
                 if task == "detect":
                     by_name = dict(zip(self.model.output_names, outs))
                     boxes = jax.vmap(detection.decode_boxes, in_axes=(0, None))(
@@ -1261,7 +1274,7 @@ class InferenceEngine:
     # ------------------------------------------------------------- dispatch
 
     def dispatch_staged(self, slab: StagingSlab, n: int, spans=(),
-                        replica: int | None = None):
+                        replica: int | None = None, rec: dict | None = None):
         """Dispatch a filled staging slab (async); returns an opaque handle
         for :meth:`fetch_outputs`. ``replica`` pins the dispatch stream
         (the batcher routes at seal time); None routes here via
@@ -1271,7 +1284,10 @@ class InferenceEngine:
         plus a ``replica`` note, so per-chip attribution survives into the
         access log and flight recorder. PJRT transfers are asynchronous, so
         the transfer stamp is the enqueue cost and the wire time folds into
-        ``device_execute``.
+        ``device_execute``. ``rec`` is the batcher's record of this batch
+        (Batcher._hand_off): its ``seq`` and ``rows`` name the profiler
+        annotations (``twd.h2d``, ``twd.serve_enqueue``, ``twd.d2h_start``),
+        and ``t_put`` and ``h2d_bytes`` are written into it.
 
         Dispatch and fetch are split so the batcher's pipeline can overlap
         batch N+1's transfer/compute with batch N's execute and device→host
@@ -1285,7 +1301,7 @@ class InferenceEngine:
         outputs starts at dispatch time so the fetch side pays neither
         compute wait nor transfer round-trip latency when it finally blocks.
         """
-        t0 = time.monotonic() if spans else 0.0
+        t0 = time.monotonic()
         slab.pad_from(n)
         # The slot-lease batcher acquires top-capacity slabs before it knows
         # the final batch size, so dispatch re-buckets: ship only the prefix
@@ -1303,8 +1319,8 @@ class InferenceEngine:
             rep.slab_bytes_inflight += slab.total_bytes
         guard = rep.dispatch_guard if rep.serialize else _NO_LOCK
         try:
-            outs, t_put = self._dispatch_on(rep, guard, slab, bucket,
-                                            bool(spans), t0)
+            outs, t_put, nbytes = self._dispatch_on(
+                rep, guard, slab, bucket, _batch_ids(rec))
         except BaseException:
             # Roll the LIVE accounting back: a failed dispatch never
             # reaches fetch_outputs, and leaked in-flight counts would make
@@ -1317,41 +1333,48 @@ class InferenceEngine:
                 rep.slab_bytes_inflight -= slab.total_bytes
             raise
         t_disp = time.monotonic()
-        if spans:
-            for s in spans:
-                s.add_max("device_transfer", t_put - t0)
-                s.add_max("device_dispatch", t_disp - t_put)
-                s.note("replica", r)
+        if rec is not None:
+            rec["t_put"], rec["h2d_bytes"] = t_put, nbytes
+        for s in spans:
+            s.add_max("device_transfer", t_put - t0)
+            s.add_max("device_dispatch", t_disp - t_put)
+            s.note("replica", r)
         return outs, (n, slab, r, t_disp, bucket)
 
     def _dispatch_on(self, rep: _Replica, guard, slab: StagingSlab,
-                     bucket: int, timed: bool, t0: float):
+                     bucket: int, ids: dict):
         """The guarded device work of one dispatch: host→device transfer +
-        execute enqueue + async D2H start on ``rep``'s stream."""
+        execute enqueue + async D2H start on ``rep``'s stream, each under
+        its profiler annotation (``ids``: the batch's seq and rows).
+        Returns (outputs, when the last ``device_put`` returned, bytes
+        shipped)."""
         serve = self._serve_exe_for(rep, slab.key[0], bucket)
+        label = f"c{canvas_side(slab.key[0])} b{bucket}"
         with guard:
             if self.cfg.packed_io:
                 buf = slab.buf if bucket == slab.bucket else slab.buf[:bucket]
-                # twdlint: disable=no-blocking-under-lock(the per-replica dispatch guard EXISTS to hold device enqueue: two concurrent multi-device XLA:CPU dispatches into ONE replica interleave per-device partitions and deadlock the collective rendezvous; disjoint replicas never contend, and the guard is a nullcontext off CPU / on single-device replicas)
-                buf_d = jax.device_put(buf, rep.data_sharding)
-                t_put = time.monotonic() if timed else 0.0
-                outs = serve(rep.params, buf_d)
+                nbytes = buf.nbytes
+                with stage(None, "h2d", label, **ids) as put:
+                    # twdlint: disable=no-blocking-under-lock(the per-replica dispatch guard EXISTS to hold device enqueue: two concurrent multi-device XLA:CPU dispatches into ONE replica interleave per-device partitions and deadlock the collective rendezvous; disjoint replicas never contend, and the guard is a nullcontext off CPU / on single-device replicas)
+                    buf_d = jax.device_put(buf, rep.data_sharding)
+                with stage(None, "serve_enqueue", label, **ids):
+                    outs = serve(rep.params, buf_d)
             else:
                 trim = bucket != slab.bucket
-                # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as the packed branch — the guarded region is exactly the device enqueue)
-                canvases_d = jax.device_put(
-                    slab.canvases[:bucket] if trim else slab.canvases,
-                    rep.data_sharding,
-                )
-                # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as the packed branch)
-                hws_d = jax.device_put(
-                    slab.hws[:bucket] if trim else slab.hws, rep.data_sharding
-                )
-                t_put = time.monotonic() if timed else 0.0
-                outs = serve(rep.params, canvases_d, hws_d)
-            for leaf in jax.tree.leaves(outs):
-                leaf.copy_to_host_async()
-        return outs, t_put
+                canvases = slab.canvases[:bucket] if trim else slab.canvases
+                hws = slab.hws[:bucket] if trim else slab.hws
+                nbytes = canvases.nbytes + hws.nbytes
+                with stage(None, "h2d", label, **ids) as put:
+                    # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as the packed branch — the guarded region is exactly the device enqueue)
+                    canvases_d = jax.device_put(canvases, rep.data_sharding)
+                    # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as the packed branch)
+                    hws_d = jax.device_put(hws, rep.data_sharding)
+                with stage(None, "serve_enqueue", label, **ids):
+                    outs = serve(rep.params, canvases_d, hws_d)
+            with stage(None, "d2h_start", label, **ids):
+                for leaf in jax.tree.leaves(outs):
+                    leaf.copy_to_host_async()
+        return outs, put.t1, nbytes
 
     def _ragged_unpack(self, rep: _Replica, canvas_s: int, bucket: int,
                        rows: int, counts: dict | None = None):
@@ -1413,15 +1436,16 @@ class InferenceEngine:
         return hit
 
     def dispatch_ragged(self, slab: RaggedSlab, n: int, spans=(),
-                        replica: int | None = None):
+                        replica: int | None = None, rec: dict | None = None):
         """Dispatch a filled ragged arena (async) — the tight-wire sibling
         of :meth:`dispatch_staged`. Ships the arena's used prefix (see
         :meth:`RaggedSlab.rows_shipped`) plus the meta table, enqueues the
         jitted device-side unpack, then the replica's serve fn; the handle
         feeds the SAME :meth:`fetch_outputs`. Spans gain a
         ``device_preprocess`` stage between transfer and dispatch — the
-        enqueue of the unpack program."""
-        t0 = time.monotonic() if spans else 0.0
+        enqueue of the unpack program (annotation ``twd.unpack_enqueue``);
+        ``rec`` as in :meth:`dispatch_staged`, with ``t_pre`` besides."""
+        t0 = time.monotonic()
         bucket = self.pick_batch_bucket(n)
         r = self.route_replica() if replica is None else int(replica)
         rep = self._replicas[r]
@@ -1431,8 +1455,8 @@ class InferenceEngine:
             rep.slab_bytes_inflight += slab.total_bytes
         guard = rep.dispatch_guard if rep.serialize else _NO_LOCK
         try:
-            outs, t_put, t_pre = self._dispatch_ragged_on(
-                rep, guard, slab, bucket, bool(spans), t0
+            outs, t_put, t_pre, nbytes = self._dispatch_ragged_on(
+                rep, guard, slab, bucket, _batch_ids(rec)
             )
         except BaseException:
             # Same live-accounting rollback as dispatch_staged; the totals
@@ -1442,35 +1466,42 @@ class InferenceEngine:
                 rep.slab_bytes_inflight -= slab.total_bytes
             raise
         t_disp = time.monotonic()
-        if spans:
-            for s in spans:
-                s.add_max("device_transfer", t_put - t0)
-                s.add_max("device_preprocess", t_pre - t_put)
-                s.add_max("device_dispatch", t_disp - t_pre)
-                s.note("replica", r)
+        if rec is not None:
+            rec["t_put"], rec["t_pre"], rec["h2d_bytes"] = t_put, t_pre, nbytes
+        for s in spans:
+            s.add_max("device_transfer", t_put - t0)
+            s.add_max("device_preprocess", t_pre - t_put)
+            s.add_max("device_dispatch", t_disp - t_pre)
+            s.note("replica", r)
         return outs, (n, slab, r, t_disp, bucket)
 
     def _dispatch_ragged_on(self, rep: _Replica, guard, slab: RaggedSlab,
-                            bucket: int, timed: bool, t0: float):
+                            bucket: int, ids: dict):
         """Guarded device work of one ragged dispatch: ship arena prefix +
-        meta, enqueue unpack, enqueue serve, start the async D2H copy."""
+        meta, enqueue unpack, enqueue serve, start the async D2H copy, each
+        under its profiler annotation. Returns (outputs, when the second
+        ``device_put`` returned, when the unpack was enqueued, bytes
+        shipped)."""
         rows = slab.rows_shipped(bucket)
         unpack, arena_sh = self._ragged_unpack(rep, slab.canvas_s, bucket, rows)
         serve = self._serve_exe_for(rep, slab.key[0], bucket)
         arena = slab.buf[: rows * slab.row_bytes]
         meta = slab.meta if bucket == slab.bucket else slab.meta[:bucket]
+        label = f"c{slab.canvas_s} b{bucket}"
         with guard:
-            # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on — the guarded region is exactly the device enqueue)
-            arena_d = jax.device_put(arena, arena_sh)
-            # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on)
-            meta_d = jax.device_put(meta, rep.replicated)
-            t_put = time.monotonic() if timed else 0.0
-            canvases_d, hws_d = unpack(arena_d, meta_d)
-            t_pre = time.monotonic() if timed else 0.0
-            outs = serve(rep.params, canvases_d, hws_d)
-            for leaf in jax.tree.leaves(outs):
-                leaf.copy_to_host_async()
-        return outs, t_put, t_pre
+            with stage(None, "h2d", label, **ids) as put:
+                # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on — the guarded region is exactly the device enqueue)
+                arena_d = jax.device_put(arena, arena_sh)
+                # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on)
+                meta_d = jax.device_put(meta, rep.replicated)
+            with stage(None, "unpack_enqueue", label, **ids) as pre:
+                canvases_d, hws_d = unpack(arena_d, meta_d)
+            with stage(None, "serve_enqueue", label, **ids):
+                outs = serve(rep.params, canvases_d, hws_d)
+            with stage(None, "d2h_start", label, **ids):
+                for leaf in jax.tree.leaves(outs):
+                    leaf.copy_to_host_async()
+        return outs, put.t1, pre.t1, arena.nbytes + meta.nbytes
 
     def dispatch_batch(self, canvases: np.ndarray, hws: np.ndarray,
                        replica: int | None = None):
@@ -1481,22 +1512,31 @@ class InferenceEngine:
         slab.write_rows(canvases, hws)
         return self.dispatch_staged(slab, canvases.shape[0], replica=replica)
 
-    def fetch_outputs(self, handle) -> tuple[np.ndarray, ...]:
+    def fetch_outputs(self, handle, rec: dict | None = None
+                      ) -> tuple[np.ndarray, ...]:
         """Block on a dispatched batch and return numpy outputs sliced to the
         real batch size (packed path: split the single fetched array back
         into per-output views using the traced tail shapes). Completing the
         fetch proves the device consumed the inputs, so the batch's staging
         slab becomes pool-eligible here — actual return waits for any
-        straggling slot lessee via the slab's refcount."""
+        straggling slot lessee via the slab's refcount. The blocking
+        conversion lies under the annotation ``twd.fetch``, named by
+        ``rec`` (the batcher's record of the batch), which also receives
+        ``d2h_bytes``."""
         outs, (n, slab, r, t_disp, bucket) = handle
+        wait = stage(None, "fetch", f"c{canvas_side(slab.key[0])} b{bucket}",
+                     **_batch_ids(rec))
         try:
             if self.cfg.packed_io:
                 # The conversion transfers the FULL compiled bucket (the
                 # device array is one buffer); the slice to n happens on
                 # host — which is exactly why the DAG executor's partial
                 # row fetches beat this path on D2H bytes/image.
-                packed_full = np.asarray(outs)
+                with wait:
+                    packed_full = np.asarray(outs)
                 self.note_d2h(packed_full.nbytes)
+                if rec is not None:
+                    rec["d2h_bytes"] = packed_full.nbytes
                 packed = packed_full[:n]
                 result = []
                 off = 0
@@ -1509,8 +1549,12 @@ class InferenceEngine:
                     result.append(chunk.astype(dt) if dt != np.float32 else chunk)
                     off += size
                 return tuple(result)
-            outs = jax.tree.map(lambda o: np.asarray(o), outs)
-            self.note_d2h(sum(o.nbytes for o in jax.tree.leaves(outs)))
+            with wait:
+                outs = jax.tree.map(lambda o: np.asarray(o), outs)
+            nbytes = sum(o.nbytes for o in jax.tree.leaves(outs))
+            self.note_d2h(nbytes)
+            if rec is not None:
+                rec["d2h_bytes"] = nbytes
             outs = jax.tree.map(lambda o: o[:n], outs)
             return outs if isinstance(outs, tuple) else (outs,)
         finally:

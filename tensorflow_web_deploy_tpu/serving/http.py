@@ -121,9 +121,13 @@ from concurrent.futures import TimeoutError as FutureTimeout
 from http.server import BaseHTTPRequestHandler
 from socketserver import TCPServer
 
+from jax.profiler import TraceAnnotation
+
 from ..utils.locks import named_lock
 from ..utils.metrics import Observability, PromText, make_access_logger
-from ..utils.tracing import Span, accept_trace_id, chrome_trace, effective_window
+from ..utils.tracing import (
+    Span, accept_trace_id, chrome_trace, clock_marker, effective_window, stage,
+)
 from . import aotcache, costmodel
 from .batcher import BacklogFull, ShuttingDown
 from .dag import PipelineCatalog, PipelineUnavailable, parse_pipeline_args
@@ -315,6 +319,12 @@ class App:
         access_log = getattr(server_cfg, "access_log", None)
         if access_log:
             self.obs.set_access_log(make_access_logger(access_log))
+        # POST /debug/trace: one recording at a time (the flag, under its
+        # lock; the recording itself sleeps outside it), and what the last
+        # one covered, for /stats "profile".
+        self._profile_lock = named_lock("http.profile_lock")
+        self._profiling = False
+        self._profile: dict | None = None
         # Content-addressed response cache (serving/respcache.py): keyed by
         # (model, version, digest of the decoded canvas, topk, serving
         # dtype), with single-flight dedup. cache_bytes=0 (the dataclass
@@ -601,6 +611,11 @@ class App:
                 # host-path occupancy picture next to the device-side
                 # occupancy above.
                 snap["batcher"]["builders"] = batcher.builder_stats()
+            if hasattr(batcher, "lifecycle_stats"):
+                # Where a batch's time went (open, launch wait, enqueue, in
+                # flight), why batches sealed, bytes each way, and how long
+                # no batch was launched at all: cumulative, read as deltas.
+                snap["batcher"]["lifecycle"] = batcher.lifecycle_stats()
         else:
             # Default model between versions (drained, or never adopted):
             # the registry block below still tells the whole story.
@@ -637,6 +652,14 @@ class App:
         # counters (monotonic across hot-swaps) plus the default
         # engine's cache location/enabled flag.
         snap["aot_cache"] = aotcache.stats(getattr(engine, "_aot", None))
+        # Every backend compile of the process, through the AOT cache or
+        # not (a jax.monitoring listener): a jit that compiles while
+        # serving shows here and nowhere else.
+        snap["compile"] = aotcache.backend_compile_stats()
+        # The last POST /debug/trace recording: its interval on the
+        # monotonic clock and the batch records that met it (None until
+        # one was made).
+        snap["profile"] = self._profile
         # Bulk jobs: lifecycle counts, aggregate image counters, recent
         # job documents (progress, versions, resume flags).
         snap["jobs"] = (self.jobs.stats() if self.jobs is not None
@@ -1540,8 +1563,13 @@ class App:
                 return ("400 Bad Request",
                         b'{"error": "topk must be an integer"}',
                         "application/json")
-            body = self._read_body(environ)
-            span.add("body_read", time.monotonic() - t0)
+            # The stage keeps its start at t0, this method's entry (the
+            # query and SLO parsing and the registry's acquire above are
+            # in it, as http_ms_per_req has always read it); the
+            # annotation covers the read alone.
+            with stage(None, "body_read") as rd:
+                body = self._read_body(environ)
+            span.add("body_read", rd.t1 - t0)
             if body is None:
                 return (
                     "413 Content Too Large",
@@ -1773,7 +1801,6 @@ class App:
         payloads: list = [None] * len(slots)
         etags: list = [None] * len(slots)
         n_hit = n_wait = 0
-        post_s = wait_s = 0.0
         try:
             # OWN slots first, regardless of upload order: a leader must
             # publish its result to the cache (waking every coalesced
@@ -1788,12 +1815,15 @@ class App:
                     payloads[i], etags[i] = slot[1], slot[2]
                 elif kind == "own":
                     _, future, orig, flight, _lease = slot
-                    row = future.result(
-                        timeout=max(0.0, deadline - time.monotonic())
-                    )
-                    t_p = time.monotonic()
-                    payload = self._format_row(row, orig, topk, mv)
-                    post_s += time.monotonic() - t_p
+                    # An annotation only: the batcher stamps queue_wait and
+                    # the device_* stages over this interval, and the stage
+                    # sum must keep tiling the request's wall time.
+                    with stage(None, "await_batch", trace_id=span.trace_id):
+                        row = future.result(
+                            timeout=max(0.0, deadline - time.monotonic())
+                        )
+                    with stage(span, "postprocess"):
+                        payload = self._format_row(row, orig, topk, mv)
                     if flight is not None:
                         # Leader: publish to the cache, wake every waiter.
                         etags[i] = self.cache.complete(flight, payload)
@@ -1803,11 +1833,11 @@ class App:
                     continue
                 n_wait += 1
                 flight = slot[1]
-                t_w = time.monotonic()
                 try:
-                    payload, etag = flight.future.result(
-                        timeout=max(0.0, deadline - time.monotonic())
-                    )
+                    with stage(span, "cache_wait"):
+                        payload, etag = flight.future.result(
+                            timeout=max(0.0, deadline - time.monotonic())
+                        )
                 except FutureTimeout:
                     raise
                 except BaseException as e:
@@ -1818,8 +1848,6 @@ class App:
                     # request once; this request's own results above are
                     # already cached, so the retry hits them.
                     raise _CoalesceRetry(e) from e
-                finally:
-                    wait_s += time.monotonic() - t_w
                 payloads[i], etags[i] = payload, etag
         except FutureTimeout:
             # Undispatched slots become padded holes instead of wasting a
@@ -1854,9 +1882,6 @@ class App:
             # would hang to their own timeouts.
             self._abort_slots(slots, e)
             raise
-        if wait_s:
-            span.add("cache_wait", wait_s)
-
         extra_headers: list[tuple[str, str]] = []
         if cache is not None:
             token = ("hit" if n_hit == len(slots)
@@ -1872,39 +1897,38 @@ class App:
         # Batch clients get a stable shape: >1 file, or an explicit
         # ``?batch=1``, returns {"results": [...]} even for one image — so
         # a dynamically-assembled batch of size 1 doesn't change schema.
-        t_post = time.monotonic()
-        if len(payloads) == 1 and _qs_last(qs, "batch") != "1":
-            # ETag = response digest (stable content identity: the
-            # formatted payload + serving version — never the envelope,
-            # whose latency/trace fields vary per request).
-            etag = etags[0] or payload_etag(payloads[0], mv.name, mv.version)
-            extra_headers.append(("ETag", f'"{etag}"'))
-            if _etag_matches(inm, etag):
-                # The client already holds exactly this content: 304 with
-                # no body. On a warm cache this costs a decode + digest +
-                # lookup — no device work, no serialization.
-                span.add("postprocess", post_s)
-                return "304 Not Modified", b"", "application/json", extra_headers
-            # Copy before the envelope update: a cached payload dict is
-            # shared across responses and must never be mutated.
-            resp = dict(payloads[0])
-        else:
-            # One result per file part, in upload order — the same
-            # per-image objects a single-image call returns.
-            resp = {"results": payloads}
-        t_ser = time.monotonic()
-        span.add("postprocess", post_s + (t_ser - t_post))
-        resp.update(
-            model=mv.name,
-            model_version=mv.version,
-            latency_ms=round(1e3 * (t_ser - t0), 2),
-            # The trace ID in the body too, so a client that logs response
-            # JSON (loadgen does) can join against the server access log
-            # without plumbing headers through.
-            trace_id=span.trace_id,
-        )
-        body = json.dumps(resp).encode()
-        span.add("serialize", time.monotonic() - t_ser)
+        with stage(span, "postprocess"):
+            if len(payloads) == 1 and _qs_last(qs, "batch") != "1":
+                # ETag = response digest (stable content identity: the
+                # formatted payload + serving version — never the envelope,
+                # whose latency/trace fields vary per request).
+                etag = etags[0] or payload_etag(payloads[0], mv.name,
+                                                mv.version)
+                extra_headers.append(("ETag", f'"{etag}"'))
+                if _etag_matches(inm, etag):
+                    # The client already holds exactly this content: 304
+                    # with no body. On a warm cache this costs a decode +
+                    # digest + lookup — no device work, no serialization.
+                    return ("304 Not Modified", b"", "application/json",
+                            extra_headers)
+                # Copy before the envelope update: a cached payload dict is
+                # shared across responses and must never be mutated.
+                resp = dict(payloads[0])
+            else:
+                # One result per file part, in upload order — the same
+                # per-image objects a single-image call returns.
+                resp = {"results": payloads}
+        with stage(span, "serialize") as ser:
+            resp.update(
+                model=mv.name,
+                model_version=mv.version,
+                latency_ms=round(1e3 * (ser.t0 - t0), 2),
+                # The trace ID in the body too, so a client that logs
+                # response JSON (loadgen does) can join against the server
+                # access log without plumbing headers through.
+                trace_id=span.trace_id,
+            )
+            body = json.dumps(resp).encode()
         return "200 OK", body, "application/json", extra_headers
 
     _SHED_STATUS = {
@@ -1945,7 +1969,7 @@ class App:
         )
 
     @staticmethod
-    def _consult_cache(cache, mv, topk, canvas, hw):
+    def _consult_cache(cache, mv, topk, canvas, hw, span):
         """Content digest + single-flight lookup for one staged image
         (the ``cache_lookup`` span stage's work), shared by the lease and
         submit staging paths. The key itself comes from respcache's
@@ -1953,18 +1977,17 @@ class App:
         (jobs._stage_one, ``bulk=True`` accounting) builds the SAME keys
         with, which is what makes a job's misses pre-warm the interactive
         tier: a change to keying belongs in respcache, never here or in
-        jobs.py. Returns ``(kind, obj, seconds)``; ``(None, None, 0.0)``
+        jobs.py. Returns ``(kind, obj)``; ``(None, None)``, and no stage,
         with the cache disabled."""
         if cache is None:
-            return None, None, 0.0
-        t_c = time.monotonic()
-        key = make_key(mv.name, mv.version, canvas_digest(canvas, hw), topk,
-                       getattr(mv.model_cfg, "dtype", "bfloat16"))
-        kind, obj = cache.begin(key, mv.name)
-        return kind, obj, time.monotonic() - t_c
+            return None, None
+        with stage(span, "cache_lookup"):
+            key = make_key(mv.name, mv.version, canvas_digest(canvas, hw),
+                           topk, getattr(mv.model_cfg, "dtype", "bfloat16"))
+            return cache.begin(key, mv.name)
 
     @staticmethod
-    def _consult_cache_packed(cache, mv, topk, tight, hw, bucket_s):
+    def _consult_cache_packed(cache, mv, topk, tight, hw, bucket_s, span):
         """Ragged-wire twin of :meth:`_consult_cache`: the digest hashes
         the TIGHT decoded bytes + (h, w) + canvas bucket
         (respcache.packed_digest) — the same equivalence classes as
@@ -1972,13 +1995,12 @@ class App:
         function of exactly those three. jobs._stage_one builds the same
         keys for bulk staging; keying changes belong in respcache."""
         if cache is None:
-            return None, None, 0.0
-        t_c = time.monotonic()
-        key = make_key(mv.name, mv.version,
-                       packed_digest(tight, hw, bucket_s), topk,
-                       getattr(mv.model_cfg, "dtype", "bfloat16"))
-        kind, obj = cache.begin(key, mv.name)
-        return kind, obj, time.monotonic() - t_c
+            return None, None
+        with stage(span, "cache_lookup"):
+            key = make_key(mv.name, mv.version,
+                           packed_digest(tight, hw, bucket_s), topk,
+                           getattr(mv.model_cfg, "dtype", "bfloat16"))
+            return cache.begin(key, mv.name)
 
     def _abort_slots(self, slots, exc: BaseException) -> None:
         """Unwind a partially-staged/awaited request: cancel + release its
@@ -2054,28 +2076,21 @@ class App:
         slots = []
         lease = None
         flight = None
-        decode_s = cache_s = 0.0
+        # Every stretch of decode work (header probe, native decode, PIL
+        # fallback) is one ``image_decode`` stage block, every digest +
+        # lookup one ``cache_lookup`` block; the span sums them per request.
+        # Stamped at zero first: a request refused before any decode still
+        # counts in the stage's histogram, as it always has.
+        span.add("image_decode", 0.0)
 
         def consult(canvas, hw):
-            nonlocal cache_s
-            kind, obj, dt = self._consult_cache(cache, mv, topk, canvas, hw)
-            cache_s += dt
-            return kind, obj
+            return self._consult_cache(cache, mv, topk, canvas, hw, span)
 
         def consult_packed(tight, hw, s):
-            nonlocal cache_s
-            kind, obj, dt = self._consult_cache_packed(cache, mv, topk,
-                                                       tight, hw, s)
-            cache_s += dt
-            return kind, obj
-
-        def stamp():
-            span.add("image_decode", decode_s)
-            if cache_s:
-                span.add("cache_lookup", cache_s)
+            return self._consult_cache_packed(cache, mv, topk, tight, hw, s,
+                                              span)
 
         def fail(status, msg):
-            stamp()
             self._abort_slots(slots, RuntimeError(msg))
             return None, (status, json.dumps({"error": msg}).encode(),
                           "application/json")
@@ -2095,22 +2110,20 @@ class App:
                     return fail("400 Bad Request",
                                 f"could not decode image: {where} "
                                 "(chaos: injected decode failure)")
-                t0 = time.monotonic()
-                plan = (native.plan_decode_packed(data, buckets) if ragged
-                        else native.plan_decode(data, buckets, wire))
-                decode_s += time.monotonic() - t0  # header probe
+                with stage(span, "image_decode"):  # header probe
+                    plan = (native.plan_decode_packed(data, buckets) if ragged
+                            else native.plan_decode(data, buckets, wire))
                 if plan is not None and ragged:
                     s, need, _dhw, orig = plan
                     lease = batcher.lease_ragged(need, s, span=span,
                                                  deadline=slo_deadline,
                                                  tenant=tenant)
-                    t0 = time.monotonic()
                     # Tight native-stride decode straight into the leased
                     # arena span — the image's single host copy; the C
                     # side re-validates the span's capacity (an overrun
                     # would corrupt a NEIGHBORING image's bytes).
-                    hw = native.decode_packed_into(data, lease.row, s)
-                    decode_s += time.monotonic() - t0
+                    with stage(span, "image_decode"):
+                        hw = native.decode_packed_into(data, lease.row, s)
                     if hw is None:
                         # Header parsed but the stream didn't decode: give
                         # the span back (it ships as a hole) and let PIL
@@ -2141,10 +2154,9 @@ class App:
                     lease = batcher.lease(row_shape, span=span,
                                           deadline=slo_deadline,
                                           tenant=tenant)
-                    t0 = time.monotonic()
-                    hw = (native.decode_into_row(data, lease.row, s, wire)
-                          if lease.row is not None else None)
-                    decode_s += time.monotonic() - t0
+                    with stage(span, "image_decode"):
+                        hw = (native.decode_into_row(data, lease.row, s, wire)
+                              if lease.row is not None else None)
                     if hw is None:
                         # Header parsed but the stream didn't decode (or the
                         # slab lacks row views): give the slot back and let
@@ -2176,20 +2188,19 @@ class App:
                             lease = flight = None
                         staged = True
                 if not staged and ragged:
-                    t0 = time.monotonic()
                     try:
-                        img = decode_image(data)
+                        with stage(span, "image_decode"):
+                            img = decode_image(data)
                     except Exception:
-                        decode_s += time.monotonic() - t0
                         return fail("400 Bad Request",
                                     f"could not decode image: {where}")
                     # Tight PIL fallback: host-downscale to the bucket if
                     # oversized, no canvas padding — the digest comes free
                     # BEFORE leasing, so cache hits never touch the
                     # batcher at all.
-                    tight, hw, s = fit_to_bucket(img, buckets)
+                    with stage(span, "image_decode"):
+                        tight, hw, s = fit_to_bucket(img, buckets)
                     orig = (img.shape[0], img.shape[1])
-                    decode_s += time.monotonic() - t0
                     kind, obj = consult_packed(tight, hw, s)
                     if kind in ("hit", "wait"):
                         slots.append(("done", obj.payload, obj.etag)
@@ -2208,18 +2219,17 @@ class App:
                                       lease))
                         lease = flight = None
                 elif not staged:
-                    t0 = time.monotonic()
                     try:
-                        img = decode_image(data)
+                        with stage(span, "image_decode"):
+                            img = decode_image(data)
                     except Exception:
-                        decode_s += time.monotonic() - t0
                         return fail("400 Bad Request",
                                     f"could not decode image: {where}")
-                    canvas, hw = pad_to_canvas(img, buckets)
-                    if wire == "yuv420":
-                        canvas = rgb_to_yuv420_canvas(canvas)
+                    with stage(span, "image_decode"):
+                        canvas, hw = pad_to_canvas(img, buckets)
+                        if wire == "yuv420":
+                            canvas = rgb_to_yuv420_canvas(canvas)
                     orig = (img.shape[0], img.shape[1])
-                    decode_s += time.monotonic() - t0
                     kind, obj = consult(canvas, hw)
                     if kind in ("hit", "wait"):
                         slots.append(("done", obj.payload, obj.etag)
@@ -2239,7 +2249,6 @@ class App:
         except ShuttingDown as e:
             if flight is not None:
                 self.cache.abort(flight, e)
-            stamp()
             self._abort_slots(slots, e)
             return None, (
                 "503 Service Unavailable",
@@ -2253,7 +2262,6 @@ class App:
             # the upload toward the request timeout.
             if flight is not None:
                 self.cache.abort(flight, e)
-            stamp()
             self._abort_slots(slots, e)
             return None, self._shed_response(e, tenant, slo_class)
         except (QuotaExceeded, DeadlineExceeded, Degraded) as e:
@@ -2268,7 +2276,6 @@ class App:
                     lease.release()
                 except Exception:
                     pass
-            stamp()
             self._abort_slots(slots, e)
             return None, self._shed_response(e, tenant, slo_class)
         except Exception as e:
@@ -2287,7 +2294,6 @@ class App:
                     pass
             self._abort_slots(slots, e)
             raise
-        stamp()
         return slots, None
 
     def _stage_submits(self, named, span, batcher, mv, topk, cache,
@@ -2299,17 +2305,11 @@ class App:
         builder with one write_row copy. Same slot shapes as
         :meth:`_stage_leases`."""
         slots = []
-        decode_s = cache_s = 0.0
         reject_level = (self.pressure.reject_level
                         if self.pressure is not None else 3)
-
-        def stamp():
-            span.add("image_decode", decode_s)
-            if cache_s:
-                span.add("cache_lookup", cache_s)
+        span.add("image_decode", 0.0)
 
         def fail(status, msg):
-            stamp()
             self._abort_slots(slots, RuntimeError(msg))
             return None, (status, json.dumps({"error": msg}).encode(),
                           "application/json")
@@ -2323,19 +2323,16 @@ class App:
                 return fail("400 Bad Request",
                             f"could not decode image: {where} "
                             "(chaos: injected decode failure)")
-            t0 = time.monotonic()
             try:
-                canvas, hw, orig = mv.engine.prepare_bytes(data)
+                with stage(span, "image_decode"):
+                    canvas, hw, orig = mv.engine.prepare_bytes(data)
             except Exception:
-                decode_s += time.monotonic() - t0
                 return fail("400 Bad Request",
                             f"could not decode image: {where}")
-            decode_s += time.monotonic() - t0
             flight = None
             if cache is not None:
-                kind, obj, dt = self._consult_cache(cache, mv, topk,
-                                                    canvas, hw)
-                cache_s += dt
+                kind, obj = self._consult_cache(cache, mv, topk, canvas, hw,
+                                                span)
                 if kind == "hit":
                     slots.append(("done", obj.payload, obj.etag))
                     continue
@@ -2360,11 +2357,9 @@ class App:
                 # dropped, which is exactly the committed-hole semantics.
                 if flight is not None:
                     self.cache.abort(flight, e)
-                stamp()
                 self._abort_slots(slots, e)
                 return None, self._shed_response(e, tenant, slo_class)
             slots.append(("own", future, orig, flight, None))
-        stamp()
         return slots, None
 
     def _format_row(self, row, orig_hw, topk: int, mv) -> dict:
@@ -2484,6 +2479,18 @@ class App:
         return "200 OK", json.dumps(doc).encode(), "application/json"
 
     def _trace(self, environ):
+        """POST /debug/trace?ms=N[&dir=D][&python=1] — record a
+        ``jax.profiler`` trace of this process for N ms. The recording
+        holds the device's ops, JAX's own host events and the program's
+        ``twd.*`` annotations (utils/tracing.py::stage); the Python tracer
+        (a ``$file:line`` event per call) is off unless ``python=1``: it
+        slows the window it records and costs gigabytes of host memory.
+        A ``twd.clock`` marker right after the start and right before the
+        stop carries ``time.monotonic_ns()``, which puts ``GET
+        /debug/trace`` and the batch records on the recording's clock.
+        ``/stats → profile`` keeps the last recording's interval and a
+        copy of every batch record that met it. One recording at a time:
+        a second POST meanwhile answers 409."""
         qs = urllib.parse.parse_qs(
             environ.get("QUERY_STRING", ""), keep_blank_values=True
         )
@@ -2493,11 +2500,40 @@ class App:
         except ValueError:
             return "400 Bad Request", b'{"error": "ms must be an integer"}', "application/json"
         out_dir = _qs_last(qs, "dir") or "/tmp/tpu_serve_trace"
+        python_tracer = _qs_last(qs, "python") == "1"
+        with self._profile_lock:
+            if self._profiling:
+                return ("409 Conflict",
+                        b'{"error": "a trace is already being recorded"}',
+                        "application/json")
+            self._profiling = True
         import jax
 
-        jax.profiler.start_trace(out_dir)
-        time.sleep(ms / 1e3)
-        jax.profiler.stop_trace()
+        try:
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python_tracer else 0
+            jax.profiler.start_trace(out_dir, profiler_options=options)
+            try:
+                t_start = clock_marker()
+                time.sleep(ms / 1e3)
+                t_stop = clock_marker()
+            finally:
+                jax.profiler.stop_trace()
+            batches = [
+                {**rec, "model": f"{mv.name}@{mv.version}"}
+                for mv in self.registry.serving_entries()
+                if hasattr(mv.batcher, "batch_timeline")
+                for rec in mv.batcher.batch_timeline()
+                if rec["t_open"] <= t_stop
+                and (rec["t_done"] is None or rec["t_done"] >= t_start)
+            ]
+            profile = {"t_start": t_start, "t_stop": t_stop,
+                       "python_tracer": python_tracer, "trace_dir": out_dir,
+                       "batches": batches}
+        finally:
+            with self._profile_lock:
+                self._profiling = False
+        self._profile = profile
         return "200 OK", json.dumps({"trace_dir": out_dir, "captured_ms": ms}).encode(), "application/json"
 
 
@@ -2723,12 +2759,24 @@ class KeepAliveWSGIHandler(BaseHTTPRequestHandler):
         self._responded = False
         # Trace start: the request's bytes are known to be arriving (the
         # keep-alive wait is over), so header-read time is request work,
-        # idle-connection time is not.
+        # idle-connection time is not. The one stage that is not a ``with
+        # stage(...)`` block: it ends in _run_app, once the headers have
+        # named the span it belongs to, so clock and annotation
+        # (``twd.http_read``) are opened by hand here and closed there (or
+        # below, where the request never got that far).
         self._req_t0 = time.monotonic()
+        self._read_ann = TraceAnnotation("twd.http_read")
+        self._read_ann.__enter__()
         try:
             self.handle_one_request()
         finally:
+            self._end_http_read()
             self.rfile.deadline = None
+
+    def _end_http_read(self):
+        ann, self._read_ann = getattr(self, "_read_ann", None), None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def send_response_only(self, code, message=None):
         # Every response funnels through here — including send_error's
@@ -2807,6 +2855,7 @@ class KeepAliveWSGIHandler(BaseHTTPRequestHandler):
         t0 = getattr(self, "_req_t0", None)
         span = Span(accept_trace_id(self.headers.get("X-Trace-Id")), t0=t0)
         span.add("http_read", time.monotonic() - span.t0)
+        self._end_http_read()
         environ = {
             "REQUEST_METHOD": self.command,
             "PATH_INFO": urllib.parse.unquote(path),

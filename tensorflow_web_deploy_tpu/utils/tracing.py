@@ -8,14 +8,31 @@ released), ``cache_lookup`` (content digest of the decoded canvas +
 response-cache consult), ``cache_wait`` (coalesced onto another request's
 in-flight computation for the same content key — single-flight dedup),
 ``staging_write`` (slot commit / fallback canvas copy),
-``queue_wait`` (commit → launch start), ``device_transfer`` (host→device
-ship of the staged slab), ``device_dispatch`` (execute enqueue + async
-D2H start), ``device_execute`` (launch end → outputs on host),
-``postprocess``, ``serialize``. Under the pipelined batcher, one
+``queue_wait`` (commit → launch start), ``device_transfer`` (launch start →
+the ``device_put``s of the staged slab returned), ``device_preprocess``
+(ragged wire: enqueue of the unpack program), ``device_dispatch`` (execute
+enqueue + async D2H start), ``device_execute`` (launch end → outputs on
+host), ``postprocess``, ``serialize``. Under the pipelined batcher, one
 request's ``device_execute`` interval routinely overlaps ANOTHER
 request's ``image_decode``/``device_transfer`` — that concurrency is the
 point, and bench.py's ``pipeline`` block measures it from the batcher's
 batch timeline.
+
+:func:`stage` is the one helper that times a stage: it adds the interval to
+the request's span *and* opens a ``jax.profiler.TraceAnnotation`` named
+``twd.<name>`` (plus a low-cardinality label such as ``c4096 b32``), with
+identities (``seq=``, ``rows=``, ``trace_id=``) as keyword arguments, which
+the profiler keeps as event stats. So a ``POST /debug/trace`` recording
+holds the program's own stages on every thread, next to the device's ops.
+An annotation's name is not always its span stage's: the engine's
+``twd.h2d`` / ``twd.unpack_enqueue`` / ``twd.serve_enqueue`` +
+``twd.d2h_start`` are what the batch's spans receive as ``device_transfer``
+/ ``device_preprocess`` / ``device_dispatch``, ``twd.fetch`` lies inside
+``device_execute``, and ``twd.await_batch`` / ``twd.seal_wait`` have no
+stage at all (another layer already stamps those intervals).
+``device_transfer`` is the *enqueue* of the host→device copy, not the copy:
+``jax.device_put`` returns before the bytes have crossed (PERF.md section 5
+has the chip's reading).
 
 A ``Span`` is created by the HTTP front end at request-accept time (or by
 the WSGI app itself for embedded callers), travels via the WSGI environ
@@ -47,6 +64,8 @@ from __future__ import annotations
 import itertools
 import re
 import time
+
+from jax.profiler import TraceAnnotation
 
 from .locks import named_lock
 
@@ -120,6 +139,14 @@ class Span:
         with self._lock:
             self.meta.setdefault(key, value)
 
+    def note_append(self, key: str, value) -> None:
+        """Metadata that accumulates (the ``batches`` a multi-image request
+        rode): a list without repeats, in arrival order."""
+        with self._lock:
+            have = self.meta.setdefault(key, [])
+            if value not in have:
+                have.append(value)
+
     def stages_copy(self) -> dict[str, float]:
         """Consistent copy for aggregation — safe against in-flight stamps."""
         with self._lock:
@@ -155,7 +182,57 @@ class Span:
         }
 
 
+class stage:
+    """Time one stage: ``with stage(span, "image_decode"): ...`` adds the
+    block's ``time.monotonic()`` interval to ``span`` under ``name`` (repeat
+    stamps sum, as :meth:`Span.add`) and holds a profiler annotation
+    ``twd.<name>[ <label>]`` open over it, ``ids`` as its stats. ``span=None``
+    is an annotation only. ``t0``/``t1`` are the block's own clock reads, for
+    a caller that stamps several spans from them. Inactive, the annotation
+    costs about a microsecond (PERF.md section 6, PR 27)."""
+
+    __slots__ = ("span", "name", "t0", "t1", "_ann")
+
+    def __init__(self, span, name: str, label: str = "", **ids):
+        self.span = span
+        self.name = name
+        self.t0 = self.t1 = None
+        self._ann = TraceAnnotation(
+            f"twd.{name} {label}" if label else f"twd.{name}", **ids)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.monotonic()
+        self._ann.__exit__(None, None, None)
+        if self.span is not None:
+            self.span.add(self.name, self.t1 - self.t0)
+        return False
+
+
+def clock_marker() -> float:
+    """Emit the ``twd.clock`` annotation that ties the profiler's clock
+    (event times count from the recording's own start) to
+    ``time.monotonic()``: the event's ``mono_ns`` stat less its
+    ``start_ns`` is the offset that puts ``batch_timeline``, ``GET
+    /debug/trace`` and the request spans on a recording's time axis.
+    Returns the monotonic seconds the marker carries."""
+    ns = time.monotonic_ns()
+    with TraceAnnotation("twd.clock", mono_ns=ns):
+        pass
+    return ns / 1e9
+
+
 # ----------------------------------------------------- chrome trace export
+
+
+# Batch-record fields that ride a batch event's ``args`` beside the
+# identity ones (Batcher._hand_off names them all).
+_BATCH_ARGS = ("reason", "t_put", "t_pre", "t_fetch", "h2d_bytes",
+               "d2h_bytes", "trace_ids")
 
 
 def _us(t: float) -> float:
@@ -212,7 +289,8 @@ def chrome_trace(models: list[dict], requests: list[tuple],
     — each model becomes one trace process whose threads are the pipeline
     stages: an ``assemble canvas=S`` track per canvas bucket (builder open
     → seal: the decode/commit window) and per-replica ``transfer``/
-    ``execute`` tracks (launch → launched → done). Bulk batches are tagged
+    ``execute``/``fetch`` tracks (launch → launched → done, and fetch
+    thread's turn → done). Bulk batches are tagged
     in the event name and args. ``requests`` is
     ``[(t0_mono, t_end_mono, span_dict)]`` (FlightRecorder.trace_records)
     — rendered as async events on a "requests" process so overlapping
@@ -250,6 +328,10 @@ def chrome_trace(models: list[dict], requests: list[tuple],
                 "seq": rec.get("seq"), "rows": rec.get("rows"),
                 "bucket": rec.get("bucket"), "replica": r,
                 "class": "bulk" if bulk else "interactive",
+                # Whatever else the record holds (the seal's reason, the
+                # engine's t_put/t_pre, bytes each way, the trace IDs that
+                # rode): the request events carry the batch seqs back.
+                **{k: rec[k] for k in _BATCH_ARGS if rec.get(k) is not None},
             }
             legs = [
                 (f"assemble canvas={s}", f"{tag}assemble b{rec.get('seq')}",
@@ -258,6 +340,10 @@ def chrome_trace(models: list[dict], requests: list[tuple],
                  t_launch, t_launched),
                 (f"replica {r} execute", f"{tag}execute b{rec.get('seq')}",
                  t_launched, t_done),
+                # The completion thread's own share of execute: from the
+                # moment it turned to this batch to the outputs on the host.
+                (f"replica {r} fetch", f"{tag}fetch b{rec.get('seq')}",
+                 rec.get("t_fetch"), t_done),
             ]
             for tid, name, a, b in legs:
                 if a is None:
@@ -284,7 +370,7 @@ def chrome_trace(models: list[dict], requests: list[tuple],
             "args": {
                 "trace_id": d.get("trace_id"), "status": d.get("status"),
                 "stages_ms": d.get("stages_ms", {}),
-                **({"model": meta["model"]} if "model" in meta else {}),
+                **{k: meta[k] for k in ("model", "batches") if k in meta},
             },
         })
         events.append({**common, "ph": "e", "ts": _us(t1), "args": {}})

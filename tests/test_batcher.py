@@ -475,3 +475,132 @@ def test_padding_waste_counters_per_bucket():
     assert cell["padded_rows_fraction"] == pytest.approx(
         1 - 3 / (cell["batches"] * 4))
     assert 0 < cell["padded_px_fraction"] < 1
+
+
+# ------------------------------------------------------ lifecycle counters
+
+
+def _flat(life: dict) -> dict:
+    """A lifecycle block as one flat dict of numbers."""
+    return {**{k: v for k, v in life.items() if k != "by_reason"},
+            **{f"by_reason.{k}": v for k, v in life["by_reason"].items()}}
+
+
+def _union_s(intervals) -> float:
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+def test_lifecycle_counters_never_decrease_and_reasons_sum_to_batches():
+    """Read while a dozen batches of every seal reason but drain go by:
+    each counter only grows, and the per-reason counts sum to the total."""
+    eng = FakeSlotEngine(bucket=4, delay_s=0.01)
+    b = Batcher(eng, max_batch=4, max_delay_ms=15, adaptive_delay=False, pipeline_depth=2)
+    b.start()
+    reads, stop = [b.lifecycle_stats()], threading.Event()
+
+    def reader():
+        while not stop.is_set():
+            reads.append(b.lifecycle_stats())
+            time.sleep(0.002)
+
+    t = threading.Thread(target=reader)
+    t.start()
+    try:
+        futures = [b.submit(_canvas(i), (1, 1)) for i in range(16)]     # four full batches
+        for f in futures:
+            f.result(timeout=5)
+        for i in range(3):                                              # three sealed by the window
+            b.submit(_canvas(i), (1, 1)).result(timeout=5)
+        b.submit(_canvas(1), (1, 1), bulk=True)                         # one flushed
+        b.flush_bulk()
+        time.sleep(0.1)
+    finally:
+        stop.set()
+        t.join(timeout=5)
+        b.stop()
+    reads.append(b.lifecycle_stats())
+    for before, after in zip(reads, reads[1:]):
+        a, z = _flat(before), _flat(after)
+        assert set(a) == set(z)
+        assert all(z[k] >= a[k] for k in a), (before, after)
+    last = reads[-1]
+    assert last["batches_total"] == sum(last["by_reason"].values()) == len(eng.batches)
+    assert last["by_reason"]["full"] == 4 and last["by_reason"]["window"] == 3
+    assert last["by_reason"]["flush"] == 1 and last["by_reason"]["drain"] == 0
+    assert set(last["by_reason"]) == {"full", "arena", "window", "flush", "drain"}
+    assert {r["reason"] for r in b.batch_timeline()} == {"full", "window", "flush"}
+
+
+def test_starved_clock_plus_inflight_union_is_elapsed_time_and_phases_tile_a_batch():
+    """A scripted sequence: idle, one batch, idle, three batches that
+    overlap in flight, idle. Between two reads of the block, the starved
+    seconds plus the union of the batches' [t_launch, t_done] is the time
+    between the reads; and each batch's four phases sum to t_done - t_open."""
+    eng = FakeSlotEngine(bucket=2, delay_s=0.03)
+    b = Batcher(eng, max_batch=2, max_delay_ms=2, adaptive_delay=False, pipeline_depth=3)
+    b.start()
+    try:
+        first = b.lifecycle_stats()
+        time.sleep(0.05)
+        b.submit(_canvas(1), (1, 1)).result(timeout=5)
+        time.sleep(0.04)
+        mid = b.lifecycle_stats()
+        assert mid["starved_s_total"] > first["starved_s_total"] + 0.08
+        futures = [b.submit(_canvas(i), (1, 1)) for i in range(6)]     # three full batches, back to back
+        for f in futures:
+            f.result(timeout=5)
+        time.sleep(0.03)
+        # every batch is done once its last future resolved and _batch_done ran
+        deadline = time.monotonic() + 2
+        while b.inflight_batches and time.monotonic() < deadline:
+            time.sleep(0.001)
+        last = b.lifecycle_stats()
+    finally:
+        b.stop()
+    recs = b.batch_timeline()
+    assert len(recs) == 4 and all(r["t_done"] is not None for r in recs)
+    flights = [(r["t_launch"], r["t_done"]) for r in recs]
+    elapsed = last["now_s"] - first["now_s"]
+    starved = last["starved_s_total"] - first["starved_s_total"]
+    assert starved + _union_s(flights) == pytest.approx(elapsed, abs=1e-3)
+    assert _union_s(flights[1:]) < sum(z - a for a, z in flights[1:])     # they did overlap
+    # the four phases tile a batch, one by one and in the totals
+    phases = ("open_s_total", "launch_wait_s_total", "enqueue_s_total", "inflight_s_total")
+    assert sum(last[k] - first[k] for k in phases) == pytest.approx(
+        sum(r["t_done"] - r["t_open"] for r in recs), abs=1e-6)
+    for r in recs:
+        assert (r["t_seal"] - r["t_open"]) + (r["t_launch"] - r["t_seal"]) \
+            + (r["t_launched"] - r["t_launch"]) + (r["t_done"] - r["t_launched"]) \
+            == pytest.approx(r["t_done"] - r["t_open"], abs=1e-9)
+        assert r["t_launched"] <= r["t_fetch"] <= r["t_done"]
+    fetch_wait = last["fetch_wait_s_total"] - first["fetch_wait_s_total"]
+    assert 4 * 0.03 <= fetch_wait <= last["inflight_s_total"] - first["inflight_s_total"] + 1e-9
+
+
+def test_a_failed_dispatch_still_closes_its_lifecycle():
+    class FailingDispatch(FakeEngine):
+        def dispatch_batch(self, canvases, hws):
+            raise RuntimeError("no device")
+
+    b = Batcher(FailingDispatch(), max_batch=2, max_delay_ms=1)
+    b.start()
+    try:
+        with pytest.raises(RuntimeError, match="no device"):
+            b.submit(_canvas(1), (1, 1)).result(timeout=5)
+        time.sleep(0.02)
+        life = b.lifecycle_stats()
+        time.sleep(0.02)
+        later = b.lifecycle_stats()
+    finally:
+        b.stop()
+    (rec,) = b.batch_timeline()
+    assert rec["t_launched"] == rec["t_done"] and rec["t_fetch"] is None
+    assert life["batches_total"] == 1 and b.inflight_batches == 0
+    # nothing is launched any more: the starved clock runs again
+    assert later["starved_s_total"] - life["starved_s_total"] == pytest.approx(
+        later["now_s"] - life["now_s"], abs=1e-6)
