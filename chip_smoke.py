@@ -365,6 +365,16 @@ class Smoke:
                    f"{phase}: the burst formed batches above 1")
 
         self.check_stats(phase, after, wire="ragged")
+        # The mid and large images fill canvases 1024 and 2048, whose unpack
+        # is the Mosaic kernel on a TPU (ops/image.py::unpack_kernel_applies);
+        # the small ones' canvas 256 and every canvas on the CPU take the
+        # XLA gather. The answers above came through whichever ran.
+        life = after["batcher"]["lifecycle"]
+        kernel = life["unpack_kernel_batches_total"]
+        self.check(0 < kernel < life["batches_total"] if self.device["platform"] == "tpu"
+                   else kernel == 0,
+                   f"{phase}: the ragged unpack ran the kernel where it applies, and only there",
+                   unpack_kernel_batches=kernel, batches=life["batches_total"])
         self.stop(phase)
         return flags, answers
 

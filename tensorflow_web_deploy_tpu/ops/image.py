@@ -105,14 +105,35 @@ def fit_to_bucket(
 # (serving/aotcache.py): bump when the unpack computation below changes
 # (arena layout, meta schema, hole convention), so on-disk executables
 # serialized against the old program can never load for the new one.
-RAGGED_UNPACK_VERSION = 2
+RAGGED_UNPACK_VERSION = 3
 
 
-def unpack_ragged(arena, meta, s: int):
+def unpack_kernel_applies(s: int, n_devices: int) -> bool:
+    """Whether a replica should ship its arenas as uint32 words, which
+    :func:`unpack_ragged` hands to the Mosaic kernel: on a TPU, on a mesh
+    of one device (GSPMD cannot partition the kernel over a sharded
+    arena), at a canvas whose rows are whole 512-byte lane rows (512 and
+    its multiples). Everything else ships bytes and takes the XLA
+    formulation: the default 256 canvas, the CPU, sharded replicas."""
+    from .pallas_unpack import kernel_fits
+
+    return (jax.default_backend() == "tpu" and n_devices == 1
+            and kernel_fits(s))
+
+
+def unpack_ragged(arena, meta, s: int, interpret: bool = False):
     """Flat ragged byte arena + per-image meta → host-identical canvases.
 
-    ``arena``: uint8, any shape (flattened here) — the packed tight-row
-    bytes; image ``i``'s pixels occupy ``meta[i, 0] + (y*w + x)*3 + c``.
+    ``arena``: the packed tight-row bytes — image ``i``'s pixels occupy
+    ``meta[i, 0] + (y*w + x)*3 + c`` — as uint8 of any shape (flattened
+    here), or as flat uint32: the same bytes viewed as little-endian words
+    (``buf.view(np.uint32)`` on the host), a whole number of canvases
+    long. The dtype picks the implementation: words go to the Mosaic
+    row-block kernel (ops/pallas_unpack.py; ``interpret`` runs it through
+    the Pallas interpreter, for tests off the chip), bytes to the XLA
+    gather below, which is also the reference the kernel is tested
+    against. Bytes cannot become words on the device: the re-view pads
+    its minor dimension of 4 to 128 lanes.
     ``meta``: int32 [K, 4] rows ``(byte_offset, h, w, valid)``; ``valid=0``
     marks a hole (zero canvas, hw pinned to the 1×1 hole convention the
     classic slab path uses).
@@ -128,12 +149,26 @@ def unpack_ragged(arena, meta, s: int):
     not one byte per index: a per-byte index tensor tiles to 128× its size
     on a TPU (4 GB of temporaries at canvas 512 × batch 32, and past the
     16 GB of a v5e from canvas 1024 on; found compiling for the chip).
+    On a TPU it lowers to a serial loop of one slice and one update per
+    canvas row (5.7 us a row on a v5e), which is what the kernel replaces.
     """
     # named_scope: the ops' metadata carries the phase; the module's name
     # (the caller's jit) is untouched.
     with jax.named_scope("unpack"):
-        flat = jnp.asarray(arena).reshape(-1)  # eager numpy callers trace too
+        arena = jnp.asarray(arena)  # eager numpy callers trace too
         meta = jnp.asarray(meta)
+        ok = meta[:, 3] > 0
+        hws = jnp.where(ok[:, None], meta[:, 1:3], jnp.ones((1, 2), jnp.int32))
+        hws = hws.astype(jnp.int32)
+        if arena.dtype == jnp.uint32:
+            from .pallas_unpack import unpack_planes
+
+            planes = unpack_planes(arena.reshape(-1), meta, s=s,
+                                   interpret=interpret)
+            # [K, 3, s, s] → [K, s, s, 3]: on a TPU the canvases' device
+            # layout is planar already, and this is a re-view, not a copy.
+            return jnp.transpose(planes, (0, 2, 3, 1)), hws
+        flat = arena.reshape(-1)
         row = 3 * s
         # A window that would run past the arena's end is clamped back by
         # dynamic_slice, which would shift the last image's rows: give every
@@ -150,10 +185,7 @@ def unpack_ragged(arena, meta, s: int):
             mask = (valid > 0) & (y < h) & (xb < w * 3)
             return jnp.where(mask, rows, jnp.uint8(0)).reshape(s, s, 3)
 
-        canvases = jax.vmap(one)(meta)
-        ok = meta[:, 3] > 0
-        hws = jnp.where(ok[:, None], meta[:, 1:3], jnp.ones((1, 2), jnp.int32))
-        return canvases, hws.astype(jnp.int32)
+        return jax.vmap(one)(meta), hws
 
 
 # --------------------------------------------------------------------------

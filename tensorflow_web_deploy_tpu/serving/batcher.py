@@ -460,6 +460,7 @@ class Batcher:
             "enqueue_s_total": 0.0, "inflight_s_total": 0.0,
             "fetch_wait_s_total": 0.0,
             "h2d_bytes_total": 0, "d2h_bytes_total": 0,
+            "unpack_kernel_batches_total": 0,
             "starved_s_total": 0.0,
         }
         self._launched_now = 0
@@ -1211,7 +1212,8 @@ class Batcher:
         # The batch record: identity, then the lifecycle's stamps in order
         # (all time.monotonic(); None until reached). The engine fills
         # t_put (both device_puts returned: the copy is *enqueued*), t_pre
-        # (unpack enqueued), h2d_bytes and d2h_bytes where it is handed the
+        # (unpack enqueued), h2d_bytes, d2h_bytes and unpack_kernel (the
+        # ragged unpack ran the Mosaic kernel) where it is handed the
         # record; trace_ids are the request spans that rode.
         rec = {
             "seq": 0, "key": b.key, "rows": len(ready), "bucket": None,
@@ -1221,7 +1223,7 @@ class Batcher:
             "t_open": b.opened_at, "t_seal": time.monotonic(),
             "t_launch": None, "t_put": None, "t_pre": None,
             "t_launched": None, "t_fetch": None, "t_done": None,
-            "h2d_bytes": None, "d2h_bytes": None,
+            "h2d_bytes": None, "d2h_bytes": None, "unpack_kernel": False,
         }
         with self._cond:
             self._dec_pending_locked(b, len(ready))
@@ -1259,6 +1261,7 @@ class Batcher:
                 life["fetch_wait_s_total"] += t_done - rec["t_fetch"]
             life["h2d_bytes_total"] += rec["h2d_bytes"] or 0
             life["d2h_bytes_total"] += rec["d2h_bytes"] or 0
+            life["unpack_kernel_batches_total"] += bool(rec["unpack_kernel"])
             self._launched_now -= 1
             if self._launched_now == 0:
                 self._starved_since = t_done

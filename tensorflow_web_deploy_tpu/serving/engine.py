@@ -1382,7 +1382,10 @@ class InferenceEngine:
         bucket, batch bucket, shipped-rows) shape: flat byte arena + meta →
         (canvases, hws) exactly as the host-padded wire would have staged
         them, sharded for the replica's serve fn. Returns (executable,
-        arena input sharding). AOT-compiled on first use (deserialize from
+        arena input sharding, whether the arena ships as uint32 words for
+        the Mosaic kernel — ops.image.unpack_kernel_applies: a TPU, a
+        one-device mesh, a canvas of whole lane rows; bytes and the XLA
+        gather otherwise). AOT-compiled on first use (deserialize from
         the executable cache when one is configured, else lower+compile,
         with write-back) — compilation happens OUTSIDE the ragged lock,
         which only memoizes the result. Warmup covers every quantized
@@ -1394,7 +1397,8 @@ class InferenceEngine:
             hit = self._ragged_fns.get(key)
         if hit is not None:
             return hit
-        from ..ops.image import RAGGED_UNPACK_VERSION, unpack_ragged
+        from ..ops.image import (RAGGED_UNPACK_VERSION, unpack_kernel_applies,
+                                 unpack_ragged)
 
         # Shard the arena over 'data' only when the byte count divides the
         # submesh; otherwise ship it replicated — the host→device wire is
@@ -1404,10 +1408,12 @@ class InferenceEngine:
         nbytes = rows * canvas_s * canvas_s * 3
         ndev = int(rep.mesh.devices.size)
         arena_sh = rep.data_sharding if nbytes % ndev == 0 else rep.replicated
+        kernel = unpack_kernel_applies(int(canvas_s), ndev)
         akey = self._aot_key(
             rep, "unpack", canvas_s, bucket, rows=rows,
             extra={"unpack_version": RAGGED_UNPACK_VERSION,
-                   "arena_sharded": nbytes % ndev == 0},
+                   "arena_sharded": nbytes % ndev == 0,
+                   "arena_words": kernel},
         )
         aot = self._aot_for(rep)
         exe = (aot.load(akey, rep.mesh.devices.flat)
@@ -1422,9 +1428,10 @@ class InferenceEngine:
                 out_shardings=(rep.data_sharding, rep.data_sharding),
             )
             t0 = time.perf_counter()
+            arena = (jax.ShapeDtypeStruct((nbytes // 4,), jnp.uint32) if kernel
+                     else jax.ShapeDtypeStruct((nbytes,), jnp.uint8))
             exe = fn.lower(
-                jax.ShapeDtypeStruct((nbytes,), jnp.uint8),
-                jax.ShapeDtypeStruct((bucket, 4), jnp.int32),
+                arena, jax.ShapeDtypeStruct((bucket, 4), jnp.int32),
             ).compile()
             aotcache.record_compile_seconds(time.perf_counter() - t0)
             if counts is not None:
@@ -1432,7 +1439,7 @@ class InferenceEngine:
             if aot is not None:
                 aot.store(akey, exe)
         with self._ragged_lock:
-            hit = self._ragged_fns.setdefault(key, (exe, arena_sh))
+            hit = self._ragged_fns.setdefault(key, (exe, arena_sh, kernel))
         return hit
 
     def dispatch_ragged(self, slab: RaggedSlab, n: int, spans=(),
@@ -1444,7 +1451,8 @@ class InferenceEngine:
         feeds the SAME :meth:`fetch_outputs`. Spans gain a
         ``device_preprocess`` stage between transfer and dispatch — the
         enqueue of the unpack program (annotation ``twd.unpack_enqueue``);
-        ``rec`` as in :meth:`dispatch_staged`, with ``t_pre`` besides."""
+        ``rec`` as in :meth:`dispatch_staged`, with ``t_pre`` and
+        ``unpack_kernel`` (the unpack ran the Mosaic kernel) besides."""
         t0 = time.monotonic()
         bucket = self.pick_batch_bucket(n)
         r = self.route_replica() if replica is None else int(replica)
@@ -1455,7 +1463,7 @@ class InferenceEngine:
             rep.slab_bytes_inflight += slab.total_bytes
         guard = rep.dispatch_guard if rep.serialize else _NO_LOCK
         try:
-            outs, t_put, t_pre, nbytes = self._dispatch_ragged_on(
+            outs, t_put, t_pre, nbytes, kernel = self._dispatch_ragged_on(
                 rep, guard, slab, bucket, _batch_ids(rec)
             )
         except BaseException:
@@ -1468,6 +1476,7 @@ class InferenceEngine:
         t_disp = time.monotonic()
         if rec is not None:
             rec["t_put"], rec["t_pre"], rec["h2d_bytes"] = t_put, t_pre, nbytes
+            rec["unpack_kernel"] = kernel
         for s in spans:
             s.add_max("device_transfer", t_put - t0)
             s.add_max("device_preprocess", t_pre - t_put)
@@ -1481,11 +1490,14 @@ class InferenceEngine:
         meta, enqueue unpack, enqueue serve, start the async D2H copy, each
         under its profiler annotation. Returns (outputs, when the second
         ``device_put`` returned, when the unpack was enqueued, bytes
-        shipped)."""
+        shipped, whether the unpack is the kernel)."""
         rows = slab.rows_shipped(bucket)
-        unpack, arena_sh = self._ragged_unpack(rep, slab.canvas_s, bucket, rows)
+        unpack, arena_sh, kernel = self._ragged_unpack(
+            rep, slab.canvas_s, bucket, rows)
         serve = self._serve_exe_for(rep, slab.key[0], bucket)
         arena = slab.buf[: rows * slab.row_bytes]
+        if kernel:
+            arena = arena.view(np.uint32)  # the same bytes on the wire
         meta = slab.meta if bucket == slab.bucket else slab.meta[:bucket]
         label = f"c{slab.canvas_s} b{bucket}"
         with guard:
@@ -1501,7 +1513,7 @@ class InferenceEngine:
             with stage(None, "d2h_start", label, **ids):
                 for leaf in jax.tree.leaves(outs):
                     leaf.copy_to_host_async()
-        return outs, put.t1, pre.t1, arena.nbytes + meta.nbytes
+        return outs, put.t1, pre.t1, arena.nbytes + meta.nbytes, kernel
 
     def dispatch_batch(self, canvases: np.ndarray, hws: np.ndarray,
                        replica: int | None = None):
@@ -1787,8 +1799,10 @@ class InferenceEngine:
             guard = rep.dispatch_guard if rep.serialize else _NO_LOCK
             q = max(1, b // 8)
             for rows in range(q, b + 1, q):
+                unpack, arena_sh, kernel = self._ragged_unpack(rep, s, b, rows)
                 arena0 = np.zeros(rows * s * s * 3, np.uint8)
-                unpack, arena_sh = self._ragged_unpack(rep, s, b, rows)
+                if kernel:
+                    arena0 = arena0.view(np.uint32)
                 # Same XLA:CPU collective-rendezvous discipline as the
                 # request path: the unpack is a multi-device dispatch, and
                 # warmup now executes on several pool threads at once.

@@ -334,6 +334,72 @@ def test_ragged_unpack_programs_cached(tmp_path):
         np.testing.assert_array_equal(a, b)
 
 
+def _kernel_unpack_engine(tmp_path, monkeypatch):
+    """An engine whose canvas-512 unpack is the Mosaic kernel, through the
+    Pallas interpreter: the test steers the two names the engine looks up
+    (on the chip the platform decides; there is no option to do it)."""
+    from functools import partial
+
+    from tensorflow_web_deploy_tpu.ops import image
+
+    monkeypatch.setattr(image, "unpack_kernel_applies", lambda s, n: s == 512)
+    monkeypatch.setattr(image, "unpack_ragged",
+                        partial(image.unpack_ragged, interpret=True))
+    cfg = _cfg("mobilenet_v2", tmp_path, ragged=True, canvas_buckets=(512,),
+               batch_buckets=(2,), max_batch=2, warmup=False)
+    cfg.model.placement = "replicas=8"  # one device a replica, as on a chip
+    return InferenceEngine(cfg)
+
+
+def test_kernel_unpack_roundtrips_and_version_2_entry_is_a_miss(tmp_path, monkeypatch):
+    """The unpack executable that holds the kernel serializes into the
+    cache and loads from it, bit-identical; one stored under
+    ``unpack_version`` 2 (the XLA gather's) is a counted miss for today's
+    program, never a load."""
+    from tensorflow_web_deploy_tpu.ops import image
+
+    rs = np.random.RandomState(3)
+    arena = np.zeros(2 * 512 * 512 * 3, np.uint8)
+    arena[:300 * 411 * 3] = rs.randint(1, 256, 300 * 411 * 3)
+    meta = np.array([[0, 300, 411, 1], [0, 0, 0, 0]], np.int32)
+
+    def unpack_with(eng):
+        exe, _, kernel = eng._ragged_unpack(eng._replicas[0], 512, 2, 2)
+        assert kernel
+        canvases, hws = exe(jnp.asarray(arena.view(np.uint32)), jnp.asarray(meta))
+        return np.asarray(canvases), np.asarray(hws)
+
+    # An entry of the old program's version first: same key but the number.
+    monkeypatch.setattr(image, "RAGGED_UNPACK_VERSION", 2)
+    old = _kernel_unpack_engine(tmp_path, monkeypatch)
+    before = aotcache.stats()
+    unpack_with(old)
+    assert _stats_delta(before, aotcache.stats())["writes_total"] == 1
+    old.close()
+    monkeypatch.setattr(image, "RAGGED_UNPACK_VERSION", 3)
+
+    cold = _kernel_unpack_engine(tmp_path, monkeypatch)
+    before = aotcache.stats()
+    c1, hw1 = unpack_with(cold)
+    d = _stats_delta(before, aotcache.stats())
+    cold.close()
+    assert (d["hits_total"], d["misses_total"], d["writes_total"]) == (0, 1, 1)
+    assert d["corrupt_total"] == 0
+
+    warm = _kernel_unpack_engine(tmp_path, monkeypatch)
+    before = aotcache.stats()
+    c2, hw2 = unpack_with(warm)
+    d = _stats_delta(before, aotcache.stats())
+    warm.close()
+    assert (d["hits_total"], d["misses_total"], d["writes_total"]) == (1, 0, 0)
+    np.testing.assert_array_equal(c1, c2)
+    np.testing.assert_array_equal(hw1, hw2)
+    np.testing.assert_array_equal(c1[0, :300, :411],
+                                  arena[:300 * 411 * 3].reshape(300, 411, 3))
+    assert not c1[0, 300:].any() and not c1[0, :, 411:].any() and not c1[1].any()
+    np.testing.assert_array_equal(hw1, [[300, 411], [1, 1]])
+
+
 @pytest.mark.slow  # ~14 s (three engine boots); the corrupt-degrade
 # contract also rides bench.py cold_start's poisoned phase and check.sh's
 # unfiltered aot smoke stage — tier-1 keeps the cheap unit-level taxonomy.

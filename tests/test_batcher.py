@@ -582,6 +582,45 @@ def test_starved_clock_plus_inflight_union_is_elapsed_time_and_phases_tile_a_bat
     assert 4 * 0.03 <= fetch_wait <= last["inflight_s_total"] - first["inflight_s_total"] + 1e-9
 
 
+@pytest.mark.parametrize("says", [None, "odd", "all"])
+def test_unpack_kernel_batches_are_counted_from_the_batch_record(says):
+    """``unpack_kernel_batches_total`` adds up what the engine notes in the
+    batch record beside ``h2d_bytes``: nothing for an engine that takes no
+    record (or whose unpack is the XLA gather, as on the CPU), every batch
+    or every other one for an engine that says its unpack ran the kernel."""
+    class Noting(FakeSlotEngine):
+        supports_span_tracing = says is not None
+
+        def dispatch_staged(self, slab, n, spans=(), rec=None):
+            if rec is not None:
+                rec["h2d_bytes"] = 1000
+                rec["unpack_kernel"] = says == "all" or rec["seq"] % 2 == 1
+            return super().dispatch_staged(slab, n)
+
+        def fetch_outputs(self, handle, rec=None):
+            return super().fetch_outputs(handle)
+
+    eng = Noting(bucket=2)
+    b = Batcher(eng, max_batch=2, max_delay_ms=2, adaptive_delay=False)
+    b.start()
+    try:
+        assert b.lifecycle_stats()["unpack_kernel_batches_total"] == 0
+        for i in range(6):  # one at a time: six batches
+            b.submit(_canvas(i), (1, 1)).result(timeout=5)
+        deadline = time.monotonic() + 2
+        while b.inflight_batches and time.monotonic() < deadline:
+            time.sleep(0.001)
+        life = b.lifecycle_stats()
+    finally:
+        b.stop()
+    recs = b.batch_timeline()
+    assert life["batches_total"] == len(recs) == 6
+    want = {None: 0, "all": 6, "odd": sum(r["seq"] % 2 for r in recs)}[says]
+    assert life["unpack_kernel_batches_total"] == want
+    assert sum(bool(r["unpack_kernel"]) for r in recs) == want
+    assert life["h2d_bytes_total"] == (0 if says is None else 6000)
+
+
 def test_a_failed_dispatch_still_closes_its_lifecycle():
     class FailingDispatch(FakeEngine):
         def dispatch_batch(self, canvases, hws):

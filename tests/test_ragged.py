@@ -10,6 +10,8 @@ the same image submitted solo.
 
 import io
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -111,6 +113,121 @@ def test_unpack_ragged_tight_arena_end(rng):
     ref_c, ref_hw = _padded(imgs, s)
     np.testing.assert_array_equal(np.asarray(canvases), ref_c)
     np.testing.assert_array_equal(np.asarray(hws), ref_hw)
+
+
+# ------------------------------------------------- unpack op: the kernel
+#
+# ops/pallas_unpack.py through the Pallas interpreter, bit for bit against
+# the XLA formulation above (what the chip's Mosaic accepts of it is
+# tests/test_tpu_compile.py's; what it computes there, chip_smoke.py's).
+
+
+def _deck(s, bucket, rows):
+    """(h, w) or None (a hole) for each of ``bucket`` slots, filling
+    ``rows`` canvases of arena to its last byte: odd and even widths (so
+    every byte shift 0-3 and word shifts across a 128-lane boundary
+    occur), a full canvas, a one-row image, a hole, and a balance that
+    ends the last image on the arena's end."""
+    if bucket == 1:
+        return [(s, s)]
+    head = [(37, s - 11), (s, s), None, (5, 3), (s // 2 + 1, s // 3 + 2)]
+    if bucket > 8:
+        head += [(1, 1), (s // 8, s - 1), None, (s - 1, s // 2 - 3), (3, s),
+                 None, (s // 4, s // 4 + 1)]
+    left = rows * s * s - sum(h * w for h, w in filter(None, head))
+    tail = [(1, left % s)] if left % s else []
+    left -= left % s
+    while left >= s * s:
+        tail.append((s, s))
+        left -= s * s
+    if left:
+        tail.append((left // s, s))
+    assert len(head) + len(tail) <= bucket, (s, bucket, rows)
+    return head + tail + [None] * (bucket - len(head) - len(tail))
+
+
+# (bucket, rows shipped): a sole canvas; arenas shipped short of the bucket.
+@pytest.mark.parametrize("bucket,rows", [(1, 1), (8, 3), (32, 4)])
+@pytest.mark.parametrize("s", [512, 1024, 2048])
+def test_unpack_kernel_matches_xla_formulation(s, bucket, rows):
+    rng = np.random.RandomState(s + bucket)
+    deck = _deck(s, bucket, rows)
+    arena = np.zeros(rows * s * s * 3, np.uint8)
+    meta = np.zeros((bucket, 4), np.int32)
+    off, shifts, wraps = 0, set(), False
+    for i, hw in enumerate(deck):
+        if hw is None:
+            continue
+        h, w = hw
+        arena[off:off + h * w * 3] = rng.randint(1, 256, h * w * 3)
+        meta[i] = (off, h, w, 1)
+        starts = off + np.arange(h) * (w * 3)
+        shifts |= set(starts % 4)
+        wraps |= bool(np.any((starts // 4) % 128 + (w * 3) // 4 > 128))
+        off += h * w * 3
+    assert off == arena.size  # the last image ends on the arena's last byte
+    assert bucket == 1 or (shifts == {0, 1, 2, 3} and wraps)
+    ref_c, ref_hw = jax.jit(lambda a, m: unpack_ragged(a, m, s))(arena, meta)
+    got_c, got_hw = jax.jit(
+        lambda a, m: unpack_ragged(a, m, s, interpret=True)
+    )(arena.view(np.uint32), meta)
+    assert got_c.dtype == jnp.uint8 and got_c.shape == (bucket, s, s, 3)
+    # Compared on the device: 400 MB a side at canvas 2048 x 32.
+    assert bool(jnp.array_equal(got_c, ref_c))
+    np.testing.assert_array_equal(np.asarray(got_hw), np.asarray(ref_hw))
+    # And the formulation itself against the host's pad-to-canvas, where
+    # that is cheap: a full canvas, and a hole.
+    i = deck.index((s, s))
+    np.testing.assert_array_equal(
+        np.asarray(got_c[i]),
+        arena[meta[i, 0]:meta[i, 0] + s * s * 3].reshape(s, s, 3))
+    if None in deck:
+        assert not np.asarray(got_c[deck.index(None)]).any()
+
+
+@pytest.mark.parametrize("s", [512, 2048])
+def test_unpack_kernel_narrow_image_on_the_arenas_last_byte(s):
+    """Stage 1 reads 3s bytes from a row's first byte whatever the row's
+    width: for a narrow image that ends the arena that is past the window,
+    and what it reads there must be masked, not shifted into the row."""
+    rng = np.random.RandomState(s)
+    deck = [(s - 1, s), (1, s - 64 * 7), (64, 7), None]
+    arena = rng.randint(1, 256, s * s * 3).astype(np.uint8)
+    meta = np.zeros((4, 4), np.int32)
+    off = 0
+    for i, (h, w) in enumerate(deck[:3]):
+        meta[i] = (off, h, w, 1)
+        off += h * w * 3
+    assert off == arena.size
+    ref_c, _ = jax.jit(lambda a, m: unpack_ragged(a, m, s))(arena, meta)
+    got_c, _ = jax.jit(
+        lambda a, m: unpack_ragged(a, m, s, interpret=True)
+    )(arena.view(np.uint32), meta)
+    assert bool(jnp.array_equal(got_c, ref_c))
+    np.testing.assert_array_equal(
+        np.asarray(got_c[2, :64, :7]), arena[-64 * 7 * 3:].reshape(64, 7, 3))
+
+
+@pytest.mark.parametrize("s,fits,block", [
+    (256, False, None), (384, False, None), (512, True, 256), (1024, True, 256),
+    (1536, True, 128), (2048, True, 128), (4096, True, 64)])
+def test_unpack_kernel_shapes(s, fits, block):
+    """Canvas 512 and its multiples; a block divides the canvas, is whole
+    u8 tiles of 32 rows and leaves the window inside a one-canvas arena."""
+    from tensorflow_web_deploy_tpu.ops.pallas_unpack import kernel_fits, row_block
+
+    assert kernel_fits(s) is fits
+    if fits:
+        assert row_block(s) == block and s % block == 0 and block % 32 == 0
+        assert (block * 3 * s) // 512 + 8 <= s * 3 * s // 512
+
+
+def test_unpack_kernel_applies_nowhere_on_the_cpu():
+    """The engine ships words only where the kernel runs compiled: never
+    under these tests, whatever the canvas and the mesh."""
+    from tensorflow_web_deploy_tpu.ops.image import unpack_kernel_applies
+
+    assert not any(unpack_kernel_applies(s, n) for s in (256, 512, 4096) for n in (1, 4))
 
 
 def test_rows_shipped_yields_only_warmed_shapes():

@@ -1,8 +1,9 @@
 """The Pallas kernels of the serving path, compiled by Mosaic for a v5e
 that is described and not attached — no chip, about two seconds each.
 
-Interpret mode (tests/test_quant.py, tests/test_pallas_preprocess.py) pins
-what the kernels compute; it cannot see what the TPU compiler refuses. Both
+Interpret mode (tests/test_quant.py, tests/test_pallas_preprocess.py,
+tests/test_ragged.py) pins what the kernels compute; it cannot see what the
+TPU compiler refuses. The depthwise and the preprocess
 kernels had passed every interpret-mode test and were refused here at
 serving shapes for more VMEM than a kernel may use (25.6 MB for one padded
 114×114×32 image, 18 MB for one 2048 canvas, against 16 MB), which is why
@@ -11,6 +12,7 @@ nothing about results or times (chip_smoke.py does the running).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -22,6 +24,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from tensorflow_web_deploy_tpu.ops.depthwise import fused_depthwise_bn
+from tensorflow_web_deploy_tpu.ops.image import unpack_ragged
 from tensorflow_web_deploy_tpu.ops.pallas_preprocess import preprocess_i420
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
@@ -87,3 +90,27 @@ def test_preprocess_compiles_for_v5e(v5e, canvas):
     # One program's HBM, against the 16 GB of a v5e.
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes < 1 << 30
+
+
+# The ragged unpack as the engine builds it (word arena in, canvases out):
+# the smallest canvas the kernel takes, the benchmark cell's two at a full
+# bucket, and an arena shipped short of its bucket.
+@pytest.mark.parametrize("canvas,bucket,rows", [(512, 8, 8), (2048, 32, 32),
+                                                (4096, 32, 32), (4096, 32, 20)])
+def test_ragged_unpack_compiles_for_v5e(v5e, canvas, bucket, rows):
+    arena = jax.ShapeDtypeStruct((rows * canvas * canvas * 3 // 4,), jnp.uint32,
+                                 sharding=v5e)
+    meta = jax.ShapeDtypeStruct((bucket, 4), jnp.int32, sharding=v5e)
+    compiled = jax.jit(
+        lambda a, m: unpack_ragged(a, m, canvas), out_shardings=(v5e, v5e)
+    ).lower(arena, meta).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    # No loop over canvas rows is left, and nothing beside the kernel
+    # touches a canvas: the transpose back to [K, s, s, 3] is a re-view of
+    # the planes the kernel wrote, in the layout jit_serve takes.
+    assert "while(" not in text
+    assert not re.search(r"= u(?:8|32)\[[\d,]+\]\S* (?:copy|transpose|fusion)\(", text)
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 1 << 20  # arena in, canvases out, no copy
+    assert m.output_size_in_bytes < bucket * canvas * canvas * 3 + (1 << 20)

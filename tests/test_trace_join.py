@@ -241,6 +241,7 @@ def test_stats_carry_lifecycle_and_compile_blocks(server):
     assert lb["batches_total"] > la["batches_total"]
     assert sum(lb["by_reason"].values()) == lb["batches_total"]
     assert lb["h2d_bytes_total"] > la["h2d_bytes_total"] and lb["d2h_bytes_total"] > la["d2h_bytes_total"]
+    assert lb["unpack_kernel_batches_total"] == 0  # the CPU takes the XLA gather
     assert lb["now_s"] > la["now_s"] and lb["starved_s_total"] >= la["starved_s_total"]
     # the engine compiled at boot and nothing since: warm-up covered every shape
     assert a["compile"]["backend_compiles_total"] > 0 and a["compile"]["backend_compile_s_total"] > 0
@@ -261,7 +262,8 @@ def test_scopes_name_the_phases_and_the_modules_keep_their_names(server):
     assert re.match(r"HloModule jit_serve\b", text)
     for scope in ("resize", "forward", "topk"):
         assert re.search(rf'op_name="jit\(serve\)/{scope}/', text), scope
-    unpack, _ = engine._ragged_unpack(rep, CANVAS, BATCH, 2)
+    unpack, _, kernel = engine._ragged_unpack(rep, CANVAS, BATCH, 2)
+    assert not kernel  # the CPU takes the XLA gather
     text = unpack.as_text()
     assert re.match(r"HloModule jit__lambda\b", text)
     assert 'op_name="jit(<lambda>)/unpack/' in text
