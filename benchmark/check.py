@@ -38,6 +38,13 @@ near 1 for int8 ones.
 With ``"control"`` set to one of ``reference/forward.py``'s 8-bit precisions
 the served scores are replaced by the top-k of the reference computed in
 that precision: the control that the comparison has to call wrong.
+
+This file is the default ``check.child`` (``manifest.py::named``): a
+configuration whose network the walkers of ``reference/nets.py`` cannot
+express names a child of its own, which reads the same document and prints
+the same answer. What any such child needs is importable from here:
+:func:`compile_cache`, :func:`pixels`, :func:`in_blocks`, :func:`compare`,
+:func:`weight_share` and :func:`answer`.
 """
 
 from __future__ import annotations
@@ -96,37 +103,58 @@ def weight_share(logp_stated: np.ndarray, logp_low: np.ndarray, spread: np.ndarr
     return float(e @ d / (d @ d)) if d @ d > 0 else float("inf")
 
 
-def main() -> int:
-    doc = json.load(sys.stdin)
+def compile_cache() -> None:
+    """The program's own rule (utils/env.py): the variable if it is set,
+    else a fixed directory inside the checkout."""
     import jax
 
-    # The program's own rule (utils/env.py): the variable if it is set, else
-    # a fixed directory inside the checkout.
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           str(Path(__file__).resolve().parent.parent / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+
+
+def pixels(item: dict) -> np.ndarray:
+    """An item's JPEG as the server's decoder yields it: RGB, uint8 [h, w, 3]."""
     from PIL import Image
 
+    return np.asarray(Image.open(io.BytesIO(base64.b64decode(item["jpeg"]))).convert("RGB"))
+
+
+def in_blocks(fn, xs: list[np.ndarray], block: int = BLOCK) -> np.ndarray:
+    """``fn`` over equal-shaped ``xs``, ``block`` at a time (the last block
+    padded with its last row, so one shape compiles), rows concatenated."""
+    out = []
+    for i in range(0, len(xs), block):
+        part = xs[i:i + block]
+        x = np.stack(part + [part[-1]] * (block - len(part)))
+        out.append(np.asarray(fn(x))[:len(part)])
+    return np.concatenate(out)
+
+
+def answer(values: dict[str, float], limits: dict[str, float], images: int, platform: str) -> dict:
+    """The child's one line: each number the configuration limits beside its
+    limit, and ``correct`` only if none is over."""
+    compared = {name: {"value": values[name], "limit": limit} for name, limit in limits.items()}
+    return {"correct": all(c["value"] <= c["limit"] for c in compared.values()), "compared": compared,
+            "images": images, "platform": platform}
+
+
+def main() -> int:
+    doc = json.load(sys.stdin)
+    import jax
+
+    compile_cache()
     from benchmark.reference import forward, weights
 
     m = doc["model"]
     params = weights.make(m["network"], m["input_size"], m["num_classes"], m["width"], doc["seed"])
-    xs = []
-    for item in doc["items"]:
-        pixels = np.asarray(Image.open(io.BytesIO(base64.b64decode(item["jpeg"]))).convert("RGB"))
-        xs.append(np.asarray(forward.preprocess(pixels, m["input_size"])))
+    xs = [np.asarray(forward.preprocess(pixels(item), m["input_size"])) for item in doc["items"]]
 
     def probs_of(precision, weights_=params):
         fn = forward.make_probs(m["network"], m["input_size"], m["num_classes"], m["width"], precision)
         weights_ = jax.device_put(weights_)
-        out = []
-        for i in range(0, len(xs), BLOCK):
-            block = xs[i:i + BLOCK]
-            pad = BLOCK - len(block)
-            x = np.stack(block + [block[-1]] * pad)
-            out.append(np.asarray(fn(weights_, x))[:len(block)])
-        return np.concatenate(out)
+        return in_blocks(lambda x: fn(weights_, x), xs)
 
     ref = probs_of("float32")
     logp = lambda p: np.log(np.maximum(p.astype(np.float64), 1e-300))
@@ -139,10 +167,7 @@ def main() -> int:
         served = [[(int(c), float(low[n, c])) for c in top[n]] for n in range(len(low))]
     values = compare(ref, served)
     values["int8_weight_share"] = weight_share(stated, below, logp(ref).std(axis=1), served)
-    compared = {name: {"value": values[name], "limit": limit} for name, limit in doc["limits"].items()}
-    correct = all(c["value"] <= c["limit"] for c in compared.values())
-    print(json.dumps({"correct": correct, "compared": compared, "images": len(xs),
-                      "platform": jax.devices()[0].platform}))
+    print(json.dumps(answer(values, doc["limits"], len(xs), jax.devices()[0].platform)))
     return 0
 
 

@@ -4,7 +4,8 @@ against the reference in float32, on images and weights drawn from each seed.
 
     python3 benchmark/control.py --workload <cell> --seeds 1,2,3 [--controls int8_weights,fp8_e5m2]
 
-One line per seed and control with the numbers ``check.py`` compares. The
+One line per seed and control with the numbers the configuration's check
+child compares (``check.py`` unless the configuration names another). The
 benchmark's own runs never call this; a ``benchmark`` PR does, when it sets
 or re-reads a limit (the upper reading is the smallest that the control
 gives; the lower one comes from the runs' own ``compared`` values). The
@@ -17,7 +18,6 @@ from __future__ import annotations
 import argparse
 import base64
 import json
-import subprocess
 import sys
 from pathlib import Path
 
@@ -25,8 +25,8 @@ if __package__ in (None, ""):
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark import traffic  # noqa: E402
-from benchmark.manifest import BENCH, ROOT, load_cell  # noqa: E402
-from benchmark.run import SAMPLE_IMAGES, child_env  # noqa: E402
+from benchmark.manifest import load_cell, named  # noqa: E402
+from benchmark.run import check_child  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -36,23 +36,17 @@ def main(argv=None) -> int:
     p.add_argument("--controls", default="int8_weights,fp8_e5m2")
     args = p.parse_args(argv)
     cell = load_cell(args.workload)
+    sample_images = named(cell.config).sample_images
     mix = traffic.Mix.load(cell.traffic_path)
     for seed in [int(s) for s in args.seeds.split(",")]:
         source = traffic.Source(traffic.Corpus(mix, seed), seed)
         images = []
-        while len(images) < SAMPLE_IMAGES:
+        while len(images) < sample_images:
             images += source.take().images
         items = [{"jpeg": base64.b64encode(traffic.variant(b, k)).decode(), "served": []}
-                 for b, k in images[:SAMPLE_IMAGES]]
+                 for b, k in images[:sample_images]]
         for control in args.controls.split(","):
-            doc = {"model": cell.config["model"], "seed": seed, "limits": cell.config["limits"],
-                   "items": items, "control": control}
-            proc = subprocess.run([sys.executable, str(BENCH / "check.py")], input=json.dumps(doc).encode(),
-                                  cwd=ROOT, env=child_env(), capture_output=True, timeout=600)
-            if proc.returncode != 0:
-                print(proc.stderr.decode(errors="replace")[-2000:], file=sys.stderr)
-                return 1
-            out = json.loads(proc.stdout.decode().strip().splitlines()[-1])
+            out = check_child(cell.config, seed, items, control, limit_s=600.0)   # no run of the benchmark: no run's limit
             print(json.dumps({"workload": args.workload, "seed": seed, "control": control, **out}), flush=True)
     return 0
 

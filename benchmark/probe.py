@@ -13,7 +13,7 @@ rate in a traffic file or looks for the cause of a failed request.
         [--serve-dtype int8]                      the program's own lower tier in the stated one's place: a control
 
 Once the server has gone, every long window's own answers go through
-``check.py`` under the configuration's limits, one line each.
+the configuration's check child under its limits, one line each.
 
 Every operation that did not end in a correct 200 goes to
 ``<out>/non_200.jsonl`` with its due and send times, status, reason,
@@ -35,7 +35,7 @@ if __package__ in (None, ""):
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark import loadgen, run as R  # noqa: E402
-from benchmark.manifest import ROOT, load_cell, load_manifest  # noqa: E402
+from benchmark.manifest import ROOT, load_cell, load_manifest, named  # noqa: E402
 
 
 def summarise(w, mix, seconds: float) -> dict:
@@ -92,7 +92,7 @@ def main(argv=None) -> int:
         knee = 0.0
         for rate in [float(r) for r in args.rates.split(",") if r]:
             mix = dataclasses.replace(b.mix, loop="open", rate_per_s=rate)
-            w = R.measure(b.server, mix, b.source, args.seed, args.step_seconds, senders, b.topk)
+            w = R.measure(b.server, mix, b.source, args.seed, args.step_seconds, senders, b.model)
             s = summarise(w, mix, args.step_seconds)
             first, _, third = s["p50_by_third_ms"]
             steady = (s["failed"] == 0 and s["late_share"] < 0.05 and first and third
@@ -106,7 +106,7 @@ def main(argv=None) -> int:
             for i in range(args.long_seeds if args.long_seconds else 0):
                 seed = args.seed + 1000 * (i + 1)
                 mix = b.mix if b.mix.loop == "closed" else dataclasses.replace(b.mix, rate_per_s=rate)
-                w = R.measure(b.server, mix, b.source, seed, args.long_seconds, senders, b.topk)
+                w = R.measure(b.server, mix, b.source, seed, args.long_seconds, senders, b.model)
                 windows.append((w, seed))
                 s = summarise(w, mix, args.long_seconds)
                 bad = R.failure_log(w.outcomes)
@@ -129,7 +129,7 @@ def main(argv=None) -> int:
             trace_dir = b.work / "trace"
             shutil.rmtree(trace_dir, ignore_errors=True)
             mix = b.mix if b.mix.loop == "closed" else dataclasses.replace(b.mix, rate_per_s=rate or b.mix.rate_per_s)
-            w = R.measure(b.server, mix, b.source, args.seed + 7, args.trace_seconds, senders, b.topk, trace_dir)
+            w = R.measure(b.server, mix, b.source, args.seed + 7, args.trace_seconds, senders, b.model, trace_dir)
             windows.append((w, args.seed + 7))
             files = list(trace_dir.rglob("*.xplane.pb"))
             note(trace_status=w.result.trace_status, files=[(str(f), f.stat().st_size) for f in files],
@@ -143,7 +143,7 @@ def main(argv=None) -> int:
     finally:
         note(server_exit=b.server.stop())
     for i, (w, seed) in enumerate(windows):
-        sample = R.draw_sample(w.outcomes, b.source.requests, seed)
+        sample = R.draw_sample(w.outcomes, b.source.requests, seed, named(cell.config).sample_images)
         controls = [c for c in args.controls.split(",") if c] if i == len(windows) - 1 else []
         for control in [None, *controls]:
             t = time.monotonic()

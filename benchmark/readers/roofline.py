@@ -5,6 +5,11 @@ window's batches by (canvas, batch bucket) with their real rows and pixels.
 The traced stretch is taken to hold the window's mix of batches: the floor
 per call is the window's mean, the time per call is the trace's.
 
+Operations and bytes of a serve call are the architecture's: they come
+from the floors module the configuration names (``cost.load_floors``), per
+row of that table, so a kernel's share reads the same work whatever
+implements it.
+
 - ``serve``: least seconds for the window's mean serve call (the larger of
   operations over peak FLOP/s and bytes over peak bytes/s, summed per batch)
   over the traced seconds per call. Compute binds at large batches,
@@ -27,14 +32,13 @@ def read(ctx, kind, serve_match="serve", unpack_match="unpack"):
     if not batches:
         return None
     peak_flops, peak_bytes = device_peak(ctx.device["kind"])
-    model = ctx.config["model"]
+    model, floors = ctx.config["model"], cost.load_floors(ctx.config)
     serve_s, serve_calls = program_time(ctx, serve_match)
     unpack_s, unpack_calls = program_time(ctx, unpack_match)
     if kind == "serve":
         if not serve_calls:
             return None
-        floor = sum(r["batches"] * cost.serve_floor_s(
-            model, r["canvas"], r["rows_real"] / r["batches"], peak_flops, peak_bytes)[0] for r in rows)
+        floor = sum(r["batches"] * cost.serve_floor_s(floors, model, r, peak_flops, peak_bytes)[0] for r in rows)
         return 100.0 * (floor / batches) / (serve_s / serve_calls)
     if kind == "unpack":
         if not unpack_calls:
@@ -47,5 +51,5 @@ def read(ctx, kind, serve_match="serve", unpack_match="unpack"):
         if not serve_calls:
             return None
         images = serve_calls * sum(r["rows_real"] for r in rows) / batches
-        return 100.0 * images * cost.image_flops(model) / ((serve_s + unpack_s) * peak_flops)
+        return 100.0 * images * cost.step_flops(floors, model, rows) / ((serve_s + unpack_s) * peak_flops)
     raise ValueError(f"roofline reader: unknown kind {kind!r}")

@@ -6,14 +6,17 @@ calls :func:`make` with the same seed and never reads that export. Plain
 numpy on the host: 24 M values take under a second, and the process that
 writes the export must stay off the chip (the server child needs it).
 
-    python benchmark/reference/weights.py <network> <input> <classes> <width> <seed> <dir>
+    python benchmark/reference/weights.py <the configuration's model block, as JSON> <seed> <dir>
 
 writes the export with orbax (the format ``--ckpt`` reads). It runs as a
-child with ``JAX_PLATFORMS=cpu``: orbax imports JAX.
+child with ``JAX_PLATFORMS=cpu``: orbax imports JAX. This is the default
+``weights.script`` (``manifest.py::named``); the package's docstring has
+what a configuration's own script keeps to, and where this one does not.
 """
 
 from __future__ import annotations
 
+import json
 import sys
 from pathlib import Path
 
@@ -85,8 +88,10 @@ def write_export(flat: dict[str, np.ndarray], directory: str) -> None:
 
 
 def main(argv) -> int:
-    network, input_size, classes, width, seed, directory = argv
-    write_export(make(network, int(input_size), int(classes), float(width), int(seed)), directory)
+    model, seed, directory = argv
+    m = json.loads(model)
+    write_export(make(m["network"], int(m["input_size"]), int(m["num_classes"]), float(m["width"]), int(seed)),
+                 directory)
     return 0
 
 

@@ -4,14 +4,16 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The parent never imports JAX. It makes the weights from the seed (a child on
-the CPU writes them as a ``--ckpt`` export), builds the traffic from the seed
+the CPU, the script the configuration names, writes them as a ``--ckpt``
+export), builds the traffic from the seed
 meanwhile, boots ``python server.py`` with the configuration's flags as a
 child on a free port, waits for ``listening on`` (a child that had to compile
 is stopped and booted again, so that the one measured only loads), sends each
 distinct image shape once (untimed), runs the window, waits for every outstanding answer,
 reads ``/stats``, and only then sends SIGTERM. Once the server is gone and
-the chip is free, ``check.py`` (a child, on the chip) runs the plain
-reference over a sample of the window's own answers and decides
+the chip is free, the configuration's check child (``check.py`` unless it
+names another; on the chip) runs the plain reference over a sample of the
+window's own answers and decides
 ``correct``; with ``--trace 1`` ``xplane.py`` (a child, on the CPU) reduces
 the profiler trace that the server wrote during the window.
 
@@ -43,12 +45,11 @@ if __package__ in (None, ""):
 import numpy as np  # noqa: E402
 
 from benchmark import loadgen, traffic  # noqa: E402
-from benchmark.manifest import BENCH, ROOT, Cell, load_cell, load_reader  # noqa: E402
+from benchmark.manifest import BENCH, ROOT, Cell, load_cell, load_reader, named  # noqa: E402
 from benchmark.serverchild import ServerChild  # noqa: E402
 
-SAMPLE_IMAGES = 128      # answers of the window that the reference re-computes
 TRACE_MS = 2500          # the profiler's window inside the measured window: one whole wave of batches
-CHECK_LIMIT_S = 240.0
+RUN_LIMIT_S = 360.0      # what the driver allows a run that does not compile, set-up, window and check together
 
 
 def work_dir(cell: Cell) -> Path:
@@ -84,32 +85,49 @@ def server_flags(config: dict, work: Path, serve_dtype: str | None = None) -> li
 
 
 def write_weights(config: dict, seed: int, export_dir: Path) -> subprocess.Popen:
-    m = config["model"]
+    """The configuration's weights script, a child on the CPU: the model
+    block, the seed and where the export goes are all it is told."""
     return subprocess.Popen(
-        [sys.executable, str(BENCH / "reference" / "weights.py"), m["network"], str(m["input_size"]),
-         str(m["num_classes"]), str(m["width"]), str(seed), str(export_dir)],
+        [sys.executable, str(named(config).weights), json.dumps(config["model"]), str(seed), str(export_dir)],
         cwd=ROOT, env=child_env("cpu"), stdout=subprocess.DEVNULL, stderr=subprocess.PIPE)
 
 
-def judge(outcome: loadgen.Outcome, topk: int) -> bool:
-    """Is this a correct 200: one list of ``topk`` finite scores per image?
-    Sets ``outcome.answers`` (per image, [(index, score)...])."""
+def judge(outcome: loadgen.Outcome, model: dict) -> bool:
+    """Is this a correct 200? Per image either ``predictions``, one list of
+    ``model.topk`` finite scores (a model that answers one step), or
+    ``steps``, ``model.answer_steps`` such lists. Sets ``outcome.answers``
+    per image as it came: [(index, score)...], or one such list a step."""
     if outcome.status != 200:
         return False
+    topk, steps = int(model["topk"]), int(model.get("answer_steps", 1))
+
+    def pairs(predictions) -> list[tuple[int, float]]:
+        out = [(int(p["index"]), float(p["score"])) for p in predictions]
+        if len(out) != topk or not all(math.isfinite(s) for _, s in out):
+            raise ValueError("not topk finite scores")
+        return out
+
+    def answer(result: dict):
+        if "steps" in result:
+            if len(result["steps"]) != steps:
+                raise ValueError("another number of steps than the configuration states")
+            return [pairs(step) for step in result["steps"]]
+        if steps != 1:
+            raise ValueError("one step where the configuration states several")
+        return pairs(result["predictions"])
+
     try:
         doc = json.loads(outcome.body)
-        results = doc["results"] if "results" in doc else [doc]
-        answers = [[(int(p["index"]), float(p["score"])) for p in r["predictions"]] for r in results]
+        answers = [answer(r) for r in (doc["results"] if "results" in doc else [doc])]
     except (ValueError, KeyError, TypeError):
         return False
-    if len(answers) != outcome.images or any(
-            len(a) != topk or not all(math.isfinite(s) for _, s in a) for a in answers):
+    if len(answers) != outcome.images:
         return False
     outcome.answers = answers
     return True
 
 
-def warm_up(server: ServerChild, corpus: traffic.Corpus, source: traffic.Source, topk: int) -> int:
+def warm_up(server: ServerChild, corpus: traffic.Corpus, source: traffic.Source, model: dict) -> int:
     """Every distinct image shape once, through the entry the window drives,
     so that the window meets no first-use cost. Returns requests sent."""
     opened: list = []
@@ -123,7 +141,7 @@ def warm_up(server: ServerChild, corpus: traffic.Corpus, source: traffic.Source,
             body, ctype = req.body()
             out = loadgen.Outcome(req.index, len(req.images))
             conn.post("/predict", body, ctype, out)
-            if not judge(out, topk):
+            if not judge(out, model):
                 raise RuntimeError(f"warm-up request {sent}: status {out.status}, body {out.body[:300]!r}")
             sent += 1
     finally:
@@ -131,9 +149,10 @@ def warm_up(server: ServerChild, corpus: traffic.Corpus, source: traffic.Source,
     return sent
 
 
-def draw_sample(outcomes: list[loadgen.Outcome], requests: dict, seed: int) -> list[tuple[loadgen.Outcome, int]]:
-    """(request, image ordinal) pairs for the reference: the request with the
-    most pixels (the longest), then a draw from the seed."""
+def draw_sample(outcomes: list[loadgen.Outcome], requests: dict, seed: int,
+                images: int) -> list[tuple[loadgen.Outcome, int]]:
+    """``images`` (request, image ordinal) pairs for the reference: the
+    request with the most pixels (the longest), then a draw from the seed."""
     ok = [o for o in outcomes if o.answers is not None]
     if not ok:
         return []
@@ -142,29 +161,41 @@ def draw_sample(outcomes: list[loadgen.Outcome], requests: dict, seed: int) -> l
     picks = [(longest, i) for i in range(longest.images)]
     order = [ok[i] for i in rs.permutation(len(ok))]
     for o in order:
-        if len(picks) >= SAMPLE_IMAGES:
+        if len(picks) >= images:
             break
         if o is not longest:
             picks += [(o, i) for i in range(o.images)]
-    return picks[:SAMPLE_IMAGES]
+    return picks[:images]
 
 
-def run_check(cell: Cell, seed: int, sample, requests: dict, control: str | None = None) -> dict:
-    """The reference, in a child that may take the chip: the server is gone."""
-    m = cell.config["model"]
+def check_child(config: dict, seed: int, items: list[dict], control: str | None, limit_s: float) -> dict:
+    """One answer of the configuration's check child: the document on its
+    standard input (the model block, the seed, the limits, per item the JPEG
+    and what was served for it, the control's name or none), its last line
+    of standard output back (``correct``, ``compared``, ``images``,
+    ``platform``). It may take the chip: no server is up."""
+    child = named(config).check
+    doc = {"model": config["model"], "seed": seed, "limits": config["limits"], "items": items,
+           "control": control}
+    proc = subprocess.run([sys.executable, str(child)], input=json.dumps(doc).encode(), cwd=ROOT,
+                          env=child_env(), stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=limit_s)
+    if proc.returncode != 0:
+        raise RuntimeError(f"{child.name} exited {proc.returncode}:\n{proc.stderr.decode(errors='replace')[-3000:]}")
+    return json.loads(proc.stdout.decode().strip().splitlines()[-1])
+
+
+def run_check(cell: Cell, seed: int, sample, requests: dict, control: str | None = None,
+              window_s: float = 0.0) -> dict:
+    """The reference over ``sample``: each image's JPEG as it was sent and
+    its answer as it came. The child has the configuration's
+    ``check.limit_s``, and never more than a run has left beside its window."""
     items = []
     for o, i in sample:
         base, k = requests[o.index].images[i]
         items.append({"jpeg": base64.b64encode(traffic.variant(base, k)).decode(),
                       "served": o.answers[i]})
-    doc = {"model": m, "seed": seed, "limits": cell.config["limits"], "items": items,
-           "control": control}
-    proc = subprocess.run([sys.executable, str(BENCH / "check.py")], input=json.dumps(doc).encode(),
-                          cwd=ROOT, env=child_env(), stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                          timeout=CHECK_LIMIT_S)
-    if proc.returncode != 0:
-        raise RuntimeError(f"check.py exited {proc.returncode}:\n{proc.stderr.decode(errors='replace')[-3000:]}")
-    return json.loads(proc.stdout.decode().strip().splitlines()[-1])
+    limit_s = min(named(cell.config).limit_s, RUN_LIMIT_S - window_s)
+    return check_child(cell.config, seed, items, control, limit_s)
 
 
 def reduce_trace(trace_dir: Path) -> dict | None:
@@ -190,7 +221,7 @@ class RecordingSource:
 
 
 def measure(server: ServerChild, mix: traffic.Mix, source, seed: int, seconds: float,
-            senders: int, topk: int, trace_dir: Path | None = None) -> SimpleNamespace:
+            senders: int, model: dict, trace_dir: Path | None = None) -> SimpleNamespace:
     """The window: /stats, the load, every answer awaited, /stats again."""
     due = None
     if mix.loop == "open":
@@ -207,7 +238,7 @@ def measure(server: ServerChild, mix: traffic.Mix, source, seed: int, seconds: f
     if trace is not None and result.trace_status != 200:
         raise RuntimeError(f"POST {trace[1]} ended with {result.trace_status!r}, not 200: no trace to read")
     for o in result.outcomes:
-        judge(o, topk)
+        judge(o, model)
     return SimpleNamespace(before=before, after=after, result=result, outcomes=result.outcomes)
 
 
@@ -242,7 +273,7 @@ def boot(cell: Cell, seed: int, *, extra_flags: tuple[str, ...] = (), env: dict 
     builder.start()
     _, err = weights.communicate(timeout=300)
     if weights.returncode != 0:
-        raise RuntimeError(f"weights.py exited {weights.returncode}:\n{err.decode(errors='replace')[-3000:]}")
+        raise RuntimeError(f"{named(config).weights.name} exited {weights.returncode}:\n{err.decode(errors='replace')[-3000:]}")
     flags = [*server_flags(config, work, serve_dtype), *extra_flags]
     server = ServerChild(flags, work / "server.log", env={**child_env(), **(env or {})})
     compile_boot_s, stats_compile_boot = 0.0, None
@@ -267,14 +298,13 @@ def boot(cell: Cell, seed: int, *, extra_flags: tuple[str, ...] = (), env: dict 
         device = {"platform": health.get("platform"), "kind": health.get("device_kind"),
                   "count": health.get("devices")}
         source = RecordingSource(traffic.Source(corpus_box["corpus"], seed))
-        topk = int(config["model"]["topk"])
-        warm_up(server, corpus_box["corpus"], source, topk)
+        warm_up(server, corpus_box["corpus"], source, config["model"])
     except BaseException:
         server.kill()
         raise
     return SimpleNamespace(server=server, mix=mix, work=work, corpus=corpus_box["corpus"],
                            source=source, device=device, boot_s=boot_s, compile_boot_s=compile_boot_s,
-                           stats_boot=stats_boot, stats_compile_boot=stats_compile_boot, topk=topk)
+                           stats_boot=stats_boot, stats_compile_boot=stats_compile_boot, model=config["model"])
 
 
 class WrongDevice(RuntimeError):
@@ -300,7 +330,7 @@ def drive(cell: Cell, seed: int, seconds: float, trace: bool, *, require_platfor
             shutil.rmtree(trace_dir, ignore_errors=True)
         setup_s = time.monotonic() - T_PROCESS_START
         w = measure(b.server, b.mix, b.source, seed, seconds, int(cell.config["http_workers"]),
-                    b.topk, trace_dir)
+                    b.model, trace_dir)
         peak = max((d.get("peak_bytes_in_use", 0) for d in w.after.get("device_memory", [])), default=0)
     finally:
         rc = b.server.stop()
@@ -318,9 +348,9 @@ def report(ctx, control: str | None = None) -> dict:
     """The result line: the check over a sample of ``ctx.outcomes``, then
     the cell's metrics (end-to-end, or per-layer where the run was traced)."""
     cell = ctx.cell
-    sample = draw_sample(ctx.outcomes, ctx.requests, ctx.seed)
+    sample = draw_sample(ctx.outcomes, ctx.requests, ctx.seed, named(cell.config).sample_images)
     t_check = time.monotonic()
-    check = (run_check(cell, ctx.seed, sample, ctx.requests, control) if sample
+    check = (run_check(cell, ctx.seed, sample, ctx.requests, control, ctx.seconds) if sample
              else {"correct": False, "compared": {}})
     check_s = time.monotonic() - t_check
     line = {"correct": bool(check["correct"]), "attempted": len(ctx.outcomes),

@@ -14,7 +14,10 @@ Runs as a child with ``JAX_PLATFORMS=cpu``: reading the file needs JAX's
   first to the last event of any plane;
 - ``programs``: the module-level line (``XLA Modules``) summed by program
   name with the trailing ``(id)`` dropped: [name, seconds, calls];
-- ``device_ops``: op-level events summed by name, longest first;
+- ``ops``: op-level events summed by name, longest first, every one of
+  them: [name, seconds, calls] (``readers/op_time.py`` times a kernel inside
+  a program from it); ``device_ops``: the ten longest, [name, seconds], as
+  the breakdown prints them;
 - ``idle_gaps``: the longest gaps of the union, each named by the host event
   that covers most of it (profiler annotations before ``$file:line`` Python
   frames, which are every gap's backdrop).
@@ -92,18 +95,21 @@ def reduce(planes: list[dict], top: int = 10) -> dict:
         merged = union([(s, s + d) for s, d, _ in by_line[OPS_LINE]])
         busy.append(sum(e - s for s, e in merged))
         for s, d, n in by_line[OPS_LINE]:
-            n = short_op(n)
-            ops[n] = ops.get(n, 0.0) + d
+            cell = ops.setdefault(short_op(n), [0.0, 0])
+            cell[0] += d
+            cell[1] += 1
         for s, d, n in by_line.get(MODULES_LINE, []):
             cell = programs.setdefault(_ID.sub("", n), [0.0, 0])
             cell[0] += d
             cell[1] += 1
         gaps += [(b[0] - a[1], (a[1], b[0])) for a, b in zip(merged, merged[1:])]
     gaps.sort(reverse=True)
+    ops = sorted(([n, t, c] for n, (t, c) in ops.items()), key=lambda r: -r[1])
     return {
         "busy_s": sum(busy) / len(busy), "window_s": window_s, "devices": len(devices),
         "programs": sorted(([n, t, c] for n, (t, c) in programs.items()), key=lambda r: -r[1]),
-        "device_ops": sorted(([n, t] for n, t in ops.items()), key=lambda r: -r[1])[:top],
+        "device_ops": [[n, t] for n, t, _ in ops[:top]],
+        "ops": ops,
         "idle_gaps": [[name_gap(g, host_events), length] for length, g in gaps[:top]],
     }
 

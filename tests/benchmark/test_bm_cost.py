@@ -5,13 +5,18 @@ from types import SimpleNamespace
 import pytest
 
 from benchmark import cost, loadgen, peaks
-from benchmark.reference import nets
+from benchmark.reference import conv_floors, nets
 
 MV2 = {"arch": "mobilenet_v2", "network": "benchmark/reference/nets.py::mobilenet_v2", "input_size": 224,
        "num_classes": 1000, "width": 1.0, "dtype": "bfloat16", "topk": 5}
 IV3 = {"arch": "inception_v3", "network": "benchmark/reference/nets.py::inception_v3", "input_size": 299,
        "num_classes": 1000, "width": 1.0, "dtype": "bfloat16", "topk": 5}
 V5E = (197e12, 819e9)
+FLOORS = cost.load_floors({"model": IV3})   # no ``floors.module`` named: the conv classifier's
+
+
+def _row(canvas, rows_real, batches=1, **more):
+    return {"canvas": canvas, "batches": batches, "rows_real": rows_real, **more}
 
 
 def test_hand_worked_layers():
@@ -29,10 +34,10 @@ def test_hand_worked_layers():
 
 
 def test_published_sizes():
-    assert cost.model_macs("inception_v3", 299, 1000, 1.0) == pytest.approx(5.71e9, rel=0.01)
-    assert cost.model_macs("mobilenet_v2", 224, 1000, 1.0) == pytest.approx(0.30e9, rel=0.02)
-    assert cost.param_count("inception_v3", 299, 1000, 1.0, with_stats=False) == pytest.approx(23.8e6, rel=0.01)
-    assert cost.param_count("mobilenet_v2", 224, 1000, 1.0, with_stats=False) == pytest.approx(3.5e6, rel=0.01)
+    assert conv_floors.model_macs("inception_v3", 299, 1000, 1.0) == pytest.approx(5.71e9, rel=0.01)
+    assert conv_floors.model_macs("mobilenet_v2", 224, 1000, 1.0) == pytest.approx(0.30e9, rel=0.02)
+    assert conv_floors.param_count("inception_v3", 299, 1000, 1.0, with_stats=False) == pytest.approx(23.8e6, rel=0.01)
+    assert conv_floors.param_count("mobilenet_v2", 224, 1000, 1.0, with_stats=False) == pytest.approx(3.5e6, rel=0.01)
 
 
 @pytest.mark.parametrize("model", [MV2, IV3], ids=lambda m: m["arch"])
@@ -42,10 +47,10 @@ def test_walkers_equal_the_programs(model):
                           input_size=(model["input_size"],) * 2, dtype="bfloat16")
     theirs = costmodel.model_cost(cfg)
     args = (model["network"], model["input_size"], 1000, 1.0)
-    assert cost.model_macs(*args) == theirs["macs_per_image"]
-    assert cost.param_count(*args, with_stats=False) == theirs["param_count"]
+    assert conv_floors.model_macs(*args) == theirs["macs_per_image"]
+    assert conv_floors.param_count(*args, with_stats=False) == theirs["param_count"]
     for s in (256, 2048):
-        assert cost.matmul_resize_flops(s, model["input_size"]) == costmodel.preprocess_flops(
+        assert conv_floors.matmul_resize_flops(s, model["input_size"]) == costmodel.preprocess_flops(
             s, (model["input_size"],) * 2)
 
 
@@ -70,14 +75,14 @@ def test_peaks_and_percentile_equal_the_programs():
 def test_serve_floor_says_which_peak_binds():
     # one MobileNetV2 image on a 2048 canvas: 12.6 MB of canvas at 819 GB/s
     # (15 us) outweighs 0.6 GFLOP at 197 TFLOP/s (3 us)
-    t, bound = cost.serve_floor_s(MV2, 2048, 1, *V5E)
+    t, bound = cost.serve_floor_s(FLOORS, MV2, _row(2048, 1), *V5E)
     assert bound == "bandwidth"
-    assert t == pytest.approx((cost.param_count("mobilenet_v2", 224, 1000, 1.0) * 2
+    assert t == pytest.approx((conv_floors.param_count("mobilenet_v2", 224, 1000, 1.0) * 2
                                + 2048 * 2048 * 3 + 40) / 819e9)
     # 32 Inception images on 256 canvases: 366 GFLOP (1.9 ms) against 54 MB (0.07 ms)
-    t, bound = cost.serve_floor_s(IV3, 256, 32, *V5E)
+    t, bound = cost.serve_floor_s(FLOORS, IV3, _row(256, 32), *V5E)
     assert bound == "compute"
-    assert t == pytest.approx(32 * (2 * cost.model_macs("inception_v3", 299, 1000, 1.0)
+    assert t == pytest.approx(32 * (2 * conv_floors.model_macs("inception_v3", 299, 1000, 1.0)
                                     + 8 * 299 * 299 * 3) / 197e12)
     assert cost.unpack_floor_s(3e6, 5e6, 819e9) == pytest.approx(8e6 / 819e9)
 
@@ -98,7 +103,7 @@ def test_a_program_that_runs_at_its_floor_reads_100_and_never_more(model):
              "px_real": 60 * 400 * 300},
             {"canvas": 2048, "batch_bucket": 32, "batches": 5, "rows_real": 160, "rows_dispatched": 160,
              "px_real": 160 * 1900 * 1400}]
-    serve_floor = sum(r["batches"] * cost.serve_floor_s(model, r["canvas"], r["rows_real"] / r["batches"], *V5E)[0]
+    serve_floor = sum(r["batches"] * cost.serve_floor_s(FLOORS, model, r, *V5E)[0]
                       for r in rows)
     unpack_floor = sum(cost.unpack_floor_s(3 * r["px_real"], r["rows_real"] * r["canvas"] ** 2 * 3, V5E[1])
                        for r in rows)

@@ -143,7 +143,7 @@ def test_sheds_timeouts_and_closed_connections_are_failed_not_dropped(server):
                     timeout_s=0.3, due=due)
     assert len(r.outcomes) == 10                      # attempted: everything that was due
     for o in r.outcomes:
-        judge(o, 5)
+        judge(o, {"topk": 5})
     ok = [o for o in r.outcomes if o.answers is not None]
     log = failure_log(r.outcomes)
     assert len(ok) + len(log) == 10 and len(ok) >= 1
@@ -159,12 +159,12 @@ def test_sheds_timeouts_and_closed_connections_are_failed_not_dropped(server):
 def test_judge_wants_topk_finite_scores_per_image():
     good = loadgen.Outcome(0, 2, status=200, body=json.dumps(
         {"results": [{"predictions": [{"index": i, "score": 0.1} for i in range(5)]}] * 2}).encode())
-    assert judge(good, 5) and len(good.answers) == 2
+    assert judge(good, {"topk": 5}) and len(good.answers) == 2
     short = loadgen.Outcome(0, 1, status=200, body=json.dumps(
         {"predictions": [{"index": 1, "score": 0.5}]}).encode())
     nan = loadgen.Outcome(0, 1, status=200, body=OK_BODY.replace(b"0.1", b"NaN", 1))
     shed = loadgen.Outcome(0, 1, status=429, body=b'{"reason": "quota"}')
-    assert not judge(short, 5) and not judge(nan, 5) and not judge(shed, 5)
+    assert not any(judge(o, {"topk": 5}) for o in (short, nan, shed))
     assert shed.shed_reason() == "quota" and good.shed_reason() is None
 
 

@@ -172,8 +172,8 @@ def test_a_network_is_found_by_the_file_and_function_a_configuration_names(tmp_p
     walked = nets.walk(network, 32, 10, 2.0)
     assert walked.params["params/stem/conv/kernel"] == (3, 3, 3, 16) and walked.params["params/logits/kernel"] == (16, 10)
     assert sum(walked.macs.values()) == 16 * 16 * 27 * 16 + 16 * 16 * 9 * 16 + 16 * 10
-    from benchmark import cost
-    assert cost.model_macs(network, 32, 10, 2.0) == sum(walked.macs.values())
+    from benchmark.reference import conv_floors
+    assert conv_floors.model_macs(network, 32, 10, 2.0) == sum(walked.macs.values())
     w = weights.make(network, 32, 10, 2.0, 2**31 + 1)
     assert set(w) == set(walked.params)
     x = np.random.RandomState(0).uniform(-1, 1, (3, 32, 32, 3)).astype(np.float32)

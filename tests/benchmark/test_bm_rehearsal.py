@@ -25,6 +25,7 @@ TINY_CONFIG = {
     # other images 0.10 / 0.38 (PERF.md has the chip's readings at full size)
     # (bfloat16 kernels read an int8 share of -0.13 to 0.18 here, the program's int8 tier 0.86 to 1.03)
     "limits": {"logit_rms": 0.04, "logit_max": 0.15, "int8_weight_share": 0.5},
+    "check": {"sample_images": 24},
 }
 
 
@@ -73,7 +74,6 @@ def test_a_whole_run_on_the_cpu_and_an_answer_altered_where_it_is_produced(tmp_p
     same outcomes with one image's scores swapped for another's say not
     correct. The harness's look for a chip is skipped, nothing else."""
     monkeypatch.setattr(R, "work_dir", lambda cell: tmp_path)
-    monkeypatch.setattr(R, "SAMPLE_IMAGES", 24)
     e2e = ({"name": "images_per_s", "unit": "images/s"}, {"name": "setup_s", "unit": "s"})
     cell = Cell("tiny-photos", 1, "tiny", TINY_CONFIG, "tiny-photos",
                 ROOT / "tests" / "benchmark" / "data" / "tiny-photos.json", e2e, ())
