@@ -62,6 +62,17 @@ TINY = {  # --rehearse: width-0.25 zoo configs at the smallest inputs that trace
 }
 
 
+# The token decoder (models/longcat_flash.py) at the tests' small size: on the
+# chip it still runs the Mosaic kernels (mla_prefill at 256 and 1,024 token
+# slots, expert_gmm), so a bring-up failure shows here before a cell does.
+DECODER = {"name": "longcat_flash", "source": "native", "task": "generate", "dtype": "bfloat16", "topk": 5,
+           "decoder": {"hidden_size": 64, "ffn_hidden_size": 128, "expert_ffn_hidden_size": 32, "num_layers": 2,
+                       "num_attention_heads": 4, "kv_lora_rank": 16, "q_lora_rank": 32, "qk_rope_head_dim": 8,
+                       "qk_nope_head_dim": 16, "v_head_dim": 16, "n_routed_experts": 24, "zero_expert_num": 12,
+                       "moe_topk": 4, "routed_scaling_factor": 6, "experts_held": 6, "vocab_size": 64,
+                       "patch": 8, "answer_steps": 4, "max_token_slots": 2048}}
+
+
 def seeded_jpeg(seed: int, h: int, w: int) -> bytes:
     """A deterministic h×w JPEG: smooth seeded gradients plus a little
     noise, so that the file is small and no two seeds look alike."""
@@ -492,6 +503,36 @@ class Smoke:
                         matmul[name], pallas[name])
         self.stop(phase)
 
+    def phase_decoder(self):
+        """The token decoder, small: boot, one request an image size, an
+        answer of ``answer_steps`` top-k lists, its counters in /stats."""
+        phase = "decoder"
+        path = self.tmp / "longcat_flash.json"
+        path.write_text(json.dumps(DECODER))
+        boot_s = self.start(phase, ["--model", str(path), "--canvas-buckets", "128,256", "--max-batch", "4"])
+        self.note(phase=phase, boot_s=round(boot_s, 1))
+        steps_stated = DECODER["decoder"]["answer_steps"]
+        for i, (h, w) in enumerate(((128, 96), (200, 256))):
+            status, _, body = self.post("/predict", seeded_jpeg(self.args.seed + 40 + i, h, w))
+            steps = body.get("steps") or []
+            ok = (status == 200 and len(steps) == steps_stated
+                  and all(len(step) == 5 and all(np.isfinite(p["score"]) and p["score"] > 0 for p in step)
+                          for step in steps))
+            self.check(ok, f"{phase}: {h}x{w} answers {steps_stated} steps of five finite scores",
+                       status=status, error=body.get("error"), first=[s[0]["index"] for s in steps if s])
+        life = self.get("/stats")["batcher"]["lifecycle"]
+        tokens = (128 // 8) * (96 // 8) + (200 // 8) * (256 // 8)
+        self.check(life.get("images_total") == 2 and life.get("tokens_real_total") == tokens
+                   and life.get("decode_steps_total") == 2 * (steps_stated - 1),
+                   f"{phase}: /stats counts 2 images, {tokens} tokens, {2 * (steps_stated - 1)} decode steps",
+                   counted={k: v for k, v in life.items() if k.endswith("_total") and isinstance(v, float)})
+        calls = [int(n) for n in re.findall(r"warmup longcat_flash: serve executable .* holds (\d+) tpu_custom_call",
+                                            self.log_text())]
+        if self.device and self.device["platform"] == "tpu":
+            self.check(bool(calls) and calls[0] >= 2, f"{phase}: the serve executable holds its Mosaic kernels",
+                       calls=calls)
+        self.stop(phase)
+
     def phase_chips(self):
         """Only what exists across chips: four one-chip replicas against
         one batch-sharded engine, then the restart."""
@@ -557,6 +598,7 @@ class Smoke:
         flags, answers = self.phase_main()
         self.phase_restart("restart", flags, answers, self.main_requests)
         self.phase_kernels()
+        self.phase_decoder()
 
 
 def accelerator_or_exit():

@@ -121,6 +121,16 @@ class ConvertedModel:
         output_names: tensor refs produced, e.g. ``["logits", "boxes:0"]``.
         s2d_stem: input-format rewrite handle when the graph's stem matches
             the space-to-depth pattern (else None) — see :class:`S2DStem`.
+        from_canvases: ``fn(params, canvases, hws)`` takes a batch's uint8
+            canvases and real sizes itself and answers whole (a token
+            decoder: patches of the real pixels in, top-k lists out), so
+            the engine puts no resize before it and no top-k after.
+        max_rows: canvas side -> the most rows one call may hold there,
+            where the model's ceiling is not a row count (token slots);
+            None for the engine's ``max_batch`` at every canvas.
+        counter_names: what the model counts a call; its last output is
+            then a vector of these, not a row a request, and the batcher
+            sums it into ``/stats``.
     """
 
     fn: Any
@@ -128,6 +138,9 @@ class ConvertedModel:
     input_specs: list[InputSpec]
     output_names: list[str]
     s2d_stem: S2DStem | None = None
+    from_canvases: bool = False
+    max_rows: Any = None
+    counter_names: tuple[str, ...] = ()
 
     @property
     def input_names(self) -> list[str]:

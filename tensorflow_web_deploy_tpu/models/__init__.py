@@ -26,7 +26,7 @@ from .ssd_mobilenet import SSDMobileNet
 @dataclasses.dataclass(frozen=True)
 class ModelSpec:
     name: str
-    build: Callable  # (num_classes=..., width=...) -> nn.Module
+    build: Callable | None  # (num_classes=..., width=...) -> nn.Module; None: task "generate"
     input_size: int
     preprocess: str
     task: str = "classify"
@@ -48,6 +48,9 @@ _ZOO: dict[str, ModelSpec] = {
         ModelSpec("mobilenet_v2", MobileNetV2, 224, "inception"),
         ModelSpec("resnet50", ResNet50, 224, "caffe"),
         ModelSpec("ssd_mobilenet", SSDMobileNet, 300, "inception", task="detect", num_classes=90),
+        # No flax module and no resize: longcat_flash.py is functional, its sizes come from the
+        # model's JSON (``decoder``), and adapter.decoder_converted wraps it.
+        ModelSpec("longcat_flash", None, 0, "patches", task="generate", num_classes=131072),
     ]
 }
 

@@ -212,6 +212,31 @@ BT601_FWD = (
 BT601_INV = (1.402, -0.344136, -0.714136, 1.772)  # (kr_v, kg_u, kg_v, kb_u)
 
 
+def patch_tokens(canvases, hws, patch: int):
+    """Canvases to token sequences of real pixels: no resize.
+
+    ``canvases`` uint8 [B, S, S, 3] (an image top-left in its canvas),
+    ``hws`` int32 [B, 2]. Every whole ``patch`` x ``patch`` block of an
+    image's own pixels is one token, in raster order of the image's own
+    grid, packed to the front of the row's ``(S / patch) ** 2`` token slots:
+    the canvas bucket is the length bucket. Returns (tokens float32 [B,
+    slots, patch * patch * 3] as ``pixel / 127.5 - 1``, zero in the padding
+    slots; lengths int32 [B])."""
+    b, s = canvases.shape[0], canvases.shape[1]
+    g = s // patch
+    blocks = canvases[:, : g * patch, : g * patch].reshape(b, g, patch, g, patch, 3)
+    blocks = blocks.transpose(0, 1, 3, 2, 4, 5).reshape(b, g * g, patch * patch * 3)
+    ph, pw = hws[:, 0] // patch, hws[:, 1] // patch
+    lengths = (ph * pw).astype(jnp.int32)
+    slot = jnp.arange(g * g, dtype=jnp.int32)[None, :]
+    cols = jnp.maximum(pw, 1)[:, None]
+    src = (slot // cols) * g + slot % cols                      # slot t is the image's block (t // pw, t % pw)
+    real = slot < lengths[:, None]
+    blocks = jnp.take_along_axis(blocks, jnp.where(real, src, 0)[:, :, None], axis=1)
+    tokens = blocks.astype(jnp.float32) / 127.5 - 1.0
+    return jnp.where(real[:, :, None], tokens, 0.0), lengths
+
+
 def rgb_to_yuv420_canvas(canvas: np.ndarray) -> np.ndarray:
     """Host-side reference packer: RGB uint8 [S, S, 3] → I420 uint8 [3S/2, S].
 

@@ -462,6 +462,9 @@ class Batcher:
             "h2d_bytes_total": 0, "d2h_bytes_total": 0,
             "unpack_kernel_batches_total": 0,
             "starved_s_total": 0.0,
+            # what the engine's model counts a call (a token decoder's
+            # tokens, picks and steps), summed in _batch_done
+            **{f"{name}_total": 0.0 for name in getattr(engine, "counter_names", ())},
         }
         self._launched_now = 0
         self._starved_since = time.monotonic()
@@ -738,7 +741,7 @@ class Batcher:
         whose dual capacity — slot count AND arena bytes — is what makes
         the packing size-aware (lease_ragged seals on whichever runs out
         first)."""
-        capacity = self.bulk_max_batch if bulk else self.max_batch
+        capacity = self._capacity(canvas_s, bulk)
         slab = self.engine.acquire_ragged(capacity, canvas_s)
         capacity = min(capacity, slab.bucket)
         delay = self.bulk_delay_s if bulk else self._update_delay()
@@ -746,8 +749,19 @@ class Batcher:
         self._open[(key, bulk)] = b
         return b
 
-    def _new_builder_locked(self, key, bulk: bool = False) -> _Builder:
+    def _capacity(self, canvas_s: int, bulk: bool) -> int:
+        """Rows one builder of this canvas bucket may hold: ``max_batch``
+        (``bulk_max_batch`` for a job's), and no more than the engine allows
+        at this canvas. A token decoder's ceiling is in token slots, rows
+        times the canvas's tokens, which its model states
+        (``InferenceEngine.max_rows``): 16 rows of a small canvas, 4 of a
+        large one."""
         capacity = self.bulk_max_batch if bulk else self.max_batch
+        max_rows = getattr(self.engine, "max_rows", None)
+        return min(capacity, max_rows(canvas_s)) if max_rows else capacity
+
+    def _new_builder_locked(self, key, bulk: bool = False) -> _Builder:
+        capacity = self._capacity(canvas_side(key), bulk)
         slab = None
         if self._staged:
             # Top-capacity slab acquired up front (the final batch size is
@@ -932,6 +946,8 @@ class Batcher:
         so balanced load walks the chips cyclically and an unbalanced one
         self-corrects. None = every replica is at depth."""
         n = self._n_replicas
+        if self._calls_full_locked():
+            return None
         if n == 1:
             return (0 if self._inflight_by_key.get((mkey, 0), 0)
                     < self.pipeline_depth else None)
@@ -1007,7 +1023,17 @@ class Batcher:
         self._bulk_gated_since = None
         return True
 
+    def _calls_full_locked(self) -> bool:
+        """The engine's ceiling on calls in flight over all buckets
+        (``InferenceEngine.max_calls_in_flight``: what the device's memory
+        holds beside the weights, by the compiled programs' temporaries;
+        None on an engine that knows none) is reached."""
+        cap = getattr(self.engine, "max_calls_in_flight", None)
+        return bool(cap) and self._inflight_total >= cap
+
     def _depth_free_locked(self, mkey) -> bool:
+        if self._calls_full_locked():
+            return False
         # Headroom check only — no engine.route_lock hop, no least-loaded
         # scan. It runs per open builder on every sealer wakeup; the real
         # replica pick happens once, at the dispatch decision.
@@ -1262,6 +1288,10 @@ class Batcher:
             life["h2d_bytes_total"] += rec["h2d_bytes"] or 0
             life["d2h_bytes_total"] += rec["d2h_bytes"] or 0
             life["unpack_kernel_batches_total"] += bool(rec["unpack_kernel"])
+            # What the model itself counted in this call (a token decoder:
+            # tokens, token slots, the router's picks, decode steps).
+            for name, value in (rec.get("model_counters") or {}).items():
+                life[f"{name}_total"] = life.get(f"{name}_total", 0.0) + value
             self._launched_now -= 1
             if self._launched_now == 0:
                 self._starved_since = t_done

@@ -383,6 +383,66 @@ def model_cost(model_cfg) -> dict | None:
     return cost
 
 
+# ------------------------------------------------------- token decoders
+
+def decoder_cost(decoder: dict) -> dict:
+    """Walkers for the token decoder (models/longcat_flash.py), from the
+    sizes its config states (``ModelConfig.decoder``): multiply-adds per
+    token of each kind of block, per token squared of the attention core,
+    and the parameters a call reads. Held equal to the benchmark's floors
+    module (benchmark/reference/longcat_floors.py) by a test, as the conv
+    walkers are to theirs.
+
+    - ``mla_params``: one latent attention's matrices (query down and up,
+      key/value down and up, output): a multiply-add each a token;
+    - ``ffn_params``: one dense SwiGLU (three matrices);
+    - ``router_params``: the router over routed and zero experts;
+    - ``expert_params``: one routed expert (three matrices), and
+      ``held_picks_per_token``: how many of a token's picks a uniform router
+      sends to the experts held here;
+    - ``core_macs_per_token_sq``: the causal core of one attention, per
+      token squared (half the pairs, a score and a value each);
+    - ``absorbed_macs_per_cached_token``: one new token's attention against
+      one cached latent (score against the latent and the rotary key, the
+      weighted sum of latents), all heads.
+    """
+    g = decoder.__getitem__
+    d, h = g("hidden_size"), g("num_attention_heads")
+    dn, dr, dv = g("qk_nope_head_dim"), g("qk_rope_head_dim"), g("v_head_dim")
+    rq, rkv = g("q_lora_rank"), g("kv_lora_rank")
+    experts_all = g("n_routed_experts") + g("zero_expert_num")
+    mla = d * rq + rq * h * (dn + dr) + d * (rkv + dr) + rkv * h * (dn + dv) + h * dv * d
+    ffn = 3 * d * g("ffn_hidden_size")
+    router = d * experts_all
+    expert = 3 * d * g("expert_ffn_hidden_size")
+    held = g("moe_topk") * g("experts_held") / experts_all
+    layers = g("num_layers")
+    return {
+        "mla_params": mla, "ffn_params": ffn, "router_params": router, "expert_params": expert,
+        "held_picks_per_token": held,
+        "layer_macs_per_token": 2 * mla + 2 * ffn + router + held * expert,
+        "core_macs_per_token_sq": h * (dn + dr + dv) / 2,
+        "absorbed_macs_per_cached_token": h * (2 * rkv + dr),
+        "dense_params": g("patch") ** 2 * 3 * d + d * g("vocab_size") + layers * (2 * mla + 2 * ffn + router),
+        "param_count": (g("patch") ** 2 * 3 * d + 2 * d * g("vocab_size") + d
+                        + layers * (2 * mla + 2 * ffn + router + g("experts_held") * expert
+                                    + 4 * d + 2 * (rq + rkv))),
+    }
+
+
+def decoder_image_flops(decoder: dict, tokens: float) -> float:
+    """Floor operations of one image of ``tokens`` patch tokens through the
+    decoder: prefill (matrices per token, the core per token squared), the
+    further answer steps against the cache, the head at every step."""
+    c, layers = decoder_cost(decoder), decoder["num_layers"]
+    more = decoder["answer_steps"] - 1
+    prefill = (tokens * (decoder["patch"] ** 2 * 3 * decoder["hidden_size"] + layers * c["layer_macs_per_token"])
+               + layers * 2 * c["core_macs_per_token_sq"] * tokens * tokens)
+    steps = more * layers * (c["layer_macs_per_token"] + 2 * c["absorbed_macs_per_cached_token"] * tokens)
+    head = decoder["answer_steps"] * decoder["hidden_size"] * decoder["vocab_size"]
+    return 2.0 * (prefill + steps + head)
+
+
 def preprocess_flops(canvas_s: int, input_hw, wire: str = "rgb") -> int:
     """FLOPs of the on-device separable matmul resize from one canvas
     bucket to the model input: resize H (h×s matmul over s×s×C canvas)

@@ -419,6 +419,16 @@ class InferenceEngine:
     # batches across replicas only when this is set, so fakes/embedders
     # with the plain signatures keep working unchanged.
     supports_replica_routing = True
+    # Class defaults, so engines built without __init__ by the tests have
+    # them too: the loaded ConvertedModel, and what it counts a call (its
+    # counter_names).
+    model = None
+    counter_names: tuple[str, ...] = ()
+    # How many serve calls the device's memory holds at once beside the
+    # weights, found at warm-up from the compiled programs' own account
+    # (_calls_that_fit); None where that is unknown, and for no ceiling but
+    # the batcher's per-bucket pipeline depth.
+    max_calls_in_flight: int | None = None
 
     def __init__(self, cfg: ServerConfig, mesh=None):
         # Ragged-wire gating: tight-arena packing exists only for the rgb
@@ -490,6 +500,8 @@ class InferenceEngine:
                 ckpt_path=self.model_cfg.ckpt_path,
                 input_format="s2d" if self._s2d_handshake else "nhwc",
                 fused_dw=self._fused_dw,
+                decoder=self.model_cfg.decoder,
+                topk=self.model_cfg.topk,
             )
         else:
             self.model = convert_pb(
@@ -517,6 +529,12 @@ class InferenceEngine:
                     "s2d input rewrite active: stem conv %s consumes the "
                     "preprocess cell layout", self.model.s2d_stem.conv_name,
                 )
+        if self.model.from_canvases and (
+                cfg.wire_format != "rgb" or self.model_cfg.dtype == "int8"):
+            raise ValueError(
+                f"model '{self.model_cfg.name}' takes patches of rgb canvases, "
+                "in float32 or bfloat16")
+        self.counter_names = self.model.counter_names
         log.info(
             "loaded %s (%s): %d params tensors, inputs=%s outputs=%s (%.1fs)",
             self.model_cfg.pb_path or self.model_cfg.name,
@@ -537,10 +555,20 @@ class InferenceEngine:
         if self._quantized:
             params = quant.quantize_params(self.model.params, dtype)
         else:
+            # float32 leaves take the served dtype; so do bfloat16 ones (a
+            # leaf export holds the served dtype already, or is served in
+            # float32).
             params = {
-                k: v.astype(dtype) if v.dtype == np.float32 else v
+                k: v.astype(dtype)
+                if v.dtype in (np.float32, jnp.bfloat16) and v.dtype != dtype else v
                 for k, v in self.model.params.items()
             }
+            if self.model.from_canvases:
+                # The device copy is the one that serves, and parity_check,
+                # the one reader of the host's after placement, feeds a
+                # resized square: the host's 2 bytes a parameter (10 GB at
+                # the published widths) go back.
+                self.model.params = {}
         # Golden numerical-parity gate: a quantized variant must prove itself
         # against the f32 reference BEFORE any device placement — a failing
         # gate parks the registry load in FAILED instead of serving garbage.
@@ -662,6 +690,18 @@ class InferenceEngine:
         buckets.append(top)
         return tuple(buckets)
 
+    def max_rows(self, canvas_s: int) -> int:
+        """The most rows one call may hold at this canvas bucket: the top
+        batch bucket, or, where the model states its own ceiling
+        (``ConvertedModel.max_rows``: a token decoder's is in token slots),
+        the largest batch bucket within it; never less than the smallest."""
+        model_max_rows = getattr(self.model, "max_rows", None)
+        if model_max_rows is None:
+            return self.batch_buckets[-1]
+        most = min(model_max_rows(canvas_s), self.max_batch)
+        fits = [b for b in self.batch_buckets if b <= most]
+        return fits[-1] if fits else self.batch_buckets[0]
+
     def canvas_shape(self, batch: int, s: int) -> tuple[int, ...]:
         """Host-staged canvas batch shape for one (batch, canvas-bucket)."""
         if self.cfg.wire_format == "yuv420":
@@ -737,6 +777,19 @@ class InferenceEngine:
         policy = None if dtype == jnp.float32 else dtype
         topk = self.model_cfg.topk
         quantized = self._quantized
+
+        if self.model.from_canvases:
+            def serve(params, canvases, hws):
+                # The model's whole answer; wrapped for the name alone: a
+                # recording and its readers know the program as jit_serve.
+                return model_fn(params, canvases, hws)
+
+            self._serve_raw = serve
+            for rep in self._replicas:
+                rep.serve = jax.jit(
+                    serve, in_shardings=(rep.replicated, rep.data_sharding,
+                                         rep.data_sharding))
+            return
 
         def make_serve(preprocess):
             def serve(params, canvases, hws):
@@ -894,6 +947,7 @@ class InferenceEngine:
             "input_size": list(mc.input_size),
             "topk": mc.topk,
             "task": mc.task,
+            "decoder": mc.decoder,
             "preprocess": mc.preprocess,
             "zoo_width": mc.zoo_width,
             "zoo_classes": mc.zoo_classes,
@@ -1567,6 +1621,14 @@ class InferenceEngine:
             self.note_d2h(nbytes)
             if rec is not None:
                 rec["d2h_bytes"] = nbytes
+            if self.counter_names:
+                # The model's last output is the call's counters, not a
+                # row a request: the batcher sums them into /stats.
+                *outs, counted = outs
+                outs = tuple(outs)
+                if rec is not None:
+                    rec["model_counters"] = dict(
+                        zip(self.counter_names, (float(v) for v in counted)))
             outs = jax.tree.map(lambda o: o[:n], outs)
             return outs if isinstance(outs, tuple) else (outs,)
         finally:
@@ -1747,7 +1809,7 @@ class InferenceEngine:
         split batch route independently — on replicated placement they
         spread across the chips.
         """
-        top = self.batch_buckets[-1]
+        top = self.max_rows(canvases.shape[2])
         n = canvases.shape[0]
         if n <= top:
             return self.fetch_outputs(
@@ -1838,7 +1900,8 @@ class InferenceEngine:
         """
         canvas_buckets = canvas_buckets or self.cfg.canvas_buckets
         batch_buckets = batch_buckets or self.batch_buckets
-        pairs = [(s, b) for s in canvas_buckets for b in batch_buckets]
+        pairs = [(s, b) for s in canvas_buckets for b in batch_buckets
+                 if b <= self.max_rows(s)]
         tasks = [(rep, s, b) for (s, b) in pairs for rep in self._replicas]
         workers = max(1, min(8, len(tasks), os.cpu_count() or 4))
         agg: dict[tuple[int, int], dict] = {
@@ -1869,6 +1932,10 @@ class InferenceEngine:
                 s, b, cell["s"], cell["compiled"], cell["deserialized"],
                 self.num_replicas,
             )
+
+        self.max_calls_in_flight = self._calls_that_fit()
+        if self.max_calls_in_flight is not None:
+            workers = min(workers, self.max_calls_in_flight)
 
         # Which kernels the serve program really holds: a Mosaic kernel is a
         # tpu_custom_call in the compiled text (0 on the CPU backend, where
@@ -1902,6 +1969,31 @@ class InferenceEngine:
             list(pool.map(lambda t: self._warm_execute(*t), tasks))
         log.info("warmup: execution pass %.2fs (%d batches x%d replicas)",
                  time.perf_counter() - t0, len(pairs), self.num_replicas)
+
+    def _calls_that_fit(self) -> int | None:
+        """Calls in flight that a device's memory holds beside what is on it
+        now (the weights, the executables): what is free, by the backend's
+        own count, over the largest temporary of a warmed serve executable,
+        by the compiler's. Each call launched and not yet done holds its
+        temporaries; the per-bucket pipeline depth knows nothing of their
+        size (a token decoder's are gigabytes: one call fits beside its
+        weights, where a conv net's fit by the dozen). None where the
+        backend reports no memory (the CPU) or the executable no analysis."""
+        temp = 0
+        for rep in self._replicas:
+            for exe in list(rep.exe.values()):
+                try:
+                    temp = max(temp, int(exe.memory_analysis().temp_size_in_bytes))
+                except Exception:
+                    return None
+        free = [ms["bytes_limit"] - ms["bytes_in_use"] for ms in self.device_memory()
+                if "bytes_limit" in ms and "bytes_in_use" in ms]
+        if not temp or not free:
+            return None
+        fit = max(1, min(free) // temp)
+        log.info("warmup: %d call(s) in flight fit (largest temporary %.2f GB, %.2f GB free)",
+                 fit, temp / 1e9, min(free) / 1e9)
+        return fit
 
     def healthcheck(self) -> bool:
         """One-image device round-trip (SURVEY.md §5.3 /healthz contract)."""

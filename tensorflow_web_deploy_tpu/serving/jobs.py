@@ -167,6 +167,18 @@ def format_result_row(row, orig_hw, topk: int, mv, trace_id=None) -> dict:
                 for s, i in zip(scores[:topk], idx[:topk])
             ]
         }
+    elif mv.model_cfg.task == "generate":
+        # Row is one top-k list a step: (scores [steps, K], ids [steps, K]);
+        # step s + 1 followed the id that step s put first.
+        scores, idx = (np.asarray(r) for r in row)
+        out = {
+            "steps": [
+                [{"label": labels[i] if i < len(labels) else f"token_{i}",
+                  "index": int(i), "score": float(s)}
+                 for s, i in zip(step_scores[:topk], step_idx[:topk])]
+                for step_scores, step_idx in zip(scores, idx)
+            ]
+        }
     else:
         # raw passthrough task
         probs = np.asarray(row[0]).reshape(-1)

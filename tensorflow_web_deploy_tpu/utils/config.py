@@ -49,7 +49,13 @@ class ModelConfig:
     # serving export from tools/train.py (orbax dir holding params +
     # batch_stats) — serve fine-tuned weights instead of the seeded init
     ckpt_path: str | None = None
-    task: str = "classify"  # "classify" | "detect"
+    task: str = "classify"  # "classify" | "detect" | "generate"
+    # task="generate" (native name "longcat_flash"): the decoder's sizes,
+    # the keys of models/longcat_flash.py::Config (widths, layers, experts
+    # held, vocabulary slice, patch, answer_steps, max_token_slots). The
+    # image is not resized: its patches are the tokens, so input_size and
+    # preprocess are unused.
+    decoder: dict | None = None
     labels_path: str | None = None
     input_name: str | None = None  # default: the graph's sole placeholder
     output_names: list[str] | None = None  # default: inferred sinks
@@ -99,6 +105,11 @@ class ModelConfig:
             self.dtype = normalize_dtype(self.dtype)
         except ValueError as e:
             raise ValueError(f"model '{self.name}': {e}") from None
+        if self.task == "generate" and not isinstance(self.decoder, dict):
+            raise ValueError(
+                f"model '{self.name}': task='generate' needs 'decoder', the "
+                "model's sizes (models/longcat_flash.py::Config)"
+            )
         if self.fused_dw not in ("auto", "on", "off"):
             raise ValueError(
                 f"model '{self.name}': fused_dw must be 'auto', 'on' or "

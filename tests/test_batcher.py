@@ -643,3 +643,45 @@ def test_a_failed_dispatch_still_closes_its_lifecycle():
     # nothing is launched any more: the starved clock runs again
     assert later["starved_s_total"] - life["starved_s_total"] == pytest.approx(
         later["now_s"] - life["now_s"], abs=1e-6)
+
+
+def test_an_engines_ceiling_on_calls_in_flight_holds_over_all_buckets():
+    """Three full batches of two canvas buckets back to back at pipeline
+    depth 3: they overlap in flight, and with the engine's own ceiling of
+    one call (what its device's memory holds beside the weights) they do
+    not, though each bucket's depth would let them."""
+    def flights(ceiling):
+        eng = FakeSlotEngine(bucket=2, delay_s=0.03)
+        eng.max_calls_in_flight = ceiling
+        b = Batcher(eng, max_batch=2, max_delay_ms=2, adaptive_delay=False, pipeline_depth=3)
+        b.start()
+        try:
+            futures = [b.submit(_canvas(i, size=8 if i < 4 else 16), (1, 1)) for i in range(6)]
+            for f in futures:
+                f.result(timeout=5)
+            deadline = time.monotonic() + 2
+            while b.inflight_batches and time.monotonic() < deadline:
+                time.sleep(0.001)
+        finally:
+            b.stop()
+        return [(r["t_launch"], r["t_done"]) for r in b.batch_timeline()]
+
+    free, held = flights(None), flights(1)
+    assert len(free) == len(held) == 3
+    assert _union_s(free) < sum(z - a for a, z in free) - 0.02          # they did overlap
+    assert _union_s(held) == pytest.approx(sum(z - a for a, z in held), abs=2e-3)
+
+
+def test_a_builder_holds_no_more_rows_than_the_engine_allows_at_its_canvas():
+    """A ceiling that goes with the canvas (a token decoder's is in token
+    slots): eight rows of the small canvas a batch, two of the large."""
+    eng = FakeEngine(delay_s=0.02)
+    eng.max_rows = lambda canvas_s: 8 if canvas_s <= 8 else 2
+    b = Batcher(eng, max_batch=8, max_delay_ms=30, adaptive_delay=False)
+    b.start()
+    small = [b.submit(_canvas(i, size=8), (1, 1)) for i in range(8)]
+    large = [b.submit(_canvas(i, size=16), (1, 1)) for i in range(6)]
+    for f in small + large:
+        f.result(timeout=5)
+    b.stop()
+    assert sorted(eng.batches) == [2, 2, 2, 8]

@@ -23,6 +23,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from tensorflow_web_deploy_tpu.ops import experts, mla
 from tensorflow_web_deploy_tpu.ops.depthwise import fused_depthwise_bn
 from tensorflow_web_deploy_tpu.ops.image import unpack_ragged
 from tensorflow_web_deploy_tpu.ops.pallas_preprocess import preprocess_i420
@@ -114,3 +115,25 @@ def test_ragged_unpack_compiles_for_v5e(v5e, canvas, bucket, rows):
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < 1 << 20  # arena in, canvases out, no copy
     assert m.output_size_in_bytes < bucket * canvas * canvas * 3 + (1 << 20)
+
+
+# The token decoder's kernels at the published widths (64 heads, keys of 128 +
+# 64 rotary, values of 128; experts 6144 x 2048) and at the benchmark's three
+# length buckets with the most rows a call holds of each.
+@pytest.mark.parametrize("rows,slots", [(16, 1024), (4, 2304), (4, 4096)])
+def test_mla_prefill_compiles_for_v5e(v5e, rows, slots):
+    s = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    compiled = jax.jit(lambda qn, qr, kn, kr, v, n: mla.pallas_core(qn, qr, kn, kr, v, n, 192 ** -0.5)).lower(
+        s(rows, 64, slots, 128), s(rows, 64, slots, 64), s(rows, 64, slots, 128), s(rows, slots, 64),
+        s(rows, 64, slots, 128), s(rows, dt=jnp.int32)).compile()
+    assert compiled.as_text().count(KERNEL) == 1 and "mla_prefill" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,n,col_tile", [(6144, 2048, 256), (2048, 6144, 512)])
+def test_expert_gmm_compiles_for_v5e(v5e, k, n, col_tile):
+    """One window of the grouped form: 4,096 rows in 32 tiles, 16 held experts."""
+    s = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    compiled = jax.jit(lambda x, w, te, nt: experts.expert_gmm(x, w, te, nt, col_tile=col_tile)).lower(
+        s(experts.CHUNK, k), s(16, k, n), s(experts.CHUNK // experts.ROW_TILE, dt=jnp.int32),
+        s(1, dt=jnp.int32)).compile()
+    assert compiled.as_text().count(KERNEL) == 1 and "expert_gmm" in compiled.as_text()
