@@ -649,13 +649,23 @@ class InferenceEngine:
         self._staging_pool: dict[tuple, list[StagingSlab]] = {}
         self._staging_lock = named_lock("engine.staging_lock")
         self._staging_cap = max(2, getattr(cfg, "staging_slabs", 6))
-        self._staging_allocs = 0  # lifetime slab allocations (reuse telemetry)
-        # Global byte budget across POOLED slabs: warmup touches every
-        # (canvas, batch) bucket pair, and per-key caps alone would pin
-        # ~1 GB at the default bucket ladder. LRU keys are evicted first;
-        # in-flight slabs are unaffected (the budget bounds idle memory).
+        # Reuse telemetry: lifetime acquisitions and how many of them had
+        # to allocate (reuse share = 1 - allocs / acquires over a window).
+        self._staging_acquires = 0
+        self._staging_allocs = 0
+        # Byte budget across POOLED (idle) slabs: staging_pool_bytes PLUS
+        # the bytes that are out with batches right now (acquired and not
+        # yet returned: open in a builder, sealed, in flight, or held by a
+        # straggling lease). Traffic that keeps k slabs of a shape out may
+        # keep as many idle, so a returned arena is the next builder's and
+        # is not unmapped and mapped again; when the last slab out comes
+        # back the budget is the floor again, so an idle server and the end
+        # of warmup (which touches every (canvas, batch) bucket pair) hold
+        # no more than staging_pool_bytes. LRU keys are evicted first.
         self._staging_budget = int(getattr(cfg, "staging_pool_bytes", 256 << 20))
         self._staging_pool_nbytes = 0
+        self._staging_out = 0
+        self._staging_out_nbytes = 0
         self._staging_last_use: dict[tuple, float] = {}
 
         # Ragged-wire state: pooled arenas ride the SAME staging pool (a
@@ -1152,23 +1162,9 @@ class InferenceEngine:
                 f"batch of {n} exceeds the top batch bucket {bucket}; "
                 "split the batch or raise batch_buckets/max_batch"
             )
-        key = (tuple(row_shape), bucket)
-        slab = None
-        with self._staging_lock:
-            self._staging_last_use[key] = time.monotonic()
-            free = self._staging_pool.get(key)
-            if free:
-                slab = free.pop()
-                self._staging_pool_nbytes -= slab.total_bytes
-            else:
-                self._staging_allocs += 1
-        if slab is None:
-            slab = StagingSlab(row_shape, bucket, self.cfg.packed_io)
-        # Pool return is the conjunction of fetch-complete AND all slot
-        # leases dropped (StagingSlab docstring); the slab itself enforces
-        # it so a straggling lessee can never overlap a reused buffer.
-        slab.arm(self._release_staging)
-        return slab
+        return self._acquire(
+            (tuple(row_shape), bucket),
+            lambda: StagingSlab(row_shape, bucket, self.cfg.packed_io))
 
     def acquire_ragged(self, n: int, canvas_s: int) -> RaggedSlab:
         """A ragged arena slab whose batch bucket fits ``n`` images at
@@ -1182,18 +1178,35 @@ class InferenceEngine:
                 f"batch of {n} exceeds the top batch bucket {bucket}; "
                 "split the batch or raise batch_buckets/max_batch"
             )
-        key = (("ragged", int(canvas_s)), bucket)
+        return self._acquire(
+            (("ragged", int(canvas_s)), bucket),
+            lambda: RaggedSlab(canvas_s, bucket))
+
+    def _acquire(self, key: tuple, make):
+        """Take a pooled slab of ``key`` or ``make()`` one, count it as out
+        and arm it. A pooled slab is handed out as it came back: its bytes
+        are the last batch's (what a batch reads of a slab is bounded by
+        its own hws / meta table, so stale bytes are never observable)."""
         slab = None
         with self._staging_lock:
             self._staging_last_use[key] = time.monotonic()
+            self._staging_acquires += 1
             free = self._staging_pool.get(key)
             if free:
                 slab = free.pop()
                 self._staging_pool_nbytes -= slab.total_bytes
+                self._staging_out += 1
+                self._staging_out_nbytes += slab.total_bytes
             else:
                 self._staging_allocs += 1
         if slab is None:
-            slab = RaggedSlab(canvas_s, bucket)
+            slab = make()  # outside the lock: maps the arena
+            with self._staging_lock:
+                self._staging_out += 1
+                self._staging_out_nbytes += slab.total_bytes
+        # Pool return is the conjunction of fetch-complete AND all slot
+        # leases dropped (StagingSlab docstring); the slab itself enforces
+        # it so a straggling lessee can never overlap a reused buffer.
         slab.arm(self._release_staging)
         return slab
 
@@ -1204,17 +1217,24 @@ class InferenceEngine:
         slab.finish_fetch()
 
     def _release_staging(self, slab: StagingSlab):
+        dropped = []  # unmapped after the lock is released
         with self._staging_lock:
+            self._staging_out -= 1
+            self._staging_out_nbytes -= slab.total_bytes
             self._staging_last_use[slab.key] = time.monotonic()
             free = self._staging_pool.setdefault(slab.key, [])
-            if len(free) >= self._staging_cap:
-                return  # drop — bounded host memory under bursty pipelining
-            free.append(slab)
-            self._staging_pool_nbytes += slab.total_bytes
-            # Global budget: drop slabs from the least-recently-used shapes
+            # Over the per-key cap the slab is dropped: bounded host memory
+            # under bursty pipelining.
+            if len(free) < self._staging_cap:
+                free.append(slab)
+                self._staging_pool_nbytes += slab.total_bytes
+            # The idle budget (see __init__): the floor plus what is still
+            # out. Over it, drop slabs from the least-recently-used shapes
             # first, so warmup-only buckets give their memory back to the
-            # shapes traffic actually hits.
-            while self._staging_pool_nbytes > self._staging_budget:
+            # shapes traffic actually hits. This return lowered the budget,
+            # so the trim runs whether or not the slab was kept.
+            budget = self._staging_budget + self._staging_out_nbytes
+            while self._staging_pool_nbytes > budget:
                 victim = min(
                     (k for k, v in self._staging_pool.items() if v),
                     key=lambda k: self._staging_last_use.get(k, 0.0),
@@ -1222,13 +1242,17 @@ class InferenceEngine:
                 )
                 if victim is None:
                     break
-                dropped = self._staging_pool[victim].pop()
-                self._staging_pool_nbytes -= dropped.total_bytes
+                evicted = self._staging_pool[victim].pop()
+                self._staging_pool_nbytes -= evicted.total_bytes
+                dropped.append(evicted)
 
     def staging_stats(self) -> dict:
         with self._staging_lock:
             out = {
+                "slab_acquires_total": self._staging_acquires,
                 "slab_allocs_total": self._staging_allocs,
+                "slabs_out": self._staging_out,
+                "slabs_out_bytes": self._staging_out_nbytes,
                 "slabs_pooled": sum(len(v) for v in self._staging_pool.values()),
                 "slabs_pooled_bytes": self._staging_pool_nbytes,
             }

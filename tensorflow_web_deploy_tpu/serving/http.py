@@ -846,8 +846,17 @@ class App:
                      help_="Currently open connections.")
         if hasattr(engine, "staging_stats"):
             s = engine.staging_stats()
+            p.scalar("staging_slab_acquires_total", s["slab_acquires_total"],
+                     mtype="counter", help_="Lifetime staging-slab acquisitions "
+                     "(reuse share = 1 - allocs / acquires).")
             p.scalar("staging_slab_allocs_total", s["slab_allocs_total"],
                      mtype="counter", help_="Lifetime staging-slab allocations.")
+            p.scalar("staging_slabs_out", s["slabs_out"],
+                     help_="Staging slabs out with batches (acquired, not "
+                     "yet returned).")
+            p.scalar("staging_out_bytes", s["slabs_out_bytes"],
+                     help_="Host bytes of the staging slabs that are out; the "
+                     "idle pool may hold staging_pool_bytes plus this.")
             p.scalar("staging_slabs_pooled", s["slabs_pooled"],
                      help_="Idle staging slabs in the pool.")
             p.scalar("staging_pooled_bytes", s["slabs_pooled_bytes"],

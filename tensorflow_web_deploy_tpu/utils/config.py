@@ -172,11 +172,15 @@ class ServerConfig:
     # dispatch ships it in one host→device transfer (no stack/concat
     # copies). The cap bounds host memory under bursty pipelining.
     staging_slabs: int = 6
-    # Global byte budget for POOLED (idle) staging slabs across all shapes:
-    # warmup touches every (canvas, batch) bucket pair, and without a global
-    # bound the per-key cap alone pins ~1 GB of host RAM at the default
-    # bucket ladder. Over budget, slabs from the least-recently-used shapes
-    # are dropped (in-flight slabs are never affected).
+    # The floor an idle pool of staging slabs falls back to, across all
+    # shapes. Under load the pool may hold this PLUS the bytes that are out
+    # with batches (acquired and not yet returned), so traffic whose slabs
+    # are larger than the floor (a 1.6 GB arena at canvas 4096 x batch 32)
+    # still reuses them; when the last slab out comes back the pool trims to
+    # the floor again, so an idle server and the end of warmup (which touches
+    # every (canvas, batch) bucket pair) pin no more than this. Over budget,
+    # slabs from the least-recently-used shapes are dropped (slabs that are
+    # out are never affected).
     staging_pool_bytes: int = 256 << 20
     # Content-addressed response cache (serving/respcache.py): byte budget
     # for cached formatted responses, keyed by (model, version, digest of

@@ -75,6 +75,15 @@ def test_loadgen_roundtrip_zero_errors(request):
         # Server-side reuse ratio agrees with the client: far more requests
         # than connections (the /stats GETs themselves add a connection).
         assert http_snap["requests_total"] > http_snap["connections_total"]
-        assert snap["staging"]["slabs_pooled"] >= 1
+        staging = snap["staging"]
+        assert staging["slabs_pooled"] >= 1
+        # The pool's reuse counters: a run of one shape allocates a few
+        # slabs and then takes them again; with every answer back, nothing
+        # is out and the idle pool is at its floor.
+        for k in ("slab_acquires_total", "slab_allocs_total", "slabs_out", "slabs_out_bytes"):
+            assert isinstance(staging[k], int), k
+        assert staging["slab_acquires_total"] > 2 * staging["slab_allocs_total"] > 0
+        assert staging["slabs_out"] == 0 and staging["slabs_out_bytes"] == 0
+        assert staging["slabs_pooled_bytes"] <= cfg.staging_pool_bytes
     finally:
         shutdown_gracefully(srv, batcher, grace_s=5.0)

@@ -260,7 +260,9 @@ class MockReplicatedEngine:
                 for i in range(self.num_replicas)
             ]
         return {
-            "slab_allocs_total": 0, "slabs_pooled": 0, "slabs_pooled_bytes": 0,
+            "slab_acquires_total": 0, "slab_allocs_total": 0,
+            "slabs_out": 0, "slabs_out_bytes": 0,
+            "slabs_pooled": 0, "slabs_pooled_bytes": 0,
             "dispatches_total": sum(r["dispatches_total"] for r in reps),
             "dispatches_inflight": sum(r["dispatches_inflight"] for r in reps),
             "placement": self.placement_summary(),

@@ -317,6 +317,98 @@ def test_ragged_partial_arena_hole_parity(rng):
         engine.close()
 
 
+def _stage_holed(slab, imgs):
+    """imgs[0], a hole (a lease that died before commit: valid stays 0),
+    imgs[1]: the batch ends on a short last row."""
+    for im in (imgs[0], None, imgs[1]):
+        h, w = (3, 5) if im is None else im.shape[:2]
+        idx, view = slab.alloc(h * w * 3)
+        if im is not None:
+            view[:] = im.reshape(-1)
+            slab.write_hw(idx, (h, w))
+
+
+def test_dirty_arena_answers_bit_for_bit_like_a_fresh_one(rng):
+    """A pooled arena comes back as its last batch left it and is not
+    zeroed (1.6 GB at canvas 4096 x batch 32). ``arm`` resets the cursors
+    and the meta table, a hole keeps valid = 0, and the unpack reads only
+    what the meta table bounds: the canvases and the served top-k of a
+    batch staged into an arena full of 0xFF equal, bit for bit, those of
+    the same batch in a newly allocated one."""
+    engine = InferenceEngine(_cfg("mobilenet_v2"))
+    try:
+        s, n = 96, 3
+        imgs = [_mixed_images(rng, s, n=2)[1], (rng.rand(17, 23, 3) * 255).astype(np.uint8)]
+
+        def serve(slab):
+            _stage_holed(slab, imgs)
+            rows = slab.rows_shipped(engine.pick_batch_bucket(n))
+            canvases, hws = unpack_ragged(
+                slab.buf[: rows * slab.row_bytes].copy(), slab.meta.copy(), s)
+            outs = engine.fetch_outputs(engine.dispatch_ragged(slab, n))
+            return np.asarray(canvases), np.asarray(hws), [np.asarray(o) for o in outs]
+
+        before = engine.staging_stats()
+        fresh = engine.acquire_ragged(n, s)
+        assert not fresh.buf.any()  # newly allocated: zeros
+        want_c, want_hw, want = serve(fresh)
+
+        dirty = engine.acquire_ragged(n, s)
+        assert dirty is fresh  # the pool's
+        dirty.buf[:] = 0xFF
+        dirty.meta[:] = 0x7F7F7F7F
+        engine.release_staging(dirty)
+        again = engine.acquire_ragged(n, s)
+        after = engine.staging_stats()
+        assert again is dirty and again.buf.min() == 0xFF  # reused, not zeroed
+        assert not again.meta.any() and again.used == 0 and again.slots == 0
+        assert after["slab_acquires_total"] - before["slab_acquires_total"] == 3
+        assert after["slab_allocs_total"] - before["slab_allocs_total"] == 1
+        got_c, got_hw, got = serve(again)
+
+        np.testing.assert_array_equal(got_c, want_c)
+        np.testing.assert_array_equal(got_hw, want_hw)
+        assert not got_c[1].any() and tuple(got_hw[1]) == (1, 1)  # the hole
+        ref_c, ref_hw = _padded(imgs, s)
+        np.testing.assert_array_equal(got_c[[0, 2]], ref_c)
+        np.testing.assert_array_equal(got_hw[[0, 2]], ref_hw)
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)  # scores and indices, all rows
+    finally:
+        engine.close()
+
+
+def test_unpack_kernel_never_reads_dirty_slack():
+    """The same for the Mosaic kernel (through the interpreter): an arena
+    shipped with slack behind its last image and a hole in it unpacks to
+    the same canvases whether the unused bytes are zeros or 0xFF."""
+    s, bucket, rows = 512, 8, 3
+    rng = np.random.RandomState(33)
+    deck = [(37, s - 11), None, (s // 2 + 1, s // 3 + 2), (5, 3)]
+    meta = np.zeros((bucket, 4), np.int32)
+    clean = np.zeros(rows * s * s * 3, np.uint8)
+    dirty = np.full(rows * s * s * 3, 0xFF, np.uint8)
+    off = 0
+    for i, hw in enumerate(deck):
+        h, w = hw or (9, 7)  # a hole's bytes were allocated, never committed
+        if hw is not None:
+            px = rng.randint(1, 256, h * w * 3)
+            clean[off:off + px.size] = dirty[off:off + px.size] = px
+            meta[i] = (off, h, w, 1)
+        else:
+            meta[i, 0] = off
+        off += h * w * 3
+    assert off < clean.size // 2  # most of what ships is slack
+    kernel = jax.jit(lambda a, m: unpack_ragged(a, m, s, interpret=True))
+    want_c, want_hw = kernel(clean.view(np.uint32), meta)
+    got_c, got_hw = kernel(dirty.view(np.uint32), meta)
+    ref_c, _ = jax.jit(lambda a, m: unpack_ragged(a, m, s))(dirty, meta)
+    assert bool(jnp.array_equal(got_c, want_c)) and bool(jnp.array_equal(got_c, ref_c))
+    np.testing.assert_array_equal(np.asarray(got_hw), np.asarray(want_hw))
+    assert not np.asarray(got_c[1]).any()
+
+
 # --------------------------------------------------------- packing identity
 
 

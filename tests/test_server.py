@@ -160,6 +160,44 @@ def test_stats(cls_server, rng):
     assert snap["staging"]["slab_allocs_total"] >= 1
 
 
+def test_staging_reuse_counters_in_stats_and_metrics(cls_server, rng):
+    """/stats -> staging and /metrics carry the pool's reuse counters:
+    acquisitions beside allocations (reuse share = 1 - allocs / acquires),
+    and the slabs and bytes that are out. A second request of a shape the
+    pool already holds raises the acquisitions and not the allocations."""
+    from tensorflow_web_deploy_tpu.utils.metrics import parse_prometheus_text
+
+    base, _ = cls_server
+    fields = ("slab_acquires_total", "slab_allocs_total", "slabs_out", "slabs_out_bytes")
+
+    def staging():
+        snap = json.loads(_get(f"{base}/stats")[1])["staging"]
+        for k in fields:
+            assert isinstance(snap[k], int) and not isinstance(snap[k], bool), k
+            assert snap[k] >= 0, k
+        return snap
+
+    _post(f"{base}/predict", _jpeg(rng))
+    first = staging()
+    _post(f"{base}/predict", _jpeg(rng))  # other pixels, the same shape
+    second = staging()
+    assert second["slab_acquires_total"] > first["slab_acquires_total"]
+    assert second["slab_allocs_total"] == first["slab_allocs_total"]
+    assert second["slab_allocs_total"] <= second["slab_acquires_total"]
+    # Every answer is back, so nothing is out and the pool is at its floor.
+    assert second["slabs_out"] == 0 and second["slabs_out_bytes"] == 0
+    assert second["slabs_pooled_bytes"] <= 256 << 20
+
+    parsed = parse_prometheus_text(_get(f"{base}/metrics")[1].decode())
+    samples, types = parsed["samples"], parsed["types"]
+    assert samples[("tpu_serve_staging_slab_acquires_total", ())] >= second["slab_acquires_total"]
+    assert samples[("tpu_serve_staging_slab_allocs_total", ())] == second["slab_allocs_total"]
+    assert samples[("tpu_serve_staging_slabs_out", ())] == 0
+    assert samples[("tpu_serve_staging_out_bytes", ())] == 0
+    assert types["tpu_serve_staging_slab_acquires_total"] == "counter"
+    assert types["tpu_serve_staging_slabs_out"] == "gauge"
+
+
 def test_demo_page(cls_server):
     base, _ = cls_server
     status, body = _get(f"{base}/")

@@ -183,20 +183,31 @@ def test_concurrent_acquire_never_blocks(staging_engine):
     assert pooled <= eng._staging_cap
 
 
+def _out(eng):
+    st = eng.staging_stats()
+    return st["slabs_out"], st["slabs_out_bytes"]
+
+
 def test_staging_pool_byte_budget_evicts_lru(staging_engine):
-    """Pooled (idle) slab memory is globally bounded: releasing past the
-    byte budget drops slabs from the least-recently-used shape key, so
-    warmup-only buckets give their memory back to the hot shapes."""
+    """Pooled (idle) slab memory is bounded by ``staging_pool_bytes`` plus
+    the bytes that are out: a slab returned while another is out is kept;
+    once nothing is out the pool trims to the floor, dropping slabs from
+    the least-recently-used shape key, so warmup-only buckets give their
+    memory back to the hot shapes."""
     eng = staging_engine
     saved = eng._staging_budget
     a = eng.acquire_staging(8, (128, 128, 3))
     b = eng.acquire_staging(8, (64, 64, 3))  # second shape key
     assert a.key != b.key
     try:
-        eng._staging_budget = a.total_bytes  # room for one big slab only
-        eng._release_staging(a)
-        eng._release_staging(b)  # over budget: a's key is LRU → evicted
+        eng._staging_budget = a.total_bytes  # floor: room for one big slab only
+        eng._release_staging(a)  # b is still out: the budget is floor + b
+        assert eng._staging_pool.get(a.key)
         stats = eng.staging_stats()
+        assert stats["slabs_pooled_bytes"] <= eng._staging_budget + b.total_bytes
+        eng._release_staging(b)  # nothing out: over the floor, a's key is LRU
+        stats = eng.staging_stats()
+        assert stats["slabs_out"] == 0 and stats["slabs_out_bytes"] == 0
         assert stats["slabs_pooled_bytes"] <= eng._staging_budget
         assert not eng._staging_pool.get(a.key)
         assert eng._staging_pool.get(b.key)
@@ -207,21 +218,25 @@ def test_staging_pool_byte_budget_evicts_lru(staging_engine):
 def test_staging_lru_eviction_order_multi_shape(staging_engine):
     """Three shape keys over budget: eviction walks strict LRU order (the
     key touched longest ago goes first), and a key re-touched by a fresh
-    acquire stops being the victim."""
+    acquire stops being the victim. The budget at each return is the floor
+    plus what is still out."""
     eng = staging_engine
     saved = eng._staging_budget
     a = eng.acquire_staging(8, (128, 128, 3))
     b = eng.acquire_staging(8, (96, 96, 3))
     c = eng.acquire_staging(8, (64, 64, 3))
     assert len({a.key, b.key, c.key}) == 3
+    assert a.total_bytes > b.total_bytes + c.total_bytes
     try:
-        # Budget fits exactly the two smaller slabs.
+        # The floor fits exactly the two smaller slabs.
         eng._staging_budget = b.total_bytes + c.total_bytes
-        eng._release_staging(a)  # a is now oldest-touched AND pooled
-        eng._release_staging(b)
-        eng._release_staging(c)  # over budget → evict a (LRU), keep b + c
+        eng._release_staging(a)  # b + c out: a fits beside the floor, kept
+        assert eng._staging_pool.get(a.key)
+        eng._release_staging(b)  # c out: a + b > floor + c → evict a (LRU)
         assert not eng._staging_pool.get(a.key)
+        eng._release_staging(c)  # nothing out: b + c is the floor, both stay
         assert eng._staging_pool.get(b.key) and eng._staging_pool.get(c.key)
+        assert eng.staging_stats()["slabs_pooled_bytes"] <= eng._staging_budget
         # Re-touching b (acquire) makes c the LRU among pooled keys.
         b2 = eng.acquire_staging(8, (96, 96, 3))
         eng._staging_budget = b2.total_bytes  # only room for one now
@@ -235,24 +250,173 @@ def test_staging_lru_eviction_order_multi_shape(staging_engine):
 def test_lru_eviction_never_touches_inflight_slabs(staging_engine):
     """The byte budget bounds IDLE memory only: a slab held in flight (or
     by a lessee) is invisible to eviction — its bytes survive any pool
-    churn byte-for-byte."""
+    churn byte-for-byte. While it is out the pool may hold that many bytes
+    beside the floor (and so reuses a smaller slab that comes and goes);
+    when it returns, the pool falls back to the floor."""
     eng = staging_engine
     saved = eng._staging_budget
-    held = eng.acquire_staging(8, (128, 128, 3))  # in flight, never released
+    held = eng.acquire_staging(8, (128, 128, 3))  # in flight, not yet released
     rng = np.random.RandomState(7)
     payload = rng.randint(0, 256, (128, 128, 3), np.uint8)
     held.write_row(0, payload, (128, 128))
     try:
-        eng._staging_budget = 1  # every release must evict something
+        eng._staging_budget = 1  # the floor keeps nothing by itself
+        bufs = set()
         for _ in range(3):
             other = eng.acquire_staging(8, (64, 64, 3))
+            bufs.add(id(other.buf))
             eng._release_staging(other)
-        assert eng.staging_stats()["slabs_pooled_bytes"] <= 1
+            stats = eng.staging_stats()
+            assert stats["slabs_out_bytes"] == held.total_bytes
+            assert stats["slabs_pooled_bytes"] <= 1 + held.total_bytes
+        assert len(bufs) == 1  # kept under held's bytes, and taken again
         # the in-flight slab was never pooled, evicted, or overwritten
         np.testing.assert_array_equal(held.canvases[0], payload)
+        eng._release_staging(held)
+        held = None
+        stats = eng.staging_stats()
+        assert stats["slabs_out"] == 0
+        assert stats["slabs_pooled_bytes"] <= 1
     finally:
         eng._staging_budget = saved
-        eng._release_staging(held)
+        if held is not None:
+            eng._release_staging(held)
+
+
+def test_returned_slab_over_the_floor_is_kept_while_its_like_is_out(staging_engine):
+    """The rule itself. With nothing else out, a returned slab larger than
+    ``staging_pool_bytes`` is dropped and the next acquire allocates (every
+    batch of a shape over the floor paid that before the budget followed
+    the bytes out). With one slab of the shape held out, the returned one
+    is kept, and the next acquire takes it: no allocation."""
+    eng = staging_engine
+    saved = eng._staging_budget
+    shape = (128, 128, 3)
+    assert _out(eng) == (0, 0)
+    try:
+        eng._staging_budget = 1024  # far under one slab of this shape
+        eng.release_staging(eng.acquire_staging(8, shape))  # trims what earlier tests left
+        # Nothing else out: allocations = acquisitions.
+        s0 = eng.staging_stats()
+        for _ in range(3):
+            lone = eng.acquire_staging(8, shape)
+            assert lone.total_bytes > eng._staging_budget
+            eng.release_staging(lone)
+            assert not eng._staging_pool.get(lone.key)
+        s1 = eng.staging_stats()
+        assert s1["slab_acquires_total"] - s0["slab_acquires_total"] == 3
+        assert s1["slab_allocs_total"] - s0["slab_allocs_total"] == 3
+        # One held out (a builder open, a batch in flight): reuse.
+        held = eng.acquire_staging(8, shape)
+        first = eng.acquire_staging(8, shape)
+        s2 = eng.staging_stats()
+        assert (s2["slabs_out"], s2["slabs_out_bytes"]) == (2, 2 * held.total_bytes)
+        eng.release_staging(first)
+        assert first in eng._staging_pool[first.key]
+        for _ in range(3):
+            again = eng.acquire_staging(8, shape)
+            assert again is first
+            eng.release_staging(again)
+        s3 = eng.staging_stats()
+        assert s3["slab_acquires_total"] - s2["slab_acquires_total"] == 3
+        assert s3["slab_allocs_total"] == s2["slab_allocs_total"]
+        assert s3["slabs_pooled_bytes"] <= eng._staging_budget + s3["slabs_out_bytes"]
+        eng.release_staging(held)
+    finally:
+        eng._staging_budget = saved
+
+
+def test_pool_trims_to_the_floor_when_the_last_slab_out_returns(staging_engine):
+    """Idle bytes never exceed the floor plus the bytes out, at every
+    return; when the last slab out comes back the pool is at the floor
+    again, even where the per-key cap, not the budget, dropped that slab."""
+    eng = staging_engine
+    saved, saved_cap = eng._staging_budget, eng._staging_cap
+    shape = (128, 128, 3)
+    assert _out(eng) == (0, 0)
+    try:
+        eng._staging_budget = 1024
+        held = [eng.acquire_staging(8, shape) for _ in range(5)]
+        for slab in held[:-1]:
+            eng.release_staging(slab)
+            st = eng.staging_stats()
+            assert st["slabs_pooled_bytes"] <= 1024 + st["slabs_out_bytes"]
+        assert eng.staging_stats()["slabs_pooled"] >= 1  # traffic keeps its like
+        eng.release_staging(held[-1])
+        st = eng.staging_stats()
+        assert (st["slabs_out"], st["slabs_out_bytes"]) == (0, 0)
+        assert st["slabs_pooled_bytes"] <= 1024 and st["slabs_pooled"] == 0
+        # The same at the per-key cap: the last return is dropped by the
+        # cap and the trim still runs.
+        eng._staging_cap = 2
+        held = [eng.acquire_staging(8, shape) for _ in range(4)]
+        for slab in held:
+            eng.release_staging(slab)
+            assert len(eng._staging_pool[slab.key]) <= 2
+        st = eng.staging_stats()
+        assert st["slabs_out"] == 0 and st["slabs_pooled_bytes"] <= 1024
+    finally:
+        eng._staging_budget, eng._staging_cap = saved, saved_cap
+
+
+@pytest.mark.parametrize("path", ["release", "fetch", "failed_dispatch"])
+def test_slabs_out_returns_to_zero(staging_engine, monkeypatch, path):
+    """``slabs_out`` / ``slabs_out_bytes`` count a slab from acquire to its
+    return, whichever way it comes back: released undispatched, fetched,
+    or recycled by the batcher after a dispatch that raised."""
+    eng = staging_engine
+    assert _out(eng) == (0, 0)
+    if path == "failed_dispatch":
+        def boom(*a, **kw):
+            raise RuntimeError("transient device error")
+
+        monkeypatch.setattr(eng, "_dispatch_on", boom)
+        inflight = eng.staging_stats()["dispatches_inflight"]
+        b = Batcher(eng, max_batch=8, max_delay_ms=1.0)
+        b.start()
+        try:
+            f = b.submit(np.zeros((128, 128, 3), np.uint8), (128, 128))
+            with pytest.raises(RuntimeError):
+                f.result(timeout=30)
+        finally:
+            b.stop()
+        assert eng.staging_stats()["dispatches_inflight"] == inflight
+    else:
+        slab = eng.acquire_staging(8, (128, 128, 3))
+        assert _out(eng) == (1, slab.total_bytes)
+        if path == "release":
+            eng.release_staging(slab)
+        else:
+            eng.fetch_outputs(eng.dispatch_staged(slab, 8))
+    assert _out(eng) == (0, 0)
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_warmup_ends_with_the_pool_at_its_floor(request, ragged):
+    """Warm-up touches every (canvas, batch) pair, several at once, and
+    each slab here is larger than the floor: while they are out the pool
+    may keep their like, and when the last comes back it holds no more
+    than ``staging_pool_bytes``, as before the budget followed the bytes
+    out."""
+    mc = ModelConfig(
+        name="small_cls", pb_path=request.getfixturevalue("small_cls_pb"),
+        input_size=(96, 96), preprocess="inception", dtype="float32",
+    )
+    floor = 20_000  # under the smallest slab: 64 * 64 * 3 * 8
+    cfg = ServerConfig(
+        model=mc, canvas_buckets=(64, 128), batch_buckets=(8, 16),
+        staging_pool_bytes=floor, ragged=ragged,
+    )
+    eng = InferenceEngine(cfg)
+    try:
+        assert eng.ragged is ragged
+        eng.warmup()
+        st = eng.staging_stats()
+        assert st["slab_acquires_total"] >= 4 and st["slab_allocs_total"] >= 1
+        assert (st["slabs_out"], st["slabs_out_bytes"]) == (0, 0)
+        assert st["slabs_pooled_bytes"] <= floor
+    finally:
+        eng.close()
 
 
 def test_slab_held_back_until_last_lease_drops(staging_engine):
