@@ -8,11 +8,13 @@ into a staging-slab row — correct, but it serialized all staging on that
 thread and cost every image a second host copy (decode buffer → canvas →
 slab). This version inverts the flow with **slot leasing**:
 
-- An HTTP worker asks for a slot in the currently-open *batch builder*
-  for its canvas row shape (``lease``). The lease hands back a view of
-  the slot's slab row, and the native decoder writes the JPEG **directly
-  into it** — wire bytes → slab, one copy, staged in parallel across the
-  worker pool with the GIL released.
+- A caller (serving/staging.py's ``stage_image``, on an HTTP worker or a
+  job's decode thread — the one place that picks ``lease`` or
+  ``lease_ragged`` for the wire) asks for a slot in the currently-open
+  *batch builder* for its canvas row shape. The lease hands back a view
+  of the slot's slab row, and the native decoder writes the JPEG
+  **directly into it** — wire bytes → slab, one copy, staged in parallel
+  across the worker pool with the GIL released.
 - ``commit(hw)`` marks the slot ready; ``release()`` abandons it (decode
   failure, client error). A sealed batch pads abandoned/expired slots as
   hw=1×1 holes — the on-device resize reads one pixel and the row's
@@ -333,7 +335,7 @@ class Batcher:
         # Fakes and embedders with the plain signatures never see either
         # keyword.
         self._engine_takes_rec = getattr(engine, "supports_span_tracing", False)
-        # Decode-into-slab is offered to callers (http.py) only when the
+        # Decode-into-slab is offered to callers (staging.py) only when the
         # engine's slabs speak the slot-lease API; otherwise submit() is
         # the entry point and staging is write_row/stack at seal time.
         self.supports_lease = self._staged and getattr(

@@ -17,13 +17,13 @@ stdlib-only front end built for the serving hot path:
   thread anyway.
 - **Connection-reuse counters** (connections vs requests) exported via
   ``/stats`` so keep-alive effectiveness is visible without a profiler.
-- **Decode-into-slab request path.** For engines with slot-lease slabs the
-  handler re-orders the hot path to lease → decode → commit → await: it
-  probes the JPEG header, leases a slot in the assembling batch builder
-  for that canvas bucket, and the native decoder writes the image
-  straight into the leased slab row (one host copy, GIL released,
-  parallel across the worker pool). Decode failures release the slot — a
-  sealed batch pads it as a hw=1×1 hole.
+- **One way in for an image** (serving/staging.py ``stage_image``, which
+  the bulk job runner calls too). This module loops over a request's
+  files and maps what staging raises to a status; which header probe,
+  lease, native decode, PIL fallback and digest go together on the wire
+  the batcher speaks, the order lease → decode into the leased row →
+  cache lookup → commit, and the unwind of a lease and a led flight all
+  live there.
 - **Request-scoped span tracing.** Every request gets a monotonically
   derived trace ID at accept time (or propagates a well-formed inbound
   ``X-Trace-Id``) and carries a Span (utils/tracing.py) through the whole
@@ -33,10 +33,10 @@ stdlib-only front end built for the serving hot path:
   execute enqueue (``device_dispatch``), device execute, postprocess,
   serialize — stamped by this module, the batcher, and the engine.
 - **Content-addressed response cache + single-flight dedup** (serving/
-  respcache.py, ``--cache-bytes``). After the native decode-into-slab the
-  handler digests the decoded canvas and consults the cache BEFORE
-  committing the slot: a hit releases the slot (the sealed batch pads it
-  as a hole) and serves the stored payload with ``X-Cache: hit``; a
+  respcache.py, ``--cache-bytes``). Staging digests the decoded pixels
+  and consults the cache BEFORE committing the slot: a hit gives the slot
+  back (the sealed batch pads it as a hole) and the stored payload is
+  served with ``X-Cache: hit``; a
   concurrent request for the same content coalesces onto the in-flight
   leader's computation (``X-Cache: coalesced`` — a viral image costs one
   device dispatch instead of N); a miss leads and fills the cache. Keys
@@ -138,9 +138,8 @@ from .overload import (
     build_pressure, parse_slo_classes,
 )
 from .registry import FAILED, ModelNotServing, ModelRegistry, UnknownModel
-from .respcache import (
-    ResponseCache, canvas_digest, make_key, packed_digest, payload_etag,
-)
+from .respcache import ResponseCache, payload_etag
+from .staging import UndecodableImage, abort_slots, stage_image
 from .telemetry import build_hub
 
 log = logging.getLogger("tpu_serve.http")
@@ -1729,8 +1728,8 @@ class App:
         requests shed before spending decode or device time."""
         model_cfg = mv.model_cfg
         batcher = mv.batcher
-        # One clamp shared with the bulk tier: the clamped topk feeds
-        # make_key, so the key spaces stay identical (jobs.clamp_topk).
+        # One clamp shared with the bulk tier: the clamped topk is part of
+        # the cache key, so the key spaces stay identical (jobs.clamp_topk).
         topk = clamp_topk(topk_req, model_cfg)
         if batcher is None:  # construction without a batcher: draining
             return (
@@ -1786,27 +1785,69 @@ class App:
 
         span.note("images", len(named))
         cache = self.cache if self.cache.enabled else None
-        # Stage every image before waiting on any: slots land in the same
-        # batch-assembly window, so same-canvas-bucket images typically
-        # share one device dispatch (mixed buckets split by design —
-        # builders are per canvas shape). Each staged image becomes one
-        # slot: a cached payload ("done"), a coalesced wait on another
-        # request's in-flight computation ("wait"), or this request's own
-        # batch future ("own").
-        if getattr(batcher, "supports_lease", False):
-            slots, err = self._stage_leases(named, span, batcher, mv, topk,
-                                            cache, tenant=tenant,
-                                            slo_class=slo_class,
-                                            slo_deadline=slo_deadline,
-                                            level=level)
-        else:
-            slots, err = self._stage_submits(named, span, batcher, mv, topk,
-                                             cache, tenant=tenant,
-                                             slo_class=slo_class,
-                                             slo_deadline=slo_deadline,
-                                             level=level)
-        if err is not None:
-            return err
+        # Shed level is ladder-relative: the LAST rung rejects cache-miss
+        # work (level 3 legacy, 4 once a quant-reroute rung is configured);
+        # hits and coalesced waits still ride.
+        reject_level = (self.pressure.reject_level
+                        if self.pressure is not None else 3)
+        buckets = self.cfg.canvas_buckets
+        if level >= 2 and len(buckets) > 1:
+            # Rung 2: every image lands in the smallest canvas bucket —
+            # less decode work, denser batches, and a hotter cache (the
+            # key space collapses with the bucket set).
+            buckets = buckets[:1]
+        # Stage every image before waiting on any (serving/staging.py):
+        # slots land in the same batch-assembly window, so
+        # same-canvas-bucket images typically share one device dispatch
+        # (mixed buckets split by design — builders are per canvas shape).
+        # Each staged image becomes one slot: a cached payload ("done"), a
+        # coalesced wait on another request's in-flight computation
+        # ("wait"), or this request's own batch future ("own").
+        slots: list[tuple] = []
+
+        def refuse(msg):
+            # stage_image left nothing behind for the image it refused; the
+            # request's earlier slots become padded holes and their led
+            # flights abort, before any answer.
+            abort_slots(slots, cache, RuntimeError(msg))
+            return ("400 Bad Request", json.dumps({"error": msg}).encode(),
+                    "application/json")
+
+        # Stamped at zero first: a request refused before any decode still
+        # counts in the stage's histogram, as it always has.
+        span.add("image_decode", 0.0)
+        try:
+            for i, (fname, data) in enumerate(named):
+                where = ("request body" if len(named) == 1
+                         else f"file '{fname}' (#{i})")
+                if not data:
+                    return refuse(f"empty {where}")
+                slots.append(stage_image(
+                    data, batcher=batcher, mv=mv, cache=cache, topk=topk,
+                    buckets=buckets, span=span, tenant=tenant,
+                    deadline=slo_deadline, chaos=self.chaos,
+                    shed_misses=level >= reject_level))
+        except UndecodableImage as e:
+            return refuse(f"could not decode image: {where}{e.note}")
+        except ShuttingDown as e:
+            abort_slots(slots, cache, e)
+            return (
+                "503 Service Unavailable",
+                b'{"error": "server shutting down"}',
+                "application/json",
+            )
+        except (BacklogFull, QuotaExceeded, DeadlineExceeded, Degraded) as e:
+            # Fast rejects (503 / 429 / 504 / 503) with a machine-readable
+            # reason and Retry-After, in microseconds instead of queueing
+            # the upload toward the request timeout.
+            abort_slots(slots, cache, e)
+            return self._shed_response(e, tenant, slo_class)
+        except BaseException as e:
+            # A PENDING slot would hold its whole builder back (stalling
+            # every sibling request) until the lease timeout; release
+            # before the request-level 500 handler answers.
+            abort_slots(slots, cache, e)
+            raise
         payloads: list = [None] * len(slots)
         etags: list = [None] * len(slots)
         n_hit = n_wait = 0
@@ -1862,7 +1903,7 @@ class App:
             # Undispatched slots become padded holes instead of wasting a
             # device dispatch on a request nobody is waiting for; led
             # flights abort so coalesced waiters fail over immediately.
-            self._abort_slots(slots, TimeoutError("inference timed out"))
+            abort_slots(slots, cache, TimeoutError("inference timed out"))
             return self._shed_response(
                 DeadlineExceeded("inference timed out"), tenant, slo_class)
         except DeadlineExceeded as e:
@@ -1871,25 +1912,25 @@ class App:
             # Same 504 + reason as an admission-time shed — the client
             # cannot tell (and should not care) which side of the seal
             # the deadline crossed.
-            self._abort_slots(slots, e)
+            abort_slots(slots, cache, e)
             return self._shed_response(e, tenant, slo_class)
         except ShuttingDown as e:
             # 503, not 500: the standard draining signal — load balancers
             # retry another backend instead of flagging an application bug.
-            self._abort_slots(slots, e)
+            abort_slots(slots, cache, e)
             return (
                 "503 Service Unavailable",
                 b'{"error": "server shutting down"}',
                 "application/json",
             )
         except _CoalesceRetry as e:
-            self._abort_slots(slots, e.__cause__ or e)
+            abort_slots(slots, cache, e.__cause__ or e)
             raise
         except BaseException as e:
             # Any other failure (expired lease, poisoned batch): the led
             # flights must abort before the 500 propagates, or waiters
             # would hang to their own timeouts.
-            self._abort_slots(slots, e)
+            abort_slots(slots, cache, e)
             raise
         extra_headers: list[tuple[str, str]] = []
         if cache is not None:
@@ -1976,400 +2017,6 @@ class App:
             "application/json",
             [("Retry-After", str(max(1, int(round(retry)))))],
         )
-
-    @staticmethod
-    def _consult_cache(cache, mv, topk, canvas, hw, span):
-        """Content digest + single-flight lookup for one staged image
-        (the ``cache_lookup`` span stage's work), shared by the lease and
-        submit staging paths. The key itself comes from respcache's
-        make_key/canvas_digest — the shared constructors the bulk path
-        (jobs._stage_one, ``bulk=True`` accounting) builds the SAME keys
-        with, which is what makes a job's misses pre-warm the interactive
-        tier: a change to keying belongs in respcache, never here or in
-        jobs.py. Returns ``(kind, obj)``; ``(None, None)``, and no stage,
-        with the cache disabled."""
-        if cache is None:
-            return None, None
-        with stage(span, "cache_lookup"):
-            key = make_key(mv.name, mv.version, canvas_digest(canvas, hw),
-                           topk, getattr(mv.model_cfg, "dtype", "bfloat16"))
-            return cache.begin(key, mv.name)
-
-    @staticmethod
-    def _consult_cache_packed(cache, mv, topk, tight, hw, bucket_s, span):
-        """Ragged-wire twin of :meth:`_consult_cache`: the digest hashes
-        the TIGHT decoded bytes + (h, w) + canvas bucket
-        (respcache.packed_digest) — the same equivalence classes as
-        canvas_digest, because the device-side unpack is a deterministic
-        function of exactly those three. jobs._stage_one builds the same
-        keys for bulk staging; keying changes belong in respcache."""
-        if cache is None:
-            return None, None
-        with stage(span, "cache_lookup"):
-            key = make_key(mv.name, mv.version,
-                           packed_digest(tight, hw, bucket_s), topk,
-                           getattr(mv.model_cfg, "dtype", "bfloat16"))
-            return cache.begin(key, mv.name)
-
-    def _abort_slots(self, slots, exc: BaseException) -> None:
-        """Unwind a partially-staged/awaited request: cancel + release its
-        OWN batch slots (committed slots of a request that 400d/timed out
-        become padded holes; dispatched slots are past saving and their
-        results are simply dropped) and abort its led cache flights so
-        coalesced waiters fail over immediately instead of hanging to
-        their own timeouts. "done"/"wait" slots hold nothing to unwind —
-        other requests own those computations."""
-        for slot in slots:
-            if slot[0] != "own":
-                continue
-            _, future, _orig, flight, lease = slot
-            try:
-                future.cancel()
-            except Exception:
-                pass
-            if lease is not None:
-                try:
-                    lease.release()
-                except Exception:
-                    pass
-            if flight is not None:
-                self.cache.abort(flight, exc)
-
-    def _stage_leases(self, named, span, batcher, mv, topk, cache,
-                      tenant=DEFAULT_TENANT, slo_class="interactive",
-                      slo_deadline=None, level=0):
-        """Decode every upload directly into a leased batch slot, with the
-        response cache consulted between decode and commit.
-
-        Returns ``(slots, error_response)``; one slot per image, in upload
-        order: ``("done", payload, etag)`` — served from cache (the leased
-        slot was released back, so a sealed batch pads it as a hw=1×1
-        hole — the whole point: a hot image costs no device work);
-        ``("wait", flight)`` — coalesced onto another request's in-flight
-        computation for the same content key; ``("own", future, orig,
-        flight, lease)`` — this request computes (``flight`` is the led
-        single-flight, None with the cache disabled).
-
-        The JPEG fast path is probe header → lease slot for the probed
-        canvas bucket → native decode INTO the slab row (the image's
-        single host copy) → digest + cache consult → commit. Non-JPEGs
-        (and native-decode failures past the header probe) take PIL into
-        a scratch canvas — there the digest comes for free BEFORE leasing,
-        so cache hits never touch the batcher at all. Any per-file failure
-        releases all of the request's slots and aborts its led flights.
-        """
-        from .. import native
-        from ..ops.image import (
-            decode_image, fit_to_bucket, pad_to_canvas, rgb_to_yuv420_canvas,
-        )
-
-        # Ragged wire (ROADMAP item 5): uploads stage as TIGHT bytes in
-        # flat arenas (batcher.lease_ragged) instead of padded canvas
-        # rows — the JPEG fast path plans the exact byte span from the
-        # header and native-decodes at native stride; PIL fallbacks copy
-        # the decoded array tight. Cache keys switch to packed_digest
-        # (same equivalence classes; the device-side unpack is
-        # deterministic).
-        ragged = getattr(batcher, "ragged", False)
-        # Shed level is ladder-relative: the LAST rung rejects cache-miss
-        # work (level 3 legacy, 4 once a quant-reroute rung is configured).
-        reject_level = (self.pressure.reject_level
-                        if self.pressure is not None else 3)
-        buckets = self.cfg.canvas_buckets
-        if level >= 2 and len(buckets) > 1:
-            # Rung 2: every image lands in the smallest canvas bucket —
-            # less decode work, denser batches, and a hotter cache (the
-            # key space collapses with the bucket set).
-            buckets = buckets[:1]
-        wire = self.cfg.wire_format
-        slots = []
-        lease = None
-        flight = None
-        # Every stretch of decode work (header probe, native decode, PIL
-        # fallback) is one ``image_decode`` stage block, every digest +
-        # lookup one ``cache_lookup`` block; the span sums them per request.
-        # Stamped at zero first: a request refused before any decode still
-        # counts in the stage's histogram, as it always has.
-        span.add("image_decode", 0.0)
-
-        def consult(canvas, hw):
-            return self._consult_cache(cache, mv, topk, canvas, hw, span)
-
-        def consult_packed(tight, hw, s):
-            return self._consult_cache_packed(cache, mv, topk, tight, hw, s,
-                                              span)
-
-        def fail(status, msg):
-            self._abort_slots(slots, RuntimeError(msg))
-            return None, (status, json.dumps({"error": msg}).encode(),
-                          "application/json")
-
-        try:
-            for i, (fname, data) in enumerate(named):
-                where = ("request body" if len(named) == 1
-                         else f"file '{fname}' (#{i})")
-                if not data:
-                    return fail("400 Bad Request", f"empty {where}")
-                lease = flight = None
-                staged = False
-                if self.chaos is not None and self.chaos.decode_fault():
-                    # Injected decode failure: indistinguishable from a
-                    # genuinely corrupt upload — the 400 path must unwind
-                    # every slot and flight this request already staged.
-                    return fail("400 Bad Request",
-                                f"could not decode image: {where} "
-                                "(chaos: injected decode failure)")
-                with stage(span, "image_decode"):  # header probe
-                    plan = (native.plan_decode_packed(data, buckets) if ragged
-                            else native.plan_decode(data, buckets, wire))
-                if plan is not None and ragged:
-                    s, need, _dhw, orig = plan
-                    lease = batcher.lease_ragged(need, s, span=span,
-                                                 deadline=slo_deadline,
-                                                 tenant=tenant)
-                    # Tight native-stride decode straight into the leased
-                    # arena span — the image's single host copy; the C
-                    # side re-validates the span's capacity (an overrun
-                    # would corrupt a NEIGHBORING image's bytes).
-                    with stage(span, "image_decode"):
-                        hw = native.decode_packed_into(data, lease.row, s)
-                    if hw is None:
-                        # Header parsed but the stream didn't decode: give
-                        # the span back (it ships as a hole) and let PIL
-                        # try.
-                        lease.release()
-                        lease = None
-                    else:
-                        kind, obj = consult_packed(lease.row, hw, s)
-                        if kind in ("hit", "wait"):
-                            lease.release()
-                            lease = None
-                            slots.append(("done", obj.payload, obj.etag)
-                                         if kind == "hit" else ("wait", obj))
-                        else:
-                            flight = obj  # None with the cache disabled
-                            if level >= reject_level:
-                                raise Degraded(
-                                    "shedding cache-miss work under "
-                                    "overload (degradation reject rung)")
-                            lease.commit(hw)
-                            slots.append(
-                                ("own", lease.future, orig, flight, lease)
-                            )
-                            lease = flight = None
-                    staged = hw is not None
-                elif plan is not None:
-                    s, row_shape, orig = plan
-                    lease = batcher.lease(row_shape, span=span,
-                                          deadline=slo_deadline,
-                                          tenant=tenant)
-                    with stage(span, "image_decode"):
-                        hw = (native.decode_into_row(data, lease.row, s, wire)
-                              if lease.row is not None else None)
-                    if hw is None:
-                        # Header parsed but the stream didn't decode (or the
-                        # slab lacks row views): give the slot back and let
-                        # PIL try.
-                        lease.release()
-                        lease = None
-                    else:
-                        # The decoder zero/neutral-pads the whole row, so
-                        # the digest is deterministic across slab reuse.
-                        kind, obj = consult(lease.row, hw)
-                        if kind in ("hit", "wait"):
-                            lease.release()
-                            lease = None
-                            slots.append(("done", obj.payload, obj.etag)
-                                         if kind == "hit" else ("wait", obj))
-                        else:
-                            flight = obj  # None with the cache disabled
-                            if level >= reject_level:
-                                # Rung 3: cache-miss work is the expensive
-                                # traffic — shed it; hits and coalesced
-                                # waits above still ride for free.
-                                raise Degraded(
-                                    "shedding cache-miss work under "
-                                    "overload (degradation reject rung)")
-                            lease.commit(hw)
-                            slots.append(
-                                ("own", lease.future, orig, flight, lease)
-                            )
-                            lease = flight = None
-                        staged = True
-                if not staged and ragged:
-                    try:
-                        with stage(span, "image_decode"):
-                            img = decode_image(data)
-                    except Exception:
-                        return fail("400 Bad Request",
-                                    f"could not decode image: {where}")
-                    # Tight PIL fallback: host-downscale to the bucket if
-                    # oversized, no canvas padding — the digest comes free
-                    # BEFORE leasing, so cache hits never touch the
-                    # batcher at all.
-                    with stage(span, "image_decode"):
-                        tight, hw, s = fit_to_bucket(img, buckets)
-                    orig = (img.shape[0], img.shape[1])
-                    kind, obj = consult_packed(tight, hw, s)
-                    if kind in ("hit", "wait"):
-                        slots.append(("done", obj.payload, obj.etag)
-                                     if kind == "hit" else ("wait", obj))
-                    else:
-                        flight = obj
-                        if level >= reject_level:
-                            raise Degraded(
-                                "shedding cache-miss work under overload "
-                                "(degradation reject rung)")
-                        lease = batcher.lease_ragged(
-                            hw[0] * hw[1] * 3, s, span=span,
-                            deadline=slo_deadline, tenant=tenant)
-                        lease.commit(hw, canvas=tight)
-                        slots.append(("own", lease.future, orig, flight,
-                                      lease))
-                        lease = flight = None
-                elif not staged:
-                    try:
-                        with stage(span, "image_decode"):
-                            img = decode_image(data)
-                    except Exception:
-                        return fail("400 Bad Request",
-                                    f"could not decode image: {where}")
-                    with stage(span, "image_decode"):
-                        canvas, hw = pad_to_canvas(img, buckets)
-                        if wire == "yuv420":
-                            canvas = rgb_to_yuv420_canvas(canvas)
-                    orig = (img.shape[0], img.shape[1])
-                    kind, obj = consult(canvas, hw)
-                    if kind in ("hit", "wait"):
-                        slots.append(("done", obj.payload, obj.etag)
-                                     if kind == "hit" else ("wait", obj))
-                    else:
-                        flight = obj
-                        if level >= reject_level:
-                            raise Degraded(
-                                "shedding cache-miss work under overload "
-                                "(degradation reject rung)")
-                        lease = batcher.lease(tuple(canvas.shape), span=span,
-                                              deadline=slo_deadline,
-                                              tenant=tenant)
-                        lease.commit(hw, canvas=canvas)
-                        slots.append(("own", lease.future, orig, flight, lease))
-                        lease = flight = None
-        except ShuttingDown as e:
-            if flight is not None:
-                self.cache.abort(flight, e)
-            self._abort_slots(slots, e)
-            return None, (
-                "503 Service Unavailable",
-                b'{"error": "server shutting down"}',
-                "application/json",
-            )
-        except BacklogFull as e:
-            # Bounded-queue fast reject: release this request's earlier
-            # slots (they become padded holes), abort its led flights, and
-            # answer 503 + Retry-After in microseconds instead of queueing
-            # the upload toward the request timeout.
-            if flight is not None:
-                self.cache.abort(flight, e)
-            self._abort_slots(slots, e)
-            return None, self._shed_response(e, tenant, slo_class)
-        except (QuotaExceeded, DeadlineExceeded, Degraded) as e:
-            # Overload sheds — same fast unwind as BacklogFull, mapped to
-            # their own statuses (429 / 504 / 503) with a machine-readable
-            # reason. A Degraded raise may hold a lease (native path leads
-            # the flight after leasing), so release it too.
-            if flight is not None:
-                self.cache.abort(flight, e)
-            if lease is not None:
-                try:
-                    lease.release()
-                except Exception:
-                    pass
-            self._abort_slots(slots, e)
-            return None, self._shed_response(e, tenant, slo_class)
-        except Exception as e:
-            # Any unexpected failure in the lease→commit window must not
-            # leave a PENDING slot behind: it would hold the whole builder
-            # back (stalling every sibling request) until the lease timeout
-            # expires it. Release what we hold — and abort any flight led
-            # but not yet slotted — then let the request-level 500 handler
-            # answer.
-            if flight is not None:
-                self.cache.abort(flight, e)
-            if lease is not None:
-                try:
-                    lease.release()
-                except Exception:
-                    pass
-            self._abort_slots(slots, e)
-            raise
-        return slots, None
-
-    def _stage_submits(self, named, span, batcher, mv, topk, cache,
-                       tenant=DEFAULT_TENANT, slo_class="interactive",
-                       slo_deadline=None, level=0):
-        """Staging for engines without slot-lease slabs (mocks, embedders):
-        decode to a canvas with ``prepare_bytes``, consult the cache, then
-        submit the misses — the batcher still slots each canvas into its
-        builder with one write_row copy. Same slot shapes as
-        :meth:`_stage_leases`."""
-        slots = []
-        reject_level = (self.pressure.reject_level
-                        if self.pressure is not None else 3)
-        span.add("image_decode", 0.0)
-
-        def fail(status, msg):
-            self._abort_slots(slots, RuntimeError(msg))
-            return None, (status, json.dumps({"error": msg}).encode(),
-                          "application/json")
-
-        for i, (fname, data) in enumerate(named):
-            where = ("request body" if len(named) == 1
-                     else f"file '{fname}' (#{i})")
-            if not data:
-                return fail("400 Bad Request", f"empty {where}")
-            if self.chaos is not None and self.chaos.decode_fault():
-                return fail("400 Bad Request",
-                            f"could not decode image: {where} "
-                            "(chaos: injected decode failure)")
-            try:
-                with stage(span, "image_decode"):
-                    canvas, hw, orig = mv.engine.prepare_bytes(data)
-            except Exception:
-                return fail("400 Bad Request",
-                            f"could not decode image: {where}")
-            flight = None
-            if cache is not None:
-                kind, obj = self._consult_cache(cache, mv, topk, canvas, hw,
-                                                span)
-                if kind == "hit":
-                    slots.append(("done", obj.payload, obj.etag))
-                    continue
-                if kind == "wait":
-                    slots.append(("wait", obj))
-                    continue
-                flight = obj
-            try:
-                if level >= reject_level and cache is not None:
-                    # The reject rung sheds the misses here too; with the cache
-                    # disabled there is no hit tier to preserve, so the
-                    # backlog/deadline gates do the shedding instead.
-                    raise Degraded(
-                        "shedding cache-miss work under overload "
-                        "(degradation reject rung)")
-                future = batcher.submit(canvas, hw, span=span,
-                                        deadline=slo_deadline, tenant=tenant)
-            except (BacklogFull, QuotaExceeded, DeadlineExceeded,
-                    Degraded) as e:
-                # Already-submitted sibling images of this request resolve
-                # in their batches with nobody waiting — their results are
-                # dropped, which is exactly the committed-hole semantics.
-                if flight is not None:
-                    self.cache.abort(flight, e)
-                self._abort_slots(slots, e)
-                return None, self._shed_response(e, tenant, slo_class)
-            slots.append(("own", future, orig, flight, None))
-        return slots, None
 
     def _format_row(self, row, orig_hw, topk: int, mv) -> dict:
         """One image's batcher row → its JSON payload. The formatter lives

@@ -59,13 +59,15 @@ steal latency budget from the interactive path.
   flight during the drain retry against the new version — zero lost,
   zero duplicated.
 
-- **Cache interplay** (serving/respcache.py): every staged image
-  consults the content-addressed response cache before taking a batch
-  slot, so bulk re-runs dedup for free — and a job's misses POPULATE the
-  cache, pre-warming the interactive tier for the corpus it just
-  processed. Bulk lookups are accounted separately (``bulk`` counters in
-  the cache stats) so the hit-rate the interactive dashboard shows is
-  not diluted by batch traffic.
+- **Staging and cache interplay.** An item becomes a batch slot through
+  the same function as an upload (serving/staging.py ``stage_image``,
+  here with ``bulk=True``), so it consults the content-addressed
+  response cache (serving/respcache.py) under the same key: bulk re-runs
+  dedup for free — and a job's misses POPULATE the cache, pre-warming the
+  interactive tier for the corpus it just processed. Bulk lookups are
+  accounted separately (``bulk`` counters in the cache stats) so the
+  hit-rate the interactive dashboard shows is not diluted by batch
+  traffic.
 
 Concurrency: one condition (``jobs.cond``, declared in
 tools/twdlint/lockorder.toml between registry.cond and batcher.cond)
@@ -93,7 +95,7 @@ from ..utils.locks import named_condition
 from ..utils.tracing import Span
 from .batcher import ShuttingDown as ShuttingDownError
 from .registry import ModelNotServing, UnknownModel
-from .respcache import canvas_digest, make_key, packed_digest
+from .staging import UndecodableImage, abort_slots, stage_image
 
 log = logging.getLogger("tpu_serve.jobs")
 
@@ -137,8 +139,9 @@ def clamp_topk(topk: int | None, model_cfg) -> int:
     """THE topk clamp (None = model default; both bounds enforced — a
     negative topk would slice labels from the wrong end). Shared by the
     interactive path (http._predict_on) and every bulk staging/format/
-    retry site: the clamped value feeds make_key, so one definition is
-    what keeps the interactive and bulk cache key spaces identical."""
+    retry site: the clamped value is part of the cache key, so one
+    definition is what keeps the interactive and bulk cache key spaces
+    identical."""
     if topk is None:
         return model_cfg.topk
     return min(max(topk, 0), model_cfg.topk)
@@ -307,6 +310,21 @@ class _Chunk:
         self.decode_s = decode_s
         self.cache_s = cache_s
         self.t_staged = time.monotonic()
+
+
+class _StageTimes:
+    """What one item's staging hands ``stage_image`` for a span: the two
+    stage totals, which the chunk's span receives as ``job_decode`` and
+    ``job_cache_lookup`` (a chunk's items stage on pool threads before the
+    chunk's span exists)."""
+
+    __slots__ = ("image_decode", "cache_lookup")
+
+    def __init__(self):
+        self.image_decode = self.cache_lookup = 0.0
+
+    def add(self, stage: str, dur_s: float) -> None:
+        setattr(self, stage, getattr(self, stage) + dur_s)
 
 
 # ------------------------------------------------------------ the manager
@@ -989,8 +1007,8 @@ class JobManager:
             else:
                 for i in range(start, end):
                     if self._should_stop(job):
-                        self._abort_slots(slots,
-                                          RuntimeError("job interrupted"))
+                        abort_slots(slots, self.cache,
+                                    RuntimeError("job interrupted"))
                         self.registry.release(mv)
                         return None
                     slot, d_s, c_s = self._stage_item(mv, batcher,
@@ -1000,7 +1018,7 @@ class JobManager:
                     cache_s += c_s
                     slots.append(slot)
         except Exception as e:
-            self._abort_slots(slots, e)
+            abort_slots(slots, self.cache, e)
             self.registry.release(mv)
             raise
         # Seal whatever this chunk left open: a full chunk already sealed
@@ -1024,264 +1042,35 @@ class JobManager:
 
     def _stage_item(self, mv, batcher, item: dict, topk: int,
                     tenant: str = "default"):
-        """One manifest item → one slot (decode-pool worker body): file
-        read errors become error lines; a batcher shutting down under us
-        (hot-swap drain racing the staging) defers the item to the retry
-        path instead of failing the whole job."""
+        """One manifest item → ``(slot, decode seconds, lookup seconds)``
+        (decode-pool worker body). The slot is serving/staging.py's —
+        staged as BULK: bulk builders, bulk cache counters, the same keys
+        as the interactive path — or ``("err", msg)`` for a file that
+        cannot be read or decoded (the job still finishes, the error
+        counted per image), or ``("retry",)`` when the batcher is shutting
+        down under us (hot-swap drain racing the staging): the item is
+        deferred to the retry path instead of failing the whole job."""
         try:
             data = Path(item["path"]).read_bytes()
         except OSError as e:
             return ("err", f"read failed: {e}"), 0.0, 0.0
+        cache = self.cache
+        if cache is not None and not cache.enabled:
+            cache = None
+        times = _StageTimes()
         try:
-            return self._stage_one(mv, batcher, data, topk, tenant=tenant)
+            slot = stage_image(
+                data, batcher=batcher, mv=mv, cache=cache, topk=topk,
+                buckets=self.cfg.canvas_buckets, span=times, bulk=True,
+                tenant=tenant, chaos=getattr(self.registry, "chaos", None))
+        except UndecodableImage as e:
+            slot = ("err", str(e))
         except ShuttingDownError:
-            return ("retry",), 0.0, 0.0
-
-    def _stage_one(self, mv, batcher, data: bytes, topk: int,
-                   tenant: str = "default"):
-        """One image → one slot: ``("done", payload)`` served from cache,
-        ``("wait", flight)`` coalesced onto an in-flight computation,
-        ``("own", future, orig, flight, lease)`` computing through a BULK
-        batch slot, or ``("err", msg)`` on decode failure. Mirrors the
-        interactive path's staging (http.App) minus the HTTP error
-        mapping; cache lookups are tagged bulk for separate accounting."""
-        cache = self.cache if self.cache is not None and self.cache.enabled \
-            else None
-        decode_s = cache_s = 0.0
-        chaos = getattr(self.registry, "chaos", None)
-        if chaos is not None and chaos.decode_fault():
-            # Injected decode failure: becomes this image's error line —
-            # the job still finishes, with the error counted per image.
-            return (("err", "could not decode image "
-                     "(chaos: injected decode failure)"), decode_s, cache_s)
-        if getattr(batcher, "supports_lease", False):
-            from .. import native
-            from ..ops.image import (
-                decode_image, fit_to_bucket, pad_to_canvas,
-                rgb_to_yuv420_canvas,
-            )
-
-            buckets = self.cfg.canvas_buckets
-            wire = self.cfg.wire_format
-            # Ragged wire: bulk chunks ship tight pixels through the same
-            # packed-slab path as interactive requests — no host-side
-            # pad-to-canvas, cache keyed on the post-resize canvas via
-            # packed_digest so hit semantics match the interactive path.
-            ragged = getattr(batcher, "ragged", False)
-            t0 = time.monotonic()
-            plan = (native.plan_decode_packed(data, buckets) if ragged
-                    else native.plan_decode(data, buckets, wire))
-            decode_s += time.monotonic() - t0
-            if plan is not None and ragged:
-                s, need, _dhw, orig = plan
-                lease = batcher.lease_ragged(need, s, bulk=True,
-                                             tenant=tenant)
-                t0 = time.monotonic()
-                hw = native.decode_packed_into(data, lease.row, s)
-                decode_s += time.monotonic() - t0
-                if hw is None:
-                    lease.release()  # header lied; PIL gets a try below
-                else:
-                    flight = None
-                    if cache is not None:
-                        t0 = time.monotonic()
-                        key = make_key(mv.name, mv.version,
-                                       packed_digest(lease.row, hw, s),
-                                       topk,
-                                       getattr(mv.model_cfg, "dtype",
-                                               "bfloat16"))
-                        kind, obj = cache.begin(key, mv.name, bulk=True)
-                        cache_s += time.monotonic() - t0
-                        if kind == "hit":
-                            lease.release()
-                            return (("done", obj.payload),
-                                    decode_s, cache_s)
-                        if kind == "wait":
-                            lease.release()
-                            return (("wait", obj), decode_s, cache_s)
-                        flight = obj
-                    try:
-                        lease.commit(hw)
-                    except BaseException as e:
-                        # Same unwind discipline as the classic branch
-                        # below: a led flight must not outlive a failed
-                        # commit.
-                        try:
-                            lease.release()
-                        finally:
-                            if flight is not None:
-                                cache.abort(flight, e)
-                        raise
-                    return (("own", lease.future, orig, flight, lease),
-                            decode_s, cache_s)
-            elif plan is not None:
-                s, row_shape, orig = plan
-                lease = batcher.lease(row_shape, bulk=True, tenant=tenant)
-                t0 = time.monotonic()
-                hw = (native.decode_into_row(data, lease.row, s, wire)
-                      if lease.row is not None else None)
-                decode_s += time.monotonic() - t0
-                if hw is None:
-                    lease.release()  # header lied; PIL gets a try below
-                else:
-                    flight = None
-                    if cache is not None:
-                        t0 = time.monotonic()
-                        key = make_key(mv.name, mv.version,
-                                       canvas_digest(lease.row, hw), topk,
-                                       getattr(mv.model_cfg, "dtype",
-                                               "bfloat16"))
-                        kind, obj = cache.begin(key, mv.name, bulk=True)
-                        cache_s += time.monotonic() - t0
-                        if kind == "hit":
-                            lease.release()
-                            return (("done", obj.payload), decode_s, cache_s)
-                        if kind == "wait":
-                            lease.release()
-                            return (("wait", obj), decode_s, cache_s)
-                        flight = obj
-                    try:
-                        lease.commit(hw)
-                    except BaseException as e:
-                        # A led flight must never outlive a failed commit
-                        # (ShuttingDown under a swap/SIGTERM race): the
-                        # retry path re-stages with a FRESH flight, and
-                        # waiters coalesced onto this one would otherwise
-                        # hang to their own timeouts. Release-then-abort,
-                        # each guarded, so neither unwind can starve the
-                        # other.
-                        try:
-                            lease.release()
-                        finally:
-                            if flight is not None:
-                                cache.abort(flight, e)
-                        raise
-                    return (("own", lease.future, orig, flight, lease),
-                            decode_s, cache_s)
-            t0 = time.monotonic()
-            try:
-                img = decode_image(data)
-            except Exception:
-                decode_s += time.monotonic() - t0
-                return (("err", "could not decode image"), decode_s, cache_s)
-            if ragged:
-                # PIL fallback on the ragged wire: resize-to-fit on the
-                # host (no canvas padding), consult the cache BEFORE
-                # leasing so hits never touch the batcher, then copy the
-                # tight bytes into the leased arena span via commit().
-                tight, hw, s = fit_to_bucket(img, buckets)
-                orig = (img.shape[0], img.shape[1])
-                decode_s += time.monotonic() - t0
-                flight = None
-                if cache is not None:
-                    t0 = time.monotonic()
-                    key = make_key(mv.name, mv.version,
-                                   packed_digest(tight, hw, s), topk,
-                                   getattr(mv.model_cfg, "dtype",
-                                           "bfloat16"))
-                    kind, obj = cache.begin(key, mv.name, bulk=True)
-                    cache_s += time.monotonic() - t0
-                    if kind == "hit":
-                        return (("done", obj.payload), decode_s, cache_s)
-                    if kind == "wait":
-                        return (("wait", obj), decode_s, cache_s)
-                    flight = obj
-                try:
-                    lease = batcher.lease_ragged(hw[0] * hw[1] * 3, s,
-                                                 bulk=True, tenant=tenant)
-                except BaseException as e:
-                    if flight is not None:
-                        cache.abort(flight, e)
-                    raise
-                try:
-                    lease.commit(hw, canvas=tight)
-                except BaseException as e:
-                    try:
-                        lease.release()
-                    finally:
-                        if flight is not None:
-                            cache.abort(flight, e)
-                    raise
-                return (("own", lease.future, orig, flight, lease),
-                        decode_s, cache_s)
-            canvas, hw = pad_to_canvas(img, buckets)
-            if wire == "yuv420":
-                canvas = rgb_to_yuv420_canvas(canvas)
-            orig = (img.shape[0], img.shape[1])
-            decode_s += time.monotonic() - t0
-        else:
-            t0 = time.monotonic()
-            try:
-                canvas, hw, orig = mv.engine.prepare_bytes(data)
-            except Exception:
-                decode_s += time.monotonic() - t0
-                return (("err", "could not decode image"), decode_s, cache_s)
-            decode_s += time.monotonic() - t0
-        flight = None
-        if cache is not None:
-            t0 = time.monotonic()
-            key = make_key(mv.name, mv.version, canvas_digest(canvas, hw),
-                           topk,
-                           getattr(mv.model_cfg, "dtype", "bfloat16"))
-            kind, obj = cache.begin(key, mv.name, bulk=True)
-            cache_s += time.monotonic() - t0
-            if kind == "hit":
-                return (("done", obj.payload), decode_s, cache_s)
-            if kind == "wait":
-                return (("wait", obj), decode_s, cache_s)
-            flight = obj
-        # Past this point the flight is led: any raise (lease/commit/
-        # submit hitting a batcher mid-drain) must abort it — see the
-        # native branch above for why a leaked flight is poison.
-        if getattr(batcher, "supports_lease", False):
-            try:
-                lease = batcher.lease(tuple(canvas.shape), bulk=True,
-                                      tenant=tenant)
-            except BaseException as e:
-                if flight is not None:
-                    cache.abort(flight, e)
-                raise
-            try:
-                lease.commit(hw, canvas=canvas)
-            except BaseException as e:
-                try:
-                    lease.release()
-                finally:
-                    if flight is not None:
-                        cache.abort(flight, e)
-                raise
-            return (("own", lease.future, orig, flight, lease),
-                    decode_s, cache_s)
-        try:
-            future = batcher.submit(canvas, hw, bulk=True)
-        except BaseException as e:
-            if flight is not None:
-                cache.abort(flight, e)
-            raise
-        return (("own", future, orig, flight, None), decode_s, cache_s)
-
-    def _abort_slots(self, slots, exc: BaseException):
-        """Unwind staged-but-unfinished slots: cancel own futures, release
-        own leases (sealed batches pad them as holes), abort led flights
-        so foreign coalesced waiters fail over instead of hanging."""
-        for slot in slots:
-            if slot[0] != "own":
-                continue
-            _, future, _orig, flight, lease = slot
-            try:
-                future.cancel()
-            except Exception:
-                pass
-            if lease is not None:
-                try:
-                    lease.release()
-                except Exception:
-                    pass
-            if flight is not None and self.cache is not None:
-                self.cache.abort(flight, exc)
+            slot = ("retry",)
+        return slot, times.image_decode, times.cache_lookup
 
     def _abort_chunk(self, ch: _Chunk, exc: BaseException):
-        self._abort_slots(ch.slots, exc)
+        abort_slots(ch.slots, self.cache, exc)
         self.registry.release(ch.mv)
 
     # ------------------------------------------------------------ finishing
@@ -1422,18 +1211,16 @@ class JobManager:
                 time.sleep(0.05)
                 continue
             topk = clamp_topk(job.topk, mv.model_cfg)
-            try:
-                data = Path(item["path"]).read_bytes()
-            except OSError as e:
-                self.registry.release(mv)
-                return (None, False, f"read failed: {e}")
             slot = None
             try:
-                slot, _d, _c = self._stage_one(mv, batcher, data, topk,
-                                               tenant=job.tenant)
+                slot, _d, _c = self._stage_item(mv, batcher, item, topk,
+                                                job.tenant)
                 kind = slot[0]
                 if kind == "err":
                     return (None, False, slot[1])
+                if kind == "retry":
+                    last = ShuttingDownError("batcher shutting down")
+                    continue
                 if kind == "done":
                     return (slot[1], True, None)
                 if kind == "wait":
@@ -1451,7 +1238,7 @@ class JobManager:
             except Exception as e:  # noqa: BLE001 — every attempt bounded
                 last = e
                 if slot is not None:
-                    self._abort_slots([slot], e)
+                    abort_slots([slot], self.cache, e)
             finally:
                 self.registry.release(mv)
         return (None, False,

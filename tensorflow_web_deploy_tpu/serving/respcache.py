@@ -14,9 +14,11 @@ module is that skip:
   compressed bytes, so two byte-identical uploads hit regardless of
   connection, header order, or multipart framing, while an f32 entry can
   never answer for an int8 variant (see :func:`make_key`). The digest is
-  computed by http.py AFTER the native decode-into-slab (the canvas row
-  is zero/neutral-padded by the decoder, so the whole-row digest is
-  deterministic across slab reuse). Pipeline-DAG stages reuse the same
+  computed by serving/staging.py — for requests and jobs alike, the one
+  caller that keys pixels — AFTER the native decode-into-slab (the canvas
+  row is zero/neutral-padded by the decoder, so the whole-row digest is
+  deterministic across slab reuse; a ragged arena's tight bytes hash with
+  their (h, w) and canvas bucket, :func:`packed_digest`). Pipeline-DAG stages reuse the same
   constructor with a *stage-input* digest — downstream of stage 1 the
   content being addressed is the upstream stage's result, not pixels
   (:func:`stage_input_digest`) — so each stage caches independently and

@@ -632,6 +632,7 @@ def test_failed_stage_aborts_led_flight(tmp_path):
     from types import SimpleNamespace
 
     from tensorflow_web_deploy_tpu.serving.batcher import ShuttingDown
+    from tensorflow_web_deploy_tpu.serving.staging import stage_image
 
     cache = ResponseCache(1 << 20)
     cfg = _cfg(str(tmp_path / "jobs"), cache_bytes=1 << 20)
@@ -641,13 +642,15 @@ def test_failed_stage_aborts_led_flight(tmp_path):
         class DownBatcher:
             supports_lease = False
 
-            def submit(self, canvas, hw, bulk=False):
+            def submit(self, canvas, hw, bulk=False, **admit):
                 raise ShuttingDown("draining under hot-swap")
 
         mv = SimpleNamespace(name="m1", version=1, model_cfg=_mc("m1"),
                              engine=MockEngine(), labels=["a", "b"])
         with pytest.raises(ShuttingDown):
-            jm._stage_one(mv, DownBatcher(), b"\x01" * 16, 3)
+            stage_image(b"\x01" * 16, batcher=DownBatcher(), mv=mv,
+                        cache=cache, topk=3, buckets=cfg.canvas_buckets,
+                        span=None, bulk=True)
         st = cache.stats()
         assert st["inflight"] == 0, "led flight must be aborted, not leaked"
         # The key is immediately re-leadable — a fresh attempt is not a

@@ -532,15 +532,15 @@ def test_packed_digest_keyed_on_bucket_and_hw(rng):
 
 
 def test_jobs_stage_one_uses_ragged_lease(ragged_pair):
-    """Bulk chunks ride the packed-slab path: _stage_one on a ragged
-    batcher stages through lease_ragged and the answer matches the solo
-    classic submit for the same JPEG."""
+    """Bulk chunks ride the packed-slab path: stage_image(bulk=True) on a
+    ragged batcher stages through lease_ragged and the answer matches the
+    solo classic submit for the same JPEG."""
     from types import SimpleNamespace
 
     from PIL import Image
 
     from tensorflow_web_deploy_tpu.ops.image import decode_image
-    from tensorflow_web_deploy_tpu.serving.jobs import JobManager
+    from tensorflow_web_deploy_tpu.serving.staging import stage_image
 
     engine, batcher = ragged_pair
     rng = np.random.RandomState(11)
@@ -549,11 +549,10 @@ def test_jobs_stage_one_uses_ragged_lease(ragged_pair):
         buf, "JPEG", quality=90)
     data = buf.getvalue()
 
-    fake = SimpleNamespace(cache=None, cfg=engine.cfg,
-                           registry=SimpleNamespace(chaos=None))
     mv = SimpleNamespace(name="m", version=1, engine=engine)
-    slot, _decode_s, _cache_s = JobManager._stage_one(fake, mv, batcher,
-                                                      data, 3)
+    slot = stage_image(data, batcher=batcher, mv=mv, cache=None, topk=3,
+                       buckets=engine.cfg.canvas_buckets, span=None,
+                       bulk=True)
     assert slot[0] == "own"
     _, future, orig, flight, lease = slot
     assert flight is None and lease is not None
