@@ -20,10 +20,10 @@ stdlib-only front end built for the serving hot path:
 - **One way in for an image** (serving/staging.py ``stage_image``, which
   the bulk job runner calls too). This module loops over a request's
   files and maps what staging raises to a status; which header probe,
-  lease, native decode, PIL fallback and digest go together on the wire
-  the batcher speaks, the order lease → decode into the leased row →
-  cache lookup → commit, and the unwind of a lease and a led flight all
-  live there.
+  lease, native decode and PIL fallback go together on the wire the
+  batcher speaks, the order cache lookup (keyed by the upload's bytes) →
+  lease → decode into the leased row → commit, and the unwind of a lease
+  and a led flight all live there.
 - **Request-scoped span tracing.** Every request gets a monotonically
   derived trace ID at accept time (or propagates a well-formed inbound
   ``X-Trace-Id``) and carries a Span (utils/tracing.py) through the whole
@@ -33,13 +33,15 @@ stdlib-only front end built for the serving hot path:
   execute enqueue (``device_dispatch``), device execute, postprocess,
   serialize — stamped by this module, the batcher, and the engine.
 - **Content-addressed response cache + single-flight dedup** (serving/
-  respcache.py, ``--cache-bytes``). Staging digests the decoded pixels
-  and consults the cache BEFORE committing the slot: a hit gives the slot
-  back (the sealed batch pads it as a hole) and the stored payload is
-  served with ``X-Cache: hit``; a
-  concurrent request for the same content coalesces onto the in-flight
+  respcache.py, ``--cache-bytes``). Staging digests the upload's bytes
+  (with the canvas bucket set and the wire) and consults the cache FIRST,
+  before the slot lease and any decode: a hit serves the stored payload
+  with ``X-Cache: hit`` for a hash of the upload and nothing else; a
+  concurrent request for the same bytes coalesces onto the in-flight
   leader's computation (``X-Cache: coalesced`` — a viral image costs one
-  device dispatch instead of N); a miss leads and fills the cache. Keys
+  decode and one device dispatch instead of N); a miss leads and fills
+  the cache. What it gives up: the same pixels in other bytes (metadata
+  rewritten without re-encoding) are another entry. Keys
   carry the model VERSION, and the registry invalidates a version's
   entries atomically when it starts draining, so a hot-swap can never
   serve a stale result. Single-image responses carry an ``ETag`` (=
@@ -957,6 +959,10 @@ class App:
         p.scalar("cache_invalidations_total", c["invalidations_total"],
                  mtype="counter",
                  help_="Entries dropped by model retire (hot-swap/unload).")
+        p.scalar("cache_digest_bytes_total", c["digest_bytes_total"],
+                 mtype="counter",
+                 help_="Bytes hashed for the keys of cache lookups (an "
+                 "image is keyed by its upload's bytes).")
         p.scalar("cache_bytes", c["bytes"],
                  help_="Bytes held by cached responses (budget: "
                  "--cache-bytes; 0 = cache disabled).")
@@ -1793,8 +1799,9 @@ class App:
         buckets = self.cfg.canvas_buckets
         if level >= 2 and len(buckets) > 1:
             # Rung 2: every image lands in the smallest canvas bucket —
-            # less decode work, denser batches, and a hotter cache (the
-            # key space collapses with the bucket set).
+            # less decode work and denser batches. The bucket set is part
+            # of the cache key, so the rung's answers are entries of their
+            # own, beside those made with every bucket to choose from.
             buckets = buckets[:1]
         # Stage every image before waiting on any (serving/staging.py):
         # slots land in the same batch-assembly window, so

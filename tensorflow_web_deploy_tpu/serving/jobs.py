@@ -62,12 +62,14 @@ steal latency budget from the interactive path.
 - **Staging and cache interplay.** An item becomes a batch slot through
   the same function as an upload (serving/staging.py ``stage_image``,
   here with ``bulk=True``), so it consults the content-addressed
-  response cache (serving/respcache.py) under the same key: bulk re-runs
-  dedup for free — and a job's misses POPULATE the cache, pre-warming the
-  interactive tier for the corpus it just processed. Bulk lookups are
-  accounted separately (``bulk`` counters in the cache stats) so the
-  hit-rate the interactive dashboard shows is not diluted by batch
-  traffic.
+  response cache (serving/respcache.py) under the same key: the file's
+  bytes with the bucket set and the wire, looked up before any lease or
+  decode. Bulk re-runs dedup for free, a repeated file costs a hash and
+  no decode (a file of the same pixels in other bytes is another entry),
+  and a job's misses POPULATE the cache, pre-warming the interactive
+  tier for the corpus it just processed. Bulk lookups are accounted
+  separately (``bulk`` counters in the cache stats) so the hit-rate the
+  interactive dashboard shows is not diluted by batch traffic.
 
 Concurrency: one condition (``jobs.cond``, declared in
 tools/twdlint/lockorder.toml between registry.cond and batcher.cond)

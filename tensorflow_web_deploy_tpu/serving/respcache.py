@@ -9,20 +9,33 @@ batcher) says the cheapest stage is the one you skip entirely. This
 module is that skip:
 
 - **Content-addressed keys.** An entry is keyed by ``(model, version,
-  digest(decoded canvas bytes + valid hw), topk, dtype)`` — the *pixels
-  the device would see* plus the serving tier, not the upload's
-  compressed bytes, so two byte-identical uploads hit regardless of
-  connection, header order, or multipart framing, while an f32 entry can
-  never answer for an int8 variant (see :func:`make_key`). The digest is
-  computed by serving/staging.py — for requests and jobs alike, the one
-  caller that keys pixels — AFTER the native decode-into-slab (the canvas
-  row is zero/neutral-padded by the decoder, so the whole-row digest is
-  deterministic across slab reuse; a ragged arena's tight bytes hash with
-  their (h, w) and canvas bucket, :func:`packed_digest`). Pipeline-DAG stages reuse the same
-  constructor with a *stage-input* digest — downstream of stage 1 the
-  content being addressed is the upstream stage's result, not pixels
-  (:func:`stage_input_digest`) — so each stage caches independently and
-  a hot-swap of one stage invalidates exactly that stage's entries.
+  digest, topk, dtype)`` (:func:`make_key`: an f32 entry can never answer
+  for an int8 variant). For an image the digest is
+  :func:`upload_digest`: blake2b-128 over THE UPLOAD'S BYTES — the part's
+  body, free of connection, header order and multipart framing — with
+  the canvas bucket set the request may choose from and the wire its
+  rows ship on, which with the model version are everything that decides
+  which pixels the device sees for those bytes. serving/staging.py builds
+  it, for requests and jobs alike, and looks up FIRST: before the header
+  probe, the slot lease and any decode, so a hit or a coalesced wait
+  costs a hash of a megabyte or two and touches neither a decoder nor
+  the batcher. The other choice is the decoded pixels: a digest of what
+  the device would see, which can only be taken after the decode into
+  the leased row. For a 9 MP phone photo that hashes 27 MB where the
+  upload is 1.1 (blake2b is bound by its own arithmetic, so the cost
+  follows the bytes: PERF.md section 6, PR 35), and a hit has already paid the
+  decode it was meant to skip. What the bytes give up: two uploads whose
+  bytes differ and whose decoded pixels are identical (an EXIF block
+  stripped or rewritten without re-encoding) are two entries, not one.
+  Every answer stays exact — a hit is what the model gave for these very
+  bytes — and a non-cryptographic hash was not taken for speed, because
+  on a public endpoint a collision is another user's answer.
+  Pipeline-DAG stages (serving/dag.py) reuse
+  :func:`make_key` with digests of their own: stage 1 the canvas it crops
+  from (:func:`canvas_digest`), downstream stages the upstream stage's
+  result (:func:`stage_input_digest`) — so each stage caches
+  independently and a hot-swap of one stage invalidates exactly that
+  stage's entries.
 
 - **Byte-budgeted LRU.** Entries carry the serialized size of their
   formatted payload; over ``max_bytes`` the least-recently-hit entries
@@ -74,9 +87,33 @@ class CacheRetired(RuntimeError):
     NEW serving version, and proceeds as an ordinary miss."""
 
 
+def upload_digest(data, buckets, wire: str | None) -> str:
+    """Content digest of one uploaded image: blake2b-128 over the upload's
+    bytes, led by what else decides the pixels the device sees for them —
+    the canvas bucket set the request may choose from (the degradation
+    ladder's rung 2 narrows it) and the wire (``ragged``, ``rgb``,
+    ``yuv420``; None for an engine without leases). Model, version, topk
+    and dtype are :func:`make_key`'s. The rule: two requests whose
+    device-side pixels could differ never share a digest.
+
+    The context goes first and ends in the length of ``data``, so no
+    upload's tail can pass for another request's context. blake2b and not
+    a faster hash: a collision here is another user's answer.
+    """
+    h = hashlib.blake2b(digest_size=16)
+    h.update(b"%s|%s|%d|" % (
+        (wire or "").encode(),
+        ",".join(str(int(s)) for s in buckets).encode(), len(data)))
+    h.update(data)
+    return h.hexdigest()
+
+
 def canvas_digest(canvas, hw) -> str:
-    """Content digest of one staged image: the decoded canvas bytes (wire
-    format — exactly what the device would see) plus the valid (h, w).
+    """Content digest of one decoded canvas: its bytes (wire format —
+    exactly what the device would see) plus the valid (h, w). The
+    two-stage pipeline (serving/dag.py) keys its first stage on it: the
+    canvas is what it crops from, and it decodes before it looks up.
+    ``/predict`` and ``/jobs`` key on :func:`upload_digest` instead.
 
     The hw rides along because the canvas alone cannot distinguish an
     image whose edge pixels are genuinely black from zero padding. The
@@ -92,27 +129,6 @@ def canvas_digest(canvas, hw) -> str:
     h = hashlib.blake2b(digest_size=16)
     h.update(arr.data)
     h.update(b"%d,%d" % (int(hw[0]), int(hw[1])))
-    return h.hexdigest()
-
-
-def packed_digest(tight, hw, bucket_s: int) -> str:
-    """Content digest of one RAGGED-staged image: the tight decoded bytes
-    (native stride, h·w·3) plus the valid (h, w) and the canvas bucket the
-    batch will unpack onto.
-
-    Same equivalence classes as :func:`canvas_digest` — the device-side
-    unpack is a deterministic function of (tight bytes, hw, bucket), so two
-    images share a packed digest iff their unpacked canvases (and hws)
-    would be identical. The digest SPACE differs from canvas_digest's by
-    construction (different byte layout hashed), which is fine: one server
-    runs one wire mode, so the two spaces never share a cache.
-    """
-    arr = np.asarray(tight)
-    if not arr.flags["C_CONTIGUOUS"]:
-        arr = np.ascontiguousarray(arr)
-    h = hashlib.blake2b(digest_size=16)
-    h.update(arr.data)
-    h.update(b"%d,%d,%d" % (int(hw[0]), int(hw[1]), int(bucket_s)))
     return h.hexdigest()
 
 
@@ -218,6 +234,10 @@ class ResponseCache:
         self._evictions = 0
         self._invalidations = 0
         self._inserts = 0
+        # Bytes hashed to build the keys looked up here: what says which
+        # representation of an image the digest runs over (an upload is a
+        # megabyte or two, its decoded pixels tens).
+        self._digest_bytes = 0
         # Bulk-tier split (serving/jobs.py): job lookups ride the same
         # entry/flight maps — that is the dedup-for-free — but count
         # apart, so the interactive hit rate dashboards read is not
@@ -247,7 +267,8 @@ class ResponseCache:
             }
         return m
 
-    def begin(self, key: tuple, model: str, bulk: bool = False):
+    def begin(self, key: tuple, model: str, bulk: bool = False,
+              digest_bytes: int = 0):
         """One lookup: ``("hit", entry)`` for a cached result, ``("wait",
         flight)`` to coalesce onto an in-flight leader (block on
         ``flight.future`` OUTSIDE any lock), or ``("lead", flight)`` —
@@ -255,8 +276,11 @@ class ResponseCache:
         or :meth:`abort` (a leaked flight would wedge every later waiter
         until their request timeouts). ``bulk=True`` marks a job-tier
         lookup: same maps (bulk and interactive dedup against each
-        other), separate counters."""
+        other), separate counters. ``digest_bytes`` is how many bytes the
+        caller hashed for ``key`` (``digest_bytes_total`` in
+        :meth:`stats`)."""
         with self._lock:
+            self._digest_bytes += digest_bytes
             entry = self._entries.get(key)
             if entry is not None:
                 self._entries.move_to_end(key)
@@ -390,6 +414,7 @@ class ResponseCache:
                 "evictions_total": self._evictions,
                 "invalidations_total": self._invalidations,
                 "inserts_total": self._inserts,
+                "digest_bytes_total": self._digest_bytes,
                 "hit_rate": (
                     round(self._hits / lookups, 4) if lookups else None
                 ),

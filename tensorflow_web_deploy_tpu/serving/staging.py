@@ -3,27 +3,34 @@
 ``POST /predict`` (serving/http.py) and the bulk job runner
 (serving/jobs.py) both stage through :func:`stage_image`; this module is
 the only place that knows the wire — which header probe, lease, native
-decode, PIL fallback and content digest go together (:class:`_Wire`) —
-and the only place that builds a response-cache key for pixels.
+decode and PIL fallback go together (:class:`_Wire`) — and the only place
+that builds a response-cache key for an upload.
 
-The order: a JPEG the native decoder takes is probed, leased, decoded INTO
-the leased memory (the image's single host copy), then digested and looked
-up; a hit or a coalesced wait gives the lease back, so the row ships as a
-hole and costs no device work; a miss commits. A native decode that fails
-after its header parsed gives the lease back and falls through to PIL. A
-PIL image is digested and looked up BEFORE any lease, so a hit never
-touches the batcher. Engines without slot leases (mocks, embedders) decode
-with ``engine.prepare_bytes`` and ``batcher.submit`` their misses.
+The order: the cache is asked FIRST, once, on every path. The key is a
+digest of the upload's bytes with the canvas bucket set and the wire
+(respcache.py ``upload_digest``; what keying by bytes and not by decoded
+pixels gives up is said there), so a hit or a coalesced wait returns
+before the header probe, the lease and any decode, and touches neither a
+decoder nor the batcher. A miss leads the flight and goes on: a JPEG the
+native decoder takes is probed, leased and decoded INTO the leased memory
+(the image's single host copy), then committed. A native decode that
+fails after its header parsed gives the lease back (the row ships as a
+hole) and falls through to PIL under the flight it already leads. A PIL
+image is decoded and fitted, then leased and committed with its pixels.
+Engines without slot leases (mocks, embedders) decode with
+``engine.prepare_bytes`` and ``batcher.submit``.
 
 The unwind: whatever leaves :func:`stage_image` by an exception leaves
 nothing behind for this image — the lease released, then the led flight
 aborted, each guarded so neither can starve the other (a PENDING slot
 holds its whole builder back until the lease timeout; a leaked flight
-wedges every coalesced waiter until theirs). The request's EARLIER slots
-are the caller's, through :func:`abort_slots`.
+wedges every coalesced waiter until theirs). The flight is older than the
+lease, so what the lease's admission raises (BacklogFull, QuotaExceeded,
+DeadlineExceeded, ShuttingDown) aborts it too. The request's EARLIER
+slots are the caller's, through :func:`abort_slots`.
 
 The timing: every stretch of decode work is a ``stage(span,
-"image_decode")`` block, every digest plus lookup a ``stage(span,
+"image_decode")`` block, the digest plus lookup one ``stage(span,
 "cache_lookup")`` block (utils/tracing.py), so both callers' spans, the
 ``twd.*`` annotations and the per-layer metrics read one set of stamps.
 """
@@ -38,7 +45,7 @@ from ..ops.image import (
 )
 from ..utils.tracing import stage
 from .overload import Degraded
-from .respcache import canvas_digest, make_key, packed_digest
+from .respcache import make_key, upload_digest
 
 
 class UndecodableImage(Exception):
@@ -52,7 +59,7 @@ class UndecodableImage(Exception):
 
 
 class _Wire(NamedTuple):
-    """The five pieces that go together on one wire of leased rows."""
+    """The four pieces that go together on one wire of leased rows."""
 
     # (data, buckets) -> (canvas bucket, need, original (h, w)) | None:
     # the JPEG header probe; ``need`` is what ``lease`` reserves.
@@ -63,8 +70,6 @@ class _Wire(NamedTuple):
     decode: Callable
     # (PIL-decoded image, buckets) -> (pixels, (h, w), canvas bucket, need)
     fit: Callable
-    # (pixels, (h, w), canvas bucket) -> content digest
-    digest: Callable
 
 
 def _plan_ragged(data, buckets):
@@ -80,25 +85,19 @@ def _fit_ragged(img, buckets):
     return tight, hw, s, hw[0] * hw[1] * 3
 
 
-# Tight bytes at native stride in a flat arena; the digest hashes them with
-# (h, w) and the canvas bucket — the same equivalence classes as a padded
-# canvas's, because the device-side unpack is a function of those three.
+# Tight bytes at native stride in a flat arena, unpacked onto the canvas
+# bucket on the device.
 _RAGGED = _Wire(
     plan=_plan_ragged,
     lease=lambda batcher, need, s, **kw: batcher.lease_ragged(need, s, **kw),
     decode=native.decode_packed_into,
     fit=_fit_ragged,
-    digest=packed_digest,
 )
 
 
-def _canvas_digest(canvas, hw, s):
-    return canvas_digest(canvas, hw)
-
-
 def _classic(wire: str) -> _Wire:
-    """Padded canvas rows, rgb or I420 planes. The decoder zero/neutral-pads
-    the whole row, so its digest is deterministic across slab reuse."""
+    """Padded canvas rows, rgb or I420 planes; the decoder zero/neutral-pads
+    the whole row."""
 
     def fit(img, buckets):
         canvas, hw = pad_to_canvas(img, buckets)
@@ -114,22 +113,23 @@ def _classic(wire: str) -> _Wire:
             native.decode_into_row(data, row, s, wire)
             if row is not None else None),
         fit=fit,
-        digest=_canvas_digest,
     )
 
 
-_CLASSIC = {wire: _classic(wire) for wire in ("rgb", "yuv420")}
+_WIRES = {"ragged": _RAGGED, "rgb": _classic("rgb"),
+          "yuv420": _classic("yuv420")}
 
 
-def _wire_of(batcher, mv) -> _Wire | None:
-    """The wire this batcher's builders speak; None for an engine without
+def _wire_of(batcher, mv) -> str | None:
+    """The name of the wire this batcher's builders speak (a key of
+    ``_WIRES``, and part of the cache key); None for an engine without
     slot-lease slabs, whose only way in is ``batcher.submit``."""
     if not getattr(batcher, "supports_lease", False):
         return None
     if getattr(batcher, "ragged", False):
-        return _RAGGED
+        return "ragged"
     cfg = getattr(mv.engine, "cfg", None)
-    return _CLASSIC[getattr(cfg, "wire_format", "rgb")]
+    return getattr(cfg, "wire_format", "rgb")
 
 
 def stage_image(data: bytes, *, batcher, mv, cache, topk: int, buckets,
@@ -143,7 +143,9 @@ def stage_image(data: bytes, *, batcher, mv, cache, topk: int, buckets,
     the led single-flight, None with ``cache`` None; ``lease`` None for
     an engine without leases).
 
-    ``buckets`` are the canvas buckets to choose from; ``span`` is the
+    The cache is asked first, keyed by ``data`` itself: a "done" or a
+    "wait" has taken no lease and called no decoder. ``buckets`` are the
+    canvas buckets to choose from, and part of the key; ``span`` is the
     request's Span, which rides the lease into its batch too.
     ``bulk=True`` is the job runner's: bulk builders, bulk cache counters,
     a ``span`` that need only take ``add(stage, seconds)`` and stays off
@@ -157,23 +159,10 @@ def stage_image(data: bytes, *, batcher, mv, cache, topk: int, buckets,
     DeadlineExceeded)."""
     if chaos is not None and chaos.decode_fault():
         raise UndecodableImage(" (chaos: injected decode failure)")
-    wire = _wire_of(batcher, mv)
-    digest = wire.digest if wire is not None else _canvas_digest
+    wire_name = _wire_of(batcher, mv)
+    wire = _WIRES[wire_name] if wire_name is not None else None
     admit = dict(span=None if bulk else span, bulk=bulk, deadline=deadline,
                  tenant=tenant)
-
-    def lookup(pixels, hw, s):
-        """``(None, None)``, and no stage, with the cache disabled."""
-        if cache is None:
-            return None, None
-        with stage(span, "cache_lookup"):
-            key = make_key(mv.name, mv.version, digest(pixels, hw, s), topk,
-                           getattr(mv.model_cfg, "dtype", "bfloat16"))
-            return cache.begin(key, mv.name, bulk=bulk)
-
-    def answered(kind, obj):
-        return (("done", obj.payload, obj.etag) if kind == "hit"
-                else ("wait", obj))
 
     def shed_if_asked():
         if shed_misses:
@@ -182,6 +171,18 @@ def stage_image(data: bytes, *, batcher, mv, cache, topk: int, buckets,
 
     lease = flight = None
     try:
+        if cache is not None:  # disabled: no lookup, and no stage
+            with stage(span, "cache_lookup"):
+                key = make_key(mv.name, mv.version,
+                               upload_digest(data, buckets, wire_name), topk,
+                               getattr(mv.model_cfg, "dtype", "bfloat16"))
+                kind, obj = cache.begin(key, mv.name, bulk=bulk,
+                                        digest_bytes=len(data))
+            if kind == "hit":
+                return "done", obj.payload, obj.etag
+            if kind == "wait":
+                return "wait", obj
+            flight = obj
         if wire is not None:
             with stage(span, "image_decode"):  # header probe
                 plan = wire.plan(data, buckets)
@@ -192,22 +193,14 @@ def stage_image(data: bytes, *, batcher, mv, cache, topk: int, buckets,
                 # would corrupt a NEIGHBOURING image's bytes.
                 with stage(span, "image_decode"):
                     hw = wire.decode(data, lease.row, s)
-                if hw is None:
-                    # The header parsed and the stream did not decode: the
-                    # row ships as a hole, PIL gets a try.
-                    lease.release()
-                    lease = None
-                else:
-                    kind, obj = lookup(lease.row, hw, s)
-                    if kind in ("hit", "wait"):
-                        lease.release()
-                        lease = None
-                        return answered(kind, obj)
-                    flight = obj
+                if hw is not None:
                     shed_if_asked()
                     lease.commit(hw)
                     return "own", lease.future, orig, flight, lease
-        # Decoded outside any lease, so the digest comes before one.
+                # The header parsed and the stream did not decode: the row
+                # ships as a hole, PIL gets a try under the same flight.
+                lease.release()
+                lease = None
         try:
             with stage(span, "image_decode"):
                 if wire is None:
@@ -216,19 +209,14 @@ def stage_image(data: bytes, *, batcher, mv, cache, topk: int, buckets,
                     img = decode_image(data)
         except Exception:
             raise UndecodableImage() from None
-        s = None
-        if wire is not None:
-            orig = (img.shape[0], img.shape[1])
-            with stage(span, "image_decode"):
-                pixels, hw, s, need = wire.fit(img, buckets)
-        kind, obj = lookup(pixels, hw, s)
-        if kind in ("hit", "wait"):
-            return answered(kind, obj)
-        flight = obj
-        shed_if_asked()
         if wire is None:
+            shed_if_asked()
             return ("own", batcher.submit(pixels, hw, **admit), orig, flight,
                     None)
+        orig = (img.shape[0], img.shape[1])
+        with stage(span, "image_decode"):
+            pixels, hw, s, need = wire.fit(img, buckets)
+        shed_if_asked()
         lease = wire.lease(batcher, need, s, **admit)
         lease.commit(hw, canvas=pixels)
         return "own", lease.future, orig, flight, lease

@@ -4,8 +4,9 @@ carried through the whole request path (accept → socket read → slot lease
 postprocess → serialize). Canonical stage names on the serving path:
 ``http_read``, ``body_read``, ``lease_wait`` (blocked acquiring a batch
 slot under backpressure), ``image_decode`` (wire bytes → slab row, GIL
-released), ``cache_lookup`` (content digest of the decoded canvas +
-response-cache consult), ``cache_wait`` (coalesced onto another request's
+released; only a cache miss pays it), ``cache_lookup`` (digest of the
+upload's bytes with bucket set and wire + response-cache consult, before
+the lease and the decode), ``cache_wait`` (coalesced onto another request's
 in-flight computation for the same content key — single-flight dedup),
 ``staging_write`` (slot commit / fallback canvas copy),
 ``queue_wait`` (commit → launch start), ``device_transfer`` (launch start →

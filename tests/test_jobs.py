@@ -409,13 +409,13 @@ def test_cache_dedup_accounting_and_interactive_prewarm(tmp_path):
         # The job populated the cache: an interactive-tier lookup for the
         # same content is a warm hit.
         from tensorflow_web_deploy_tpu.serving.respcache import (
-            canvas_digest, make_key,
+            make_key, upload_digest,
         )
         mv = reg.acquire("m1")
         try:
-            canvas, hw, _ = mv.engine.prepare_bytes(blobs[0])
-            key = make_key(mv.name, mv.version, canvas_digest(canvas, hw),
-                           mv.model_cfg.topk)
+            # The mock engine has no leases, so no wire rides in the key.
+            digest = upload_digest(blobs[0], cfg.canvas_buckets, None)
+            key = make_key(mv.name, mv.version, digest, mv.model_cfg.topk)
             kind, _ = cache.begin(key, mv.name)
             assert kind == "hit", "job results must pre-warm the interactive tier"
         finally:
@@ -656,11 +656,11 @@ def test_failed_stage_aborts_led_flight(tmp_path):
         # The key is immediately re-leadable — a fresh attempt is not a
         # coalesced waiter on a dead computation.
         from tensorflow_web_deploy_tpu.serving.respcache import (
-            canvas_digest, make_key,
+            make_key, upload_digest,
         )
-        canvas, hw, _orig = mv.engine.prepare_bytes(b"\x01" * 16)
-        kind, _obj = cache.begin(
-            make_key("m1", 1, canvas_digest(canvas, hw), 3), "m1", bulk=True)
+        digest = upload_digest(b"\x01" * 16, cfg.canvas_buckets, None)
+        kind, _obj = cache.begin(make_key("m1", 1, digest, 3), "m1",
+                                 bulk=True)
         assert kind == "lead"
     finally:
         jm.stop(grace_s=3)

@@ -18,7 +18,6 @@ import pytest
 from tensorflow_web_deploy_tpu.ops.image import fit_to_bucket, unpack_ragged
 from tensorflow_web_deploy_tpu.serving.batcher import Batcher
 from tensorflow_web_deploy_tpu.serving.engine import InferenceEngine
-from tensorflow_web_deploy_tpu.serving.respcache import packed_digest
 from tensorflow_web_deploy_tpu.utils.config import ModelConfig, ServerConfig
 
 # Tiny configs per zoo architecture: enough layers to be the real model,
@@ -517,15 +516,6 @@ def test_ragged_disables_packed_io():
         assert engine.ragged and not engine.cfg.packed_io
     finally:
         engine.close()
-
-
-def test_packed_digest_keyed_on_bucket_and_hw(rng):
-    im = (rng.rand(10, 12, 3) * 255).astype(np.uint8)
-    tight = im.reshape(-1)
-    base = packed_digest(tight, (10, 12), 96)
-    assert base == packed_digest(tight.copy(), (10, 12), 96)
-    assert base != packed_digest(tight, (12, 10), 96)
-    assert base != packed_digest(tight, (10, 12), 128)
 
 
 # ------------------------------------------------------------- jobs staging

@@ -23,7 +23,7 @@ from tensorflow_web_deploy_tpu.serving.http import (
 from tensorflow_web_deploy_tpu.serving.registry import ModelRegistry
 from tensorflow_web_deploy_tpu.serving.respcache import (
     CacheRetired, ResponseCache, canvas_digest, make_key, payload_etag,
-    stage_input_digest,
+    stage_input_digest, upload_digest,
 )
 from tensorflow_web_deploy_tpu.utils.config import ModelConfig, ServerConfig
 
@@ -113,6 +113,63 @@ def test_canvas_digest_deterministic_and_content_sensitive(rng):
     assert canvas_digest(view, (8, 8)) == canvas_digest(
         np.ascontiguousarray(view), (8, 8)
     )
+
+
+UPLOAD = b"\xff\xd8 the bytes of one upload \xff\xd9"
+# (data, buckets, wire) that must not share a digest with
+# (UPLOAD, (256, 512), "ragged"): another upload, or the same one where the
+# device would see other pixels for it.
+OTHER_UPLOADS = {
+    "one_byte_changed": (UPLOAD[:5] + b"T" + UPLOAD[6:], (256, 512), "ragged"),
+    "one_byte_more": (UPLOAD + b"\0", (256, 512), "ragged"),
+    "empty": (b"", (256, 512), "ragged"),
+    "narrowed_bucket_set": (UPLOAD, (256,), "ragged"),
+    "another_bucket_set": (UPLOAD, (256, 1024), "ragged"),
+    "buckets_that_spell_alike": (UPLOAD, (25, 6512), "ragged"),
+    "wire_rgb": (UPLOAD, (256, 512), "rgb"),
+    "wire_yuv420": (UPLOAD, (256, 512), "yuv420"),
+    "no_leases": (UPLOAD, (256, 512), None),
+    # An upload whose tail spells another request's context, and one whose
+    # head does: the context is framed, so neither passes for it.
+    "tail_spells_a_context": (UPLOAD + b"|256|", (512,), "ragged"),
+    "head_spells_a_context": (b"ragged|256,512|" + UPLOAD, (), None),
+}
+
+
+@pytest.mark.parametrize("other", list(OTHER_UPLOADS))
+def test_upload_digest_tells_apart(other):
+    base = upload_digest(UPLOAD, (256, 512), "ragged")
+    assert len(base) == 32  # blake2b-128, as hex
+    assert upload_digest(*OTHER_UPLOADS[other]) != base
+
+
+@pytest.mark.parametrize("same", [
+    (bytearray(UPLOAD), (256, 512), "ragged"),
+    (memoryview(UPLOAD), [256, 512], "ragged"),
+    (UPLOAD, (np.int64(256), np.int64(512)), "ragged"),
+], ids=["bytearray", "memoryview_and_list", "numpy_ints"])
+def test_upload_digest_is_of_the_content_not_of_its_container(same):
+    assert upload_digest(*same) == upload_digest(UPLOAD, (256, 512), "ragged")
+
+
+@pytest.mark.parametrize("bulk", [False, True], ids=["interactive", "bulk"])
+@pytest.mark.parametrize("outcome", ["lead", "wait", "hit"])
+def test_digest_bytes_total_counts_what_every_lookup_hashed(outcome, bulk):
+    cache = ResponseCache(1 << 20)
+    key = make_key("m", 1, upload_digest(UPLOAD, (256,), "rgb"), 5)
+    assert cache.stats()["digest_bytes_total"] == 0
+    want = 0
+    if outcome != "lead":
+        _, flight = cache.begin(key, "m", digest_bytes=len(UPLOAD))
+        want = len(UPLOAD)
+        if outcome == "hit":
+            cache.complete(flight, _payload())
+    kind, _ = cache.begin(key, "m", bulk=bulk, digest_bytes=len(UPLOAD))
+    assert kind == outcome
+    assert cache.stats()["digest_bytes_total"] == want + len(UPLOAD)
+    # A key the caller did not hash for (a pipeline stage's) counts none.
+    cache.begin(make_key("m", 1, "stage-input", 5), "m", bulk=bulk)
+    assert cache.stats()["digest_bytes_total"] == want + len(UPLOAD)
 
 
 def test_payload_etag_stable_and_version_sensitive():

@@ -4,8 +4,11 @@ Every wire (ragged arenas, classic rgb rows, classic I420 rows, a batcher
 without slot leases) takes every source (a JPEG the native decoder takes,
 a PNG through PIL, a JPEG whose header parses and whose body the native
 decoder refuses) past every cache outcome through ``stage_image``, and
-every way out by an exception leaves nothing behind. Real batchers over
-real slabs; the engines run no model.
+every way out by an exception leaves nothing behind. The cache is asked
+first and keyed by the upload's bytes: a hit or a wait takes no lease and
+calls no decoder, and whatever else decides the device's pixels (bucket
+set, wire, topk, dtype, version) leads a flight of its own. Real batchers
+over real slabs; the engines run no model.
 """
 
 import io
@@ -17,8 +20,9 @@ import pytest
 from PIL import Image
 
 from tensorflow_web_deploy_tpu import native
+from tensorflow_web_deploy_tpu.ops.image import decode_image
 from tensorflow_web_deploy_tpu.serving.batcher import (
-    BacklogFull, Batcher, ShuttingDown,
+    BacklogFull, Batcher, DeadlineExceeded, QuotaExceeded, ShuttingDown,
 )
 from tensorflow_web_deploy_tpu.serving.engine import RaggedSlab, StagingSlab
 from tensorflow_web_deploy_tpu.serving.overload import Degraded
@@ -80,8 +84,10 @@ class PlainEngine:
 
     def __init__(self):
         self.rows = 0
+        self.prepared = 0
 
     def prepare_bytes(self, data):
+        self.prepared += 1
         return native.decode_to_canvas(data, BUCKETS, "rgb")
 
     def dispatch_batch(self, canvases, hws):
@@ -122,6 +128,28 @@ def _source(kind, seed=7):
     return buf.getvalue()
 
 
+def _rewrapped(data, comment=b"re-saved by a gallery app"):
+    """The same JPEG with a comment segment behind its SOI: other bytes,
+    the same decoded pixels."""
+    assert data[:2] == b"\xff\xd8"
+    seg = b"\xff\xfe" + (len(comment) + 2).to_bytes(2, "big") + comment
+    return data[:2] + seg + data[2:]
+
+
+class CountingSpan(Span):
+    """A Span that also counts its stamps by stage."""
+
+    __slots__ = ("stamps",)
+
+    def __init__(self):
+        super().__init__()
+        self.stamps = {}
+
+    def add(self, stage, dur_s):
+        self.stamps[stage] = self.stamps.get(stage, 0) + 1
+        super().add(stage, dur_s)
+
+
 class Rig:
     """One started batcher of a wire, with its lease entry points counted."""
 
@@ -146,12 +174,20 @@ class Rig:
 
         return call
 
-    def stage(self, data, cache, **kw):
-        span = Span()
-        slot = stage_image(data, batcher=self.batcher, mv=self.mv,
-                           cache=cache, topk=3, buckets=BUCKETS, span=span,
+    def stage(self, data, cache, *, topk=3, buckets=BUCKETS, mv=None, **kw):
+        self.span = span = CountingSpan()
+        slot = stage_image(data, batcher=self.batcher, mv=mv or self.mv,
+                           cache=cache, topk=topk, buckets=buckets, span=span,
                            **kw)
         return slot, span.stages_copy()
+
+    def decodes(self):
+        """Images a decoder has served: ``prepare_bytes`` by the engine's
+        count, the native decoder and PIL by the process's own."""
+        if hasattr(self.engine, "prepared"):
+            return self.engine.prepared
+        st = native.stats()
+        return st["native_decodes_total"] + st["pil_decodes_total"]
 
     def holes(self):
         return self.batcher.builder_stats()["holes_total"]
@@ -195,8 +231,8 @@ def test_every_wire_source_and_cache_outcome(rig, wire, source, cache_case):
     data = _source(source)
     cache = None if cache_case == "disabled" else ResponseCache(1 << 20)
     leases = wire != "no_leases"
-    # Which sources lease BEFORE the lookup (a hit then leaves a hole), and
-    # which are given back because the native decoder refused the body.
+    # Which sources decode into a lease, and which of those leases are
+    # given back because the native decoder refused the body.
     native_first = leases and native.available() and source != "png"
     refused = native_first and source == "cmyk_jpeg"
 
@@ -212,7 +248,7 @@ def test_every_wire_source_and_cache_outcome(rig, wire, source, cache_case):
         rows = 1
         if cache_case == "hit":
             etag = cache.complete(led[3], {"answer": 42})
-    holes0, calls0 = r.holes(), len(r.calls)
+    holes0, calls0, decodes0 = r.holes(), len(r.calls), r.decodes()
 
     slot, stages = r.stage(data, cache)
 
@@ -233,30 +269,138 @@ def test_every_wire_source_and_cache_outcome(rig, wire, source, cache_case):
         assert future.result(timeout=5)[0] == sum(HW)
         rows += 1
         if flight is not None:
+            # One lookup and one flight, also where the native decoder
+            # refused the body and PIL took over.
+            assert r.span.stamps["cache_lookup"] == 1
+            assert cache.stats()["misses_total"] == 1
+            assert cache.stats()["inflight"] == 1
             cache.abort(flight, RuntimeError("test over"))
 
-    # The entry points taken, in order: a native decode leases before the
-    # lookup; PIL leases (or submits) only a miss.
     calls = r.calls[calls0:]
-    if not leases:  # submit leases for itself: count the way in only
-        assert [c for c in calls if c[0] == "submit"] == (
-            [] if answered else [("submit", None)])
+    if answered:
+        # The lookup came first: no lease, no submit, no header probe, no
+        # decoder, and so no hole either.
+        assert calls == []
+        assert r.decodes() == decodes0
+        assert "image_decode" not in stages
+    elif not leases:  # submit leases for itself: count the way in only
+        assert [c for c in calls if c[0] == "submit"] == [("submit", None)]
     else:
-        want = []
-        if native_first:
-            want.append(NEED[wire])
-        if (refused or not native_first) and not answered:
-            want.append(NEED[wire])
-        assert calls == want
-    # A lease given back is a hole: the refused native decode's, and the
-    # row a hit or a wait had decoded into.
-    assert r.holes() - holes0 == int(refused) + int(
-        answered and native_first and not refused)
-    assert stages["image_decode"] > 0
+        # A refused native decode gives its lease back, and PIL's pixels
+        # take another.
+        assert calls == [NEED[wire]] * (2 if refused else 1)
+    if not answered:
+        assert r.decodes() == decodes0 + 1
+        assert stages["image_decode"] > 0
+    # The one lease given back, so the one hole: the refused decode's.
+    assert r.holes() - holes0 == int(refused and not answered)
     assert ("cache_lookup" in stages) == (cache is not None)
     _settle(r, rows)
     if cache is not None:
-        assert cache.stats()["inflight"] == 0
+        st = cache.stats()
+        assert st["inflight"] == 0
+        assert st["digest_bytes_total"] == len(data) * (1 + answered)
+
+
+# What else decides the pixels the device sees for the same bytes, or the
+# answer made of them: each is part of the key.
+OTHERWISE = {
+    "bucket_set": dict(buckets=(CANVAS, 2 * CANVAS)),
+    "topk": dict(topk=5),
+    "dtype": dict(mv=dict(model_cfg=SimpleNamespace(dtype="int8"))),
+    "version": dict(mv=dict(version=2)),
+    "model": dict(mv=dict(name="another")),
+}
+
+
+@pytest.mark.parametrize("what", [*OTHERWISE, "wire"])
+@pytest.mark.parametrize("wire", list(WIRES))
+def test_the_same_bytes_otherwise_lead_a_flight_of_their_own(rig, wire, what):
+    r = rig(wire)
+    cache = ResponseCache(1 << 20)
+    data = _source("jpeg")
+    first, _ = r.stage(data, cache)
+    assert first[0] == "own"
+    if what == "wire":
+        other = rig({"ragged": "rgb", "rgb": "yuv420", "yuv420": "no_leases",
+                     "no_leases": "ragged"}[wire])
+        second, _ = other.stage(data, cache)
+    else:
+        kw = dict(OTHERWISE[what])
+        if "mv" in kw:
+            kw["mv"] = SimpleNamespace(**{**vars(r.mv), **kw["mv"]})
+        second, _ = r.stage(data, cache, **kw)
+    assert second[0] == "own" and second[3] is not first[3]
+    assert second[3].key != first[3].key
+    st = cache.stats()
+    assert st["inflight"] == 2 and st["misses_total"] == 2
+    assert st["coalesced_total"] == 0
+    # Nothing else changed: the same request again coalesces.
+    again, _ = r.stage(data, cache)
+    assert again == ("wait", first[3])
+    abort_slots([first, second], cache, RuntimeError("test over"))
+
+
+@pytest.mark.parametrize("other", ["one_byte", "trailing_byte",
+                                   "metadata_added"])
+@pytest.mark.parametrize("wire", list(WIRES))
+def test_the_bytes_are_the_key_not_the_pixels(rig, wire, other):
+    """The stated design: uploads whose bytes differ are two entries, also
+    where their decoded pixels are the same."""
+    r = rig(wire)
+    cache = ResponseCache(1 << 20)
+    data = _rewrapped(_source("jpeg"), b"taken on a phone")
+    twin = {"one_byte": _rewrapped(_source("jpeg"), b"taken on a phonE"),
+            "trailing_byte": data + b"\0",
+            "metadata_added": _rewrapped(data)}[other]
+    assert twin != data
+    np.testing.assert_array_equal(decode_image(twin), decode_image(data))
+    first, _ = r.stage(data, cache)
+    second, _ = r.stage(twin, cache)
+    assert first[0] == second[0] == "own"
+    assert first[1].result(timeout=5)[0] == second[1].result(timeout=5)[0]
+    cache.complete(first[3], {"answer": 1})
+    cache.complete(second[3], {"answer": 2})
+    st = cache.stats()
+    assert st["entries"] == 2 and st["misses_total"] == 2
+    assert st["digest_bytes_total"] == len(data) + len(twin)
+    assert r.stage(data, cache)[0][:2] == ("done", {"answer": 1})
+    assert r.stage(twin, cache)[0][:2] == ("done", {"answer": 2})
+
+
+@pytest.mark.parametrize("exc", [BacklogFull, QuotaExceeded,
+                                 DeadlineExceeded, ShuttingDown])
+@pytest.mark.parametrize("source", ["jpeg", "png"])
+@pytest.mark.parametrize("wire", ["ragged", "rgb", "yuv420"])
+def test_a_refused_lease_aborts_the_flight_that_is_older_than_it(
+        rig, monkeypatch, wire, source, exc):
+    r = rig(wire)
+    cache = ResponseCache(1 << 20)
+    data = _source(source)
+    waiters = []
+
+    def refuse(*a, **kw):
+        # While the leader asks for its lease, the same upload arrives on
+        # another request and coalesces onto the flight already led.
+        monkeypatch.undo()
+        waiters.append(r.stage(data, cache)[0])
+        raise exc("no room for this one")
+
+    monkeypatch.setattr(r.batcher, NEED[wire][0], refuse)
+    with pytest.raises(exc):
+        r.stage(data, cache)
+
+    (waiter,) = waiters
+    assert waiter[0] == "wait"
+    assert isinstance(waiter[1].future.exception(timeout=1), exc)
+    assert r.pending() == 0
+    assert cache.stats()["inflight"] == 0, "a led flight was leaked"
+    # The key leads again at once, and is no wait on a dead flight.
+    slot, _ = r.stage(data, cache)
+    assert slot[0] == "own" and slot[3] is not waiter[1]
+    assert slot[1].result(timeout=5)[0] == sum(HW)
+    cache.abort(slot[3], RuntimeError("test over"))
+    _settle(r, 1)
 
 
 @pytest.mark.parametrize("source", ["jpeg", "png"])
