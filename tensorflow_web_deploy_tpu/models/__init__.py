@@ -48,9 +48,10 @@ _ZOO: dict[str, ModelSpec] = {
         ModelSpec("mobilenet_v2", MobileNetV2, 224, "inception"),
         ModelSpec("resnet50", ResNet50, 224, "caffe"),
         ModelSpec("ssd_mobilenet", SSDMobileNet, 300, "inception", task="detect", num_classes=90),
-        # No flax module and no resize: longcat_flash.py is functional, its sizes come from the
-        # model's JSON (``decoder``), and adapter.decoder_converted wraps it.
+        # No flax module and no resize: a decoder's module (its zoo name; decoder.py has the contract) is
+        # functional, its sizes come from the model's JSON (``decoder``), and adapter.decoder_converted wraps it.
         ModelSpec("longcat_flash", None, 0, "patches", task="generate", num_classes=131072),
+        ModelSpec("nemotron_h", None, 0, "patches", task="generate", num_classes=131072),
     ]
 }
 

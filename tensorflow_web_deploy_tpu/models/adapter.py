@@ -126,11 +126,11 @@ def native_converted(
 
     A ``task="generate"`` entry of the registry is no flax module: its
     sizes are ``decoder`` (the model's JSON), it answers ``topk`` ids a step
-    itself, and :func:`decoder_converted` wraps it.
+    itself, and :func:`decoder_converted` wraps the family's module.
     """
     spec = get(name)
     if spec.task == "generate":
-        return decoder_converted(decoder or {}, topk, seed=seed, ckpt_path=ckpt_path)
+        return decoder_converted(decoder or {}, topk, seed=seed, ckpt_path=ckpt_path, name=name)
     input_size = input_size or spec.input_size
     if input_format not in ("nhwc", "s2d"):
         raise ValueError(f"input_format must be 'nhwc' or 's2d', got {input_format!r}")
@@ -200,19 +200,19 @@ _EXPORT_DTYPES = {"float32": np.float32, "bfloat16": jnp.bfloat16}
 def read_leaf_export(export_dir: str, table, stacks: dict[str, tuple[int, ...]]) -> dict[str, np.ndarray]:
     """A decoder's ``--ckpt`` export: ``manifest.json`` (``dtype`` and each
     leaf's shape) beside one raw file a leaf (its name with ``/`` as ``.``),
-    as ``benchmark/reference/longcat_weights.py`` writes one. Read leaf by
-    leaf into the model's parameters (``table``:
-    models/longcat_flash.py::leaf_table; an expert's matrix lands in its
-    layer's stack), so the host never holds more than the parameters
-    themselves; a leaf that is missing, or of another shape than
-    the model states, is an error here."""
+    as the weights scripts of ``benchmark/reference/`` write one. Read leaf by
+    leaf into the model's parameters (``table``: the family's
+    ``leaf_table``; an expert's matrix lands in its layer's stack, and what
+    of a stack no leaf fills stays zero), so the
+    host never holds more than the parameters themselves; a leaf that is
+    missing, or of another shape than the model states, is an error here."""
     import json
     from pathlib import Path
 
     root = Path(export_dir)
     manifest = json.loads((root / "manifest.json").read_text())
     dtype = np.dtype(_EXPORT_DTYPES[manifest["dtype"]])
-    out = {name: np.empty(shape, dtype) for name, shape in stacks.items()}
+    out = {name: np.zeros(shape, dtype) for name, shape in stacks.items()}
     for leaf, shape, stack, index in table:
         if tuple(manifest["leaves"].get(leaf, ())) != tuple(shape):
             raise ValueError(f"{export_dir}: leaf {leaf!r} is {manifest['leaves'].get(leaf)}, the model states {shape}")
@@ -220,28 +220,32 @@ def read_leaf_export(export_dir: str, table, stacks: dict[str, tuple[int, ...]])
     return out
 
 
-def decoder_converted(decoder: dict, topk: int, seed: int = 0, ckpt_path: str | None = None) -> ConvertedModel:
-    """The token decoder (models/longcat_flash.py) behind the engine's one
+def decoder_converted(decoder: dict, topk: int, seed: int = 0, ckpt_path: str | None = None,
+                      name: str | None = None) -> ConvertedModel:
+    """The token decoder of the zoo entry ``name`` (its family's module,
+    found by that name: models/decoder.py has what is asked of one; without
+    a name, the family whose sizes ``decoder`` states) behind the engine's one
     interface, as a model that serves ``from_canvases``:
     ``fn(params, canvases, hws)`` takes the patches of the canvases' real
     pixels (no resize) and returns (scores [B, steps, k], ids [B, steps,
     k], counters). Its ceiling a call is ``max_token_slots``, so the rows
     it allows go with the canvas."""
     from ..ops.image import patch_tokens
-    from . import longcat_flash as lf
+    from .decoder import family
 
-    cfg = lf.Config.from_dict(decoder)
+    fam = family(name, decoder)
+    cfg = fam.Config.from_dict(decoder)
     if ckpt_path:
-        params = read_leaf_export(ckpt_path, lf.leaf_table(cfg), lf.param_shapes(cfg))
+        params = read_leaf_export(ckpt_path, fam.leaf_table(cfg), fam.param_shapes(cfg))
     else:
-        params = lf.init_params(cfg, seed)
+        params = fam.init_params(cfg, seed)
 
     def fn(params_arg, canvases, hws):
         # One program a call: patches, prefill of every layer, step 1's
         # top-k, then the cached steps. The model's own scopes name the rest.
         with jax.named_scope("patches"):
             tokens, lengths = patch_tokens(canvases, hws, cfg.patch)
-        return lf.answer(cfg, params_arg, tokens, lengths, topk)
+        return fam.answer(cfg, params_arg, tokens, lengths, topk)
 
     return ConvertedModel(
         fn=fn, params=params,
@@ -249,5 +253,5 @@ def decoder_converted(decoder: dict, topk: int, seed: int = 0, ckpt_path: str | 
         output_names=["scores", "ids", "counters"],
         from_canvases=True,
         max_rows=lambda canvas_s: cfg.max_token_slots // cfg.token_slots(canvas_s),
-        counter_names=lf.COUNTERS,
+        counter_names=fam.COUNTERS,
     )

@@ -1,5 +1,5 @@
-"""LongCat-Flash's language model behind the image path: the first model of
-this zoo that is no convolutional network.
+"""LongCat-Flash's language model behind the image path: the first
+``task: "generate"`` family of this zoo (models/decoder.py has the contract).
 
 One published layer is a *double layer* on ``x [T, D]``::
 
@@ -36,6 +36,8 @@ import numpy as np
 
 from ..ops import experts as experts_op
 from ..ops import mla
+from . import decoder as shared
+from .decoder import layer_params as _layer, mm as _mm, out as _out, rmsnorm
 
 # What `answer` counts, a call: /stats -> batcher.lifecycle.<name>_total sums them over batches.
 COUNTERS = ("images", "tokens_real", "token_slots", "token_slots_pad", "picks", "zero_picks", "held_picks",
@@ -69,8 +71,7 @@ class Config:
 
     @classmethod
     def from_dict(cls, d: dict) -> "Config":
-        names = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in names})
+        return shared.config_from(cls, d)
 
     @property
     def mla_scale_q(self) -> float:      # mla_scale_q_lora: sqrt(hidden / q_lora_rank)
@@ -151,22 +152,6 @@ def init_params(c: Config, seed: int = 0) -> dict[str, np.ndarray]:
 
 
 # ------------------------------------------------------------------ the blocks
-
-def rmsnorm(x, gain, eps: float, dtype=None):
-    """In float32 whatever comes in; out in ``dtype`` (``x``'s unless given)."""
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * gain.astype(jnp.float32)).astype(dtype or x.dtype)
-
-
-def _out(x, w):
-    """A block's last product, in float32: it is added to the residual stream, which stays float32."""
-    return jnp.dot(x, w, preferred_element_type=jnp.float32)
-
-
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-
 
 FFN_CHUNK = 4096   # tokens of one pass of a dense FFN: bounds its hidden activations (100 MB a chunk)
 
@@ -250,17 +235,8 @@ def _double_layer(c: Config, p, x, valid, attend):
     return y, (left0, left1), counters
 
 
-def _layer(params: dict, l: int) -> dict:
-    pre = f"layer{l}/"
-    return {k[len(pre):]: v for k, v in params.items() if k.startswith(pre)}
-
-
 def _top(c: Config, params, hidden, topk: int):
-    with jax.named_scope("head"):
-        hn = rmsnorm(hidden, params["final_norm"], c.rms_norm_eps, params["head"].dtype)
-        logits = jnp.dot(hn, params["head"], preferred_element_type=jnp.float32)
-        scores, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), min(topk, logits.shape[-1]))
-        return scores, ids.astype(jnp.int32)
+    return shared.top(hidden, params["final_norm"], params["head"], c.rms_norm_eps, topk)
 
 
 def answer(c: Config, params: dict, tokens, lengths, topk: int):

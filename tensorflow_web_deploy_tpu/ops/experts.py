@@ -1,11 +1,18 @@
 """A routed expert layer that is told which experts it holds.
 
 The router scores every expert of the published layer, routed and zero
-ones alike (:func:`route`); the layer computes what *its own* experts add
+ones alike; the layer computes what *its own* experts add
 for the tokens routed to them, and what the zero (identity) experts add,
 which every holder of a share computes alike. What the absent experts
 would add is left out: that is the other chips' part, and no code stands in
 for them.
+
+Two routers (``expert_layer(router=...)``): :func:`route`, a softmax whose
+``topk`` largest are kept as they are, and :func:`route_sigmoid`, sigmoid
+scores chosen with a selection bias that the weights do not carry,
+renormalised over the picks. Two expert shapes, told by the matrices the
+layer is given: three (:func:`swiglu`) or two (:func:`relu2`, a squared
+ReLU between them). Grouping, windows and the kernel serve both.
 
 Two forms of the held experts' sum:
 
@@ -13,8 +20,8 @@ Two forms of the held experts' sum:
   weight (zero where the token did not pick it). ``E_held`` times the
   necessary work: the definition, for the CPU and the tests.
 - ``grouped``: the (token, expert) pairs sorted by expert, each expert's
-  group padded to whole row tiles, and three grouped matrix products
-  (``expert_gmm``, a Mosaic kernel: one expert's weights a row tile, chosen
+  group padded to whole row tiles, and a grouped matrix product a matrix of
+  the expert (``expert_gmm``, a Mosaic kernel: one expert's weights a row tile, chosen
   by a prefetched table) over a window of ``CHUNK`` rows at a time, as many
   windows as the pairs need. No pair is dropped and there is no capacity
   factor: a batch that routes more pairs here takes more windows.
@@ -48,6 +55,19 @@ def route(u, w_router, topk: int, scale: float):
     return scale * top_p, ids.astype(jnp.int32)
 
 
+def route_sigmoid(u, w_router, bias, topk: int, scale: float):
+    """Sigmoid scores in float32 over all experts; the ``topk`` largest of
+    ``score + bias`` are chosen (``bias`` [E] or None: it moves the choice
+    and not the weight), each weighted ``scale * score / sum of the picked
+    scores``. Returns (weights [T, k] float32, ids [T, k] int32)."""
+    logits = jnp.dot(u.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(s if bias is None else s + bias.astype(jnp.float32), topk)
+    picked = jnp.take_along_axis(s, ids, axis=-1)
+    return scale * picked / jnp.sum(picked, axis=-1, keepdims=True), ids.astype(jnp.int32)
+
+
 def swiglu(x, w_gate, w_up, w_down):
     """SwiGLU with float32 accumulation; the two hidden products are kept
     in ``x``'s dtype (at 16,384 tokens x 12,288 a float32 pair is 1.6 GB)."""
@@ -58,6 +78,23 @@ def swiglu(x, w_gate, w_up, w_down):
     return jnp.dot(hidden, w_down, preferred_element_type=f32)
 
 
+def _relu2_hidden(a):
+    return jnp.square(jax.nn.relu(a.astype(jnp.float32)))
+
+
+def relu2(x, w_up, w_down):
+    """``w_down (relu(w_up x))**2`` with float32 accumulation, the hidden
+    product kept in ``x``'s dtype as :func:`swiglu`'s are."""
+    f32 = jnp.float32
+    a = jnp.dot(x, w_up, preferred_element_type=f32).astype(x.dtype)
+    return jnp.dot(_relu2_hidden(a).astype(x.dtype), w_down, preferred_element_type=f32)
+
+
+def expert_fn(n_matrices: int):
+    """An expert of three matrices is a SwiGLU, one of two a squared ReLU."""
+    return {3: swiglu, 2: relu2}[n_matrices]
+
+
 def held_weights(weights, ids, held_first: int, n_held: int):
     """[T, n_held]: each token's routing weight on each held expert (ids
     ``held_first`` .. ``held_first + n_held - 1``), zero where not picked."""
@@ -66,13 +103,16 @@ def held_weights(weights, ids, held_first: int, n_held: int):
     return jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
 
 
-def _dense_sum(u, hw, w_gate, w_up, w_down):
+def _dense_sum(u, hw, *w):
+    """``w``: the held experts' stacks, [E_held, ...] each (gate, up, down or up, down)."""
+    expert = expert_fn(len(w))
+
     def one(acc, ws):
-        e_w, g, a, d = ws
-        return acc + e_w[:, None] * swiglu(u, g, a, d), None
+        e_w, *mats = ws
+        return acc + e_w[:, None] * expert(u, *mats), None
 
     acc0 = jnp.zeros(u.shape, jnp.float32)
-    acc, _ = jax.lax.scan(one, acc0, (hw.T, w_gate, w_up, w_down))
+    acc, _ = jax.lax.scan(one, acc0, (hw.T, *w))
     return acc
 
 
@@ -90,6 +130,15 @@ def _gmm_kernel(tile_expert_ref, n_tiles_ref, x_ref, w_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+def _col_tile(n: int, want: int) -> int:
+    """The column tile for ``n`` columns: ``want`` where it divides them,
+    else the largest multiple of 128 below it that does (2,688 and 1,920
+    columns go in tiles of 384 where 512 were asked), else all of them in one."""
+    if n <= want or n % want == 0:
+        return min(n, want)
+    return next((c for c in range(want - want % 128, 0, -128) if n % c == 0), n)
+
+
 def expert_gmm(x, w, tile_expert, n_tiles, *, col_tile: int = 256, interpret: bool = False):
     """Rows ``x`` [R, K] in tiles of ``ROW_TILE``, tile ``i`` times expert
     ``tile_expert[i]``'s matrix of ``w`` [E, K, N]; tiles from ``n_tiles`` on
@@ -97,7 +146,7 @@ def expert_gmm(x, w, tile_expert, n_tiles, *, col_tile: int = 256, interpret: bo
     grid axis, so consecutive tiles of one expert reuse its block of weights."""
     r, k = x.shape
     n = w.shape[-1]
-    col_tile = min(col_tile, n)
+    col_tile = _col_tile(n, col_tile)
     assert r % ROW_TILE == 0 and n % col_tile == 0, (r, n, col_tile)
 
     def live(i, n_tiles):   # a dead tile names the last live one's blocks: nothing new is fetched for it
@@ -152,11 +201,14 @@ def group_rows(weights, ids, held_first: int, n_held: int):
     return jnp.where(live, tokens[pick], t), jnp.where(live, wts[pick], 0.0), tile_expert, ends[-1]
 
 
-def _grouped_sum(u, weights, ids, held_first, w_gate, w_up, w_down, interpret: bool = False):
+def _grouped_sum(u, weights, ids, held_first, *w, interpret: bool = False):
+    """``w``: the held experts' stacks, as :func:`_dense_sum` takes them."""
     t, d = u.shape
-    row_token, row_weight, tile_expert, rows_used = group_rows(weights, ids, held_first, w_gate.shape[0])
+    *w_in, w_down = w
+    n_held = w_down.shape[0]
+    row_token, row_weight, tile_expert, rows_used = group_rows(weights, ids, held_first, n_held)
     u_pad = jnp.concatenate([u, jnp.zeros((1, d), u.dtype)])               # row T: what padding gathers
-    chunk = window_rows(t, ids.shape[1], w_gate.shape[0])
+    chunk = window_rows(t, ids.shape[1], n_held)
     tiles = chunk // ROW_TILE
 
     def window(state):
@@ -168,8 +220,11 @@ def _grouped_sum(u, weights, ids, held_first, w_gate, w_up, w_down, interpret: b
         n_tiles = jnp.clip((rows_used - r0 + ROW_TILE - 1) // ROW_TILE, 0, tiles).astype(jnp.int32)[None]
         x = u_pad[rows]
         gmm = functools.partial(expert_gmm, tile_expert=te, n_tiles=n_tiles, interpret=interpret)
-        g, a = gmm(x, w_gate), gmm(x, w_up)
-        hidden = (jax.nn.silu(g.astype(jnp.float32)) * a.astype(jnp.float32)).astype(u.dtype)
+        if len(w_in) == 2:
+            g, a = (gmm(x, m) for m in w_in)
+            hidden = (jax.nn.silu(g.astype(jnp.float32)) * a.astype(jnp.float32)).astype(u.dtype)
+        else:
+            hidden = _relu2_hidden(gmm(x, w_in[0], col_tile=512)).astype(u.dtype)
         y = gmm(hidden, w_down, col_tile=512).astype(jnp.float32) * wts[:, None]
         return c + 1, acc.at[rows].add(y, mode="drop")
 
@@ -179,26 +234,34 @@ def _grouped_sum(u, weights, ids, held_first, w_gate, w_up, w_down, interpret: b
     return acc
 
 
-def expert_layer(u, valid, w_router, w_gate, w_up, w_down, *, topk: int, scale: float, n_routed: int,
-                 held_first: int = 0):
+def expert_layer(u, valid, w_router, *w, topk: int, scale: float, n_routed: int, held_first: int = 0,
+                 router: str = "softmax", router_bias=None):
     """``m`` [T, D] float32 for the tokens ``u`` [T, D] (``valid`` [T] bool:
     padding is routed nowhere and adds nothing), and the layer's counters.
 
-    ``w_gate``/``w_up`` [E_held, D, F], ``w_down`` [E_held, F, D]: the held
-    experts, ids ``held_first`` onward; ids from ``n_routed`` on are zero
-    experts, ``E(u) = u``."""
-    n_held = w_gate.shape[0]
+    ``w``: the held experts, ids ``held_first`` onward, as stacks:
+    ``w_gate``/``w_up`` [E_held, D, F] and ``w_down`` [E_held, F, D]
+    (SwiGLU), or ``w_up`` and ``w_down`` alone (squared ReLU). ``router``:
+    ``"softmax"`` (:func:`route`) or ``"sigmoid"`` (:func:`route_sigmoid`,
+    with ``router_bias``). Ids from ``n_routed`` on are zero experts,
+    ``E(u) = u``; a router no wider than ``n_routed`` has none."""
+    n_held = w[-1].shape[0]
     with jax.named_scope("router"):
-        weights, ids = route(u, w_router, topk, scale)
+        if router == "sigmoid":
+            weights, ids = route_sigmoid(u, w_router, router_bias, topk, scale)
+        else:
+            weights, ids = route(u, w_router, topk, scale)
         weights = jnp.where(valid[:, None], weights, 0.0)
-        zero_w = jnp.sum(jnp.where(ids >= n_routed, weights, 0.0), axis=1)
+        has_zero = w_router.shape[-1] > n_routed
+        zero_w = jnp.sum(jnp.where(ids >= n_routed, weights, 0.0), axis=1) if has_zero else None
         hw = held_weights(weights, ids, held_first, n_held)
     with jax.named_scope("experts"):
         if jax.default_backend() == "tpu":
-            held = _grouped_sum(u, weights, ids, held_first, w_gate, w_up, w_down)
+            m = _grouped_sum(u, weights, ids, held_first, *w)
         else:
-            held = _dense_sum(u, hw, w_gate, w_up, w_down)
-        m = held + zero_w[:, None] * u.astype(jnp.float32)
+            m = _dense_sum(u, hw, *w)
+        if has_zero:
+            m = m + zero_w[:, None] * u.astype(jnp.float32)
     real = valid[:, None]
     load = (hw > 0).sum(axis=0).astype(jnp.float32)                         # tokens a held expert
     counters = {

@@ -385,9 +385,9 @@ def model_cost(model_cfg) -> dict | None:
 
 # ------------------------------------------------------- token decoders
 
-def decoder_cost(decoder: dict) -> dict:
-    """Walkers for the token decoder (models/longcat_flash.py), from the
-    sizes its config states (``ModelConfig.decoder``): multiply-adds per
+def _longcat_flash_cost(decoder: dict) -> dict:
+    """Walkers for the ``longcat_flash`` family, from the sizes its config
+    states: multiply-adds per
     token of each kind of block, per token squared of the attention core,
     and the parameters a call reads. Held equal to the benchmark's floors
     module (benchmark/reference/longcat_floors.py) by a test, as the conv
@@ -430,17 +430,106 @@ def decoder_cost(decoder: dict) -> dict:
     }
 
 
-def decoder_image_flops(decoder: dict, tokens: float) -> float:
-    """Floor operations of one image of ``tokens`` patch tokens through the
-    decoder: prefill (matrices per token, the core per token squared), the
-    further answer steps against the cache, the head at every step."""
-    c, layers = decoder_cost(decoder), decoder["num_layers"]
+def _longcat_flash_image_flops(decoder: dict, tokens: float) -> float:
+    """Prefill (matrices per token, the core per token squared), the further
+    answer steps against the cache, the head at every step."""
+    c, layers = _longcat_flash_cost(decoder), decoder["num_layers"]
     more = decoder["answer_steps"] - 1
     prefill = (tokens * (decoder["patch"] ** 2 * 3 * decoder["hidden_size"] + layers * c["layer_macs_per_token"])
                + layers * 2 * c["core_macs_per_token_sq"] * tokens * tokens)
     steps = more * layers * (c["layer_macs_per_token"] + 2 * c["absorbed_macs_per_cached_token"] * tokens)
     head = decoder["answer_steps"] * decoder["hidden_size"] * decoder["vocab_size"]
     return 2.0 * (prefill + steps + head)
+
+
+def _nemotron_h_cost(decoder: dict) -> dict:
+    """Walkers for the ``nemotron_h`` family (a layer is one mixer, its kind a
+    character of ``hybrid_override_pattern``), held equal to
+    benchmark/reference/nemotron_h_floors.py by a test.
+
+    - ``mamba_params``: a Mamba-2 mixer's two projections, a multiply-add
+      each a token; ``scan_macs_per_token``: its chunked scan (per head two
+      products against the state and half a chunk's masked product, per
+      group half a chunk's ``C B'``); ``step_macs_per_token``: the
+      recurrence of one token (state update and read-out);
+    - ``attn_params``: query, key, value and output matrices;
+      ``core_macs_per_token_sq``: the causal core per token squared (half
+      the pairs, a score and a value each); ``decode_macs_per_cached_token``:
+      one new token against one cached key and value, all query heads;
+    - ``router_params``, ``shared_params`` (the shared expert's two
+      matrices), ``expert_params`` (one routed expert's two) and
+      ``held_picks_per_token``: how many of a token's picks a uniform router
+      sends to the experts held here.
+    """
+    g = decoder.__getitem__
+    d, pattern = g("hidden_size"), g("hybrid_override_pattern")
+    h, p, n, groups, q = g("mamba_num_heads"), g("mamba_head_dim"), g("ssm_state_size"), g("n_groups"), g("chunk_size")
+    d_inner, conv = h * p, h * p + 2 * groups * n
+    hq, hk, dh = g("num_attention_heads"), g("num_key_value_heads"), g("head_dim")
+    mamba = d * (d_inner + conv + h) + d_inner * d
+    attn = d * (hq + 2 * hk) * dh + hq * dh * d
+    router = d * g("n_routed_experts")
+    shared = 2 * d * g("moe_shared_expert_intermediate_size")
+    expert = 2 * d * g("moe_intermediate_size")
+    held = g("num_experts_per_tok") * g("experts_held") / g("n_routed_experts")
+    n_m, n_a, n_e = (pattern.count(k) for k in "M*E")
+    small = {"M": g("conv_kernel") * conv + conv + 3 * h + d_inner + d, "*": d, "E": g("n_routed_experts") + d}
+    outer = g("patch") ** 2 * 3 * d + 2 * d * g("vocab_size") + d
+    return {
+        "mamba_params": mamba, "attn_params": attn, "router_params": router, "shared_params": shared,
+        "expert_params": expert, "held_picks_per_token": held,
+        "scan_macs_per_token": h * (2 * p * n + q * p // 2) + groups * (q * n // 2),
+        "step_macs_per_token": 2 * h * p * n,
+        "core_macs_per_token_sq": hq * dh,
+        "decode_macs_per_cached_token": hq * 2 * dh,
+        "layers": {"M": n_m, "*": n_a, "E": n_e},
+        "matrix_macs_per_token": n_m * mamba + n_a * attn + n_e * (router + shared + held * expert),
+        "dense_params": (g("patch") ** 2 * 3 * d + d * g("vocab_size")
+                         + n_m * mamba + n_a * attn + n_e * (router + shared)),
+        "param_count": (outer + n_m * (mamba + small["M"]) + n_a * (attn + small["*"])
+                        + n_e * (router + shared + g("experts_held") * expert + small["E"])),
+    }
+
+
+def _nemotron_h_image_flops(decoder: dict, tokens: float) -> float:
+    """Prefill (matrices and the scan per token, the core per token squared),
+    the further answer steps through both kinds of state, the head at every step."""
+    c = _nemotron_h_cost(decoder)
+    n = c["layers"]
+    more = decoder["answer_steps"] - 1
+    prefill = (tokens * (decoder["patch"] ** 2 * 3 * decoder["hidden_size"] + c["matrix_macs_per_token"]
+                         + n["M"] * c["scan_macs_per_token"])
+               + n["*"] * c["core_macs_per_token_sq"] * tokens * tokens)
+    steps = more * (c["matrix_macs_per_token"] + n["M"] * c["step_macs_per_token"]
+                    + n["*"] * c["decode_macs_per_cached_token"] * tokens)
+    head = decoder["answer_steps"] * decoder["hidden_size"] * decoder["vocab_size"]
+    return 2.0 * (prefill + steps + head)
+
+
+# A family's walkers, by its zoo name (models/decoder.py has what else a family keeps).
+_DECODER_WALKERS = {"longcat_flash": (_longcat_flash_cost, _longcat_flash_image_flops),
+                    "nemotron_h": (_nemotron_h_cost, _nemotron_h_image_flops)}
+
+
+def _walkers(decoder: dict, name: str | None):
+    if name is None:    # a caller that holds only the sizes: the family whose Config states them
+        from ..models.decoder import family
+        name = family(None, decoder).__name__.rsplit(".", 1)[-1]
+    return _DECODER_WALKERS[name]
+
+
+def decoder_cost(decoder: dict, name: str | None = None) -> dict:
+    """A token decoder's walkers, from the sizes its config states
+    (``ModelConfig.decoder``) and its family's zoo name: multiply-adds per
+    token of each kind of block, per token squared of the attention core,
+    and the parameters a call reads. Each family's are held equal to its
+    floors module under ``benchmark/reference/`` by a test."""
+    return _walkers(decoder, name)[0](decoder)
+
+
+def decoder_image_flops(decoder: dict, tokens: float, name: str | None = None) -> float:
+    """Floor operations of one image of ``tokens`` patch tokens through the decoder."""
+    return _walkers(decoder, name)[1](decoder, tokens)
 
 
 def preprocess_flops(canvas_s: int, input_hw, wire: str = "rgb") -> int:

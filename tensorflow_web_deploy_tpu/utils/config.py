@@ -50,8 +50,9 @@ class ModelConfig:
     # batch_stats) — serve fine-tuned weights instead of the seeded init
     ckpt_path: str | None = None
     task: str = "classify"  # "classify" | "detect" | "generate"
-    # task="generate" (native name "longcat_flash"): the decoder's sizes,
-    # the keys of models/longcat_flash.py::Config (widths, layers, experts
+    # task="generate" (the native name is the family's: a module of
+    # models/, models/decoder.py has the contract): the decoder's sizes,
+    # the keys of that module's Config (widths, layers, experts
     # held, vocabulary slice, patch, answer_steps, max_token_slots). The
     # image is not resized: its patches are the tokens, so input_size and
     # preprocess are unused.
@@ -108,7 +109,7 @@ class ModelConfig:
         if self.task == "generate" and not isinstance(self.decoder, dict):
             raise ValueError(
                 f"model '{self.name}': task='generate' needs 'decoder', the "
-                "model's sizes (models/longcat_flash.py::Config)"
+                "model's sizes (the Config of the family's module in models/)"
             )
         if self.fused_dw not in ("auto", "on", "off"):
             raise ValueError(

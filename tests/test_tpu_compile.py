@@ -23,7 +23,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from tensorflow_web_deploy_tpu.ops import experts, mla
+from tensorflow_web_deploy_tpu.ops import experts, gqa, mla, ssd
 from tensorflow_web_deploy_tpu.ops.depthwise import fused_depthwise_bn
 from tensorflow_web_deploy_tpu.ops.image import unpack_ragged
 from tensorflow_web_deploy_tpu.ops.pallas_preprocess import preprocess_i420
@@ -135,5 +135,36 @@ def test_expert_gmm_compiles_for_v5e(v5e, k, n, col_tile):
     s = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
     compiled = jax.jit(lambda x, w, te, nt: experts.expert_gmm(x, w, te, nt, col_tile=col_tile)).lower(
         s(experts.CHUNK, k), s(16, k, n), s(experts.CHUNK // experts.ROW_TILE, dt=jnp.int32),
+        s(1, dt=jnp.int32)).compile()
+    assert compiled.as_text().count(KERNEL) == 1 and "expert_gmm" in compiled.as_text()
+
+
+# The second decoder's kernels at the published widths (64 Mamba heads of 64 with state 128 in 8 groups, chunk
+# 128: two heads side by side in a block of 128 lanes; 32 query over 2 key/value heads of 128; 64 held experts
+# 2688 x 1856 in stacks of whole 128-blocks, 1,920) and at the benchmark's three length buckets.
+@pytest.mark.parametrize("rows,slots", [(16, 1024), (4, 2304), (4, 4096)])
+def test_ssd_prefill_compiles_for_v5e(v5e, rows, slots):
+    s = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    scan = lambda xbc, dt, a, n: ssd.pallas_scan(xbc, dt, a, n, heads=64, head_dim=64, groups=8, chunk=128)
+    compiled = jax.jit(scan).lower(s(rows, slots, 6144), s(rows, slots, 64, dt=jnp.float32), s(64, dt=jnp.float32),
+                                   s(rows, dt=jnp.int32)).compile()
+    assert compiled.as_text().count(KERNEL) == 1 and "ssd_prefill" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,slots", [(16, 1024), (4, 2304), (4, 4096)])
+def test_gqa_prefill_compiles_for_v5e(v5e, rows, slots):
+    s = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    compiled = jax.jit(lambda q, k, v, n: gqa.pallas_core(q, k, v, n, 128 ** -0.5)).lower(
+        s(rows, 2, 16, slots, 128), s(rows, 2, slots, 128), s(rows, 2, slots, 128), s(rows, dt=jnp.int32)).compile()
+    assert compiled.as_text().count(KERNEL) == 1 and "gqa_prefill" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1920), (1920, 2688)])
+def test_expert_gmm_compiles_for_v5e_at_the_second_decoders_widths(v5e, k, n):
+    """One window of the grouped form over 64 held experts; 512 columns are asked, 384 divide both widths."""
+    s = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    assert experts._col_tile(n, 512) == 384
+    compiled = jax.jit(lambda x, w, te, nt: experts.expert_gmm(x, w, te, nt, col_tile=512)).lower(
+        s(experts.CHUNK, k), s(64, k, n), s(experts.CHUNK // experts.ROW_TILE, dt=jnp.int32),
         s(1, dt=jnp.int32)).compile()
     assert compiled.as_text().count(KERNEL) == 1 and "expert_gmm" in compiled.as_text()
