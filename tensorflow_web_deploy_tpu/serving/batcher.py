@@ -46,8 +46,18 @@ a CPU pipeline:
 
 ``pipeline_depth`` bounds dispatched-but-unfetched batches PER canvas
 bucket (sealed batches of one row shape can't starve another's), and the
-sealer blocks on the condition variable at the cap — batches keep
-growing exactly when the device is the bottleneck. Every batch's
+sealer blocks on the condition variable at the cap — a bucket's one open
+builder keeps growing exactly when the device is the bottleneck. An
+engine may also state a ceiling on calls in flight over ALL buckets
+(``max_calls_in_flight``: what its device's memory holds beside the
+weights). Under it a builder past its window seals only into a call slot
+that is free and not already promised to a sealed batch, oldest builder
+first; the others keep accepting until they are full or a slot is free
+for them (``window_holds_total`` counts the sealer passes that held one).
+Sealing them all when one slot frees would leave the rest sealed, small
+and waiting, while their canvases' new arrivals open fresh builders. A
+builder past its window never waits behind a batch opened after it: it
+takes the next slot, and sealed batches dispatch oldest first. Every batch's
 lifecycle is stamped into a small ring (``batch_timeline``): builder
 open, seal, launch start/end, fetch done — the record bench.py's
 ``pipeline`` block and the overlap tests read to PROVE decode of batch
@@ -143,7 +153,7 @@ log = logging.getLogger("tpu_serve.batcher")
 
 # Why a builder sealed (rec["reason"], lifecycle.by_reason): every slot
 # leased; the ragged arena out of bytes for the next image; the batch window
-# over with a pipeline slot free; flush_bulk(); shutdown's drain.
+# over with a dispatch slot free for it; flush_bulk(); shutdown's drain.
 SEAL_REASONS = ("full", "arena", "window", "flush", "drain")
 
 # Slot-lease states. PENDING: lessee still decoding. READY: committed, row
@@ -458,6 +468,10 @@ class Batcher:
         self._life = {
             "batches_total": 0,
             "by_reason": dict.fromkeys(SEAL_REASONS, 0),
+            # sealer passes in which a builder past its window, nothing
+            # decoding, stayed open: the engine's ceiling had no call slot
+            # free for it (_pick_action_locked)
+            "window_holds_total": 0,
             "open_s_total": 0.0, "launch_wait_s_total": 0.0,
             "enqueue_s_total": 0.0, "inflight_s_total": 0.0,
             "fetch_wait_s_total": 0.0,
@@ -1025,17 +1039,25 @@ class Batcher:
         self._bulk_gated_since = None
         return True
 
-    def _calls_full_locked(self) -> bool:
-        """The engine's ceiling on calls in flight over all buckets
+    def _call_slots_locked(self) -> int | None:
+        """Calls the engine's ceiling on calls in flight over all buckets
         (``InferenceEngine.max_calls_in_flight``: what the device's memory
-        holds beside the weights, by the compiled programs' temporaries;
-        None on an engine that knows none) is reached."""
+        holds beside the weights, by the compiled programs' temporaries)
+        leaves free; None on an engine that knows no ceiling."""
         cap = getattr(self.engine, "max_calls_in_flight", None)
-        return bool(cap) and self._inflight_total >= cap
+        return cap - self._inflight_total if cap else None
+
+    def _calls_full_locked(self) -> bool:
+        slots = self._call_slots_locked()
+        return slots is not None and slots <= 0
+
+    def _promised_before_locked(self, b: _Builder) -> int:
+        """Call slots promised ahead of ``b``: interactive batches sealed
+        and waiting that opened before it (they dispatch oldest first)."""
+        return sum(1 for c in self._closing
+                   if not c.bulk and c.opened_at <= b.opened_at)
 
     def _depth_free_locked(self, mkey) -> bool:
-        if self._calls_full_locked():
-            return False
         # Headroom check only — no engine.route_lock hop, no least-loaded
         # scan. It runs per open builder on every sealer wakeup; the real
         # replica pick happens once, at the dispatch decision.
@@ -1052,36 +1074,55 @@ class Batcher:
         grace = min(self.lease_timeout_s, 2.0) if draining else self.lease_timeout_s
         for b in list(self._open.values()):
             self._expire_locked(b, now, grace)
-        for b in list(self._open.values()):
+        # Free call slots under the engine's ceiling (None: no ceiling).
+        # With one, builders are visited oldest first, so that the oldest
+        # past their windows take the slots.
+        slots = self._call_slots_locked()
+        by_age = slots is not None
+        opened = list(self._open.values())
+        if by_age:
+            opened.sort(key=lambda x: x.opened_at)
+        held = False
+        for b in opened:
             # Past-deadline builders close only when every in-flight decode
-            # resolved AND their bucket's pipeline has a free slot: closing
-            # earlier would fragment concurrent arrivals into fresh builders
-            # while this one sits undispatchable — and sealing while the
-            # pipeline is full would freeze the batch's size exactly when
-            # the device being the bottleneck makes waiting free (batches
-            # must keep growing up to capacity then). A bulk builder closes
-            # against its own gate instead: while interactive load holds
-            # the device, the bulk batch keeps accepting and GROWS toward
-            # bulk_max_batch — the gate's pressure buys batch efficiency.
-            # The pending-decode wait is bounded — leases expire above.
+            # resolved AND a dispatch slot is free for them: their bucket's
+            # pipeline depth, and under the engine's ceiling a call slot
+            # that no sealed batch opened before them is promised. Closing
+            # earlier would freeze the batch's size while it sits
+            # undispatchable, and fragment its canvas's new arrivals into
+            # fresh builders — so while the device is the bottleneck a
+            # builder keeps accepting and GROWS toward capacity. A bulk
+            # builder closes against its own gate instead: while
+            # interactive load holds the device, the bulk batch keeps
+            # accepting and GROWS toward bulk_max_batch. The pending-decode
+            # wait is bounded — leases expire above.
             if draining:
                 self._close_builder_locked(b, "drain")
             elif len(b.leases) >= b.capacity:
                 self._close_builder_locked(b, "full")
-            elif (
-                now >= b.deadline and not b.n_pending
-                and (self._bulk_gate_open_locked(now, consume=False,
-                                                 tenant=b.tenant,
-                                                 rows=b.n_ready)
-                     if b.bulk
-                     else self._depth_free_locked((b.key, False)))
-            ):
-                self._close_builder_locked(b, "window")
+            elif now < b.deadline or b.n_pending:
+                continue
+            elif b.bulk:
+                if self._bulk_gate_open_locked(now, consume=False,
+                                               tenant=b.tenant,
+                                               rows=b.n_ready):
+                    self._close_builder_locked(b, "window")
+            elif self._depth_free_locked((b.key, False)):
+                if slots is None or self._promised_before_locked(b) < slots:
+                    self._close_builder_locked(b, "window")
+                else:
+                    held = True
+        if held:
+            self._life["window_holds_total"] += 1
         for b in self._closing:
             self._expire_locked(b, now, grace)
         # Interactive builders first, always: the bulk class is strictly
         # lower priority and must never jump a sealed interactive batch.
-        for b in sorted(self._closing, key=lambda x: x.bulk):
+        # Under a ceiling, oldest first among each: a builder sealed into
+        # a slot ahead of younger sealed batches takes that slot.
+        order = ((lambda x: (x.bulk, x.opened_at)) if by_age
+                 else (lambda x: x.bulk))
+        for b in sorted(self._closing, key=order):
             if b.n_pending:
                 continue  # a lessee is still decoding; bounded by expiry
             if not b.bulk:
@@ -1153,10 +1194,11 @@ class Batcher:
     def _next_wake_locked(self, now: float) -> float | None:
         wake = None
         for b in self._open.values():
-            # A past-deadline builder still open has pending decodes (else
-            # _pick_action_locked closed it); its next event is a commit
-            # (notifies the condition) or a lease expiry (covered below) —
-            # re-waking on the stale deadline would just spin.
+            # A past-deadline builder still open has pending decodes or no
+            # dispatch slot (else _pick_action_locked closed it); its next
+            # event is a commit or a batch done (both notify the condition)
+            # or a lease expiry (covered below) — re-waking on the stale
+            # deadline would just spin.
             if b.deadline > now:
                 wake = b.deadline if wake is None else min(wake, b.deadline)
         # MUST mirror _pick_action_locked's expiry horizon: during drain

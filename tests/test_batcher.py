@@ -685,3 +685,84 @@ def test_a_builder_holds_no_more_rows_than_the_engine_allows_at_its_canvas():
         f.result(timeout=5)
     b.stop()
     assert sorted(eng.batches) == [2, 2, 2, 8]
+
+
+def _sealed_waiting(b: Batcher) -> int:
+    with b._cond:
+        return sum(not c.bulk for c in b._closing)
+
+
+def test_under_a_ceiling_of_one_call_builders_past_their_window_keep_accepting():
+    """One call in flight at a time, three canvas buckets, a slow call.
+    Pages come into all three while a call runs: when it is done the oldest
+    builder takes the slot and the other two keep accepting, so no more than
+    one batch is ever sealed and waiting and the calls that follow are
+    fuller. Sealing all three when the slot frees (the rule before) ran
+    seven calls of 1, 2, 2, 2, 2, 2, 2 rows here."""
+    eng = FakeEngine(delay_s=0.3)
+    eng.max_calls_in_flight = 1
+    b = Batcher(eng, max_batch=8, max_delay_ms=5, adaptive_delay=False, pipeline_depth=4)
+    b.start()
+    seen, stop = [], threading.Event()
+
+    def watch():
+        while not stop.is_set():
+            seen.append(_sealed_waiting(b))
+            time.sleep(0.002)
+
+    t = threading.Thread(target=watch)
+    t.start()
+    try:
+        futures = [b.submit(_canvas(0), (1, 1))]                          # call 1, alone
+        time.sleep(0.05)
+        for i in range(6):                                                # 2 a canvas, during call 1
+            futures.append(b.submit(_canvas(i, size=(8, 12, 16)[i % 3]), (1, 1)))
+        time.sleep(0.4)                                                   # call 2 runs: the canvas-8 pair
+        for i in range(6):                                                # 2 a canvas more
+            futures.append(b.submit(_canvas(i, size=(8, 12, 16)[i % 3]), (1, 1)))
+        with b._cond:
+            growing = {c.key[0]: (len(c.leases), c.accepting) for c in b._open.values()}
+        for f in futures:
+            f.result(timeout=10)
+    finally:
+        stop.set()
+        t.join(timeout=5)
+        b.stop()
+    assert growing == {8: (2, True), 12: (4, True), 16: (4, True)}
+    assert max(seen) <= 1
+    recs = b.batch_timeline()
+    assert [(r["key"][0], r["rows"]) for r in recs] == [(8, 1), (8, 2), (12, 4), (16, 4), (8, 2)]
+    assert eng.batches == [1, 2, 4, 4, 2]
+    assert {r["reason"] for r in recs} == {"window"}
+    assert b.lifecycle_stats()["window_holds_total"] > 0
+
+
+@pytest.mark.parametrize("ceiling", [1, None])
+def test_a_builder_past_its_window_goes_before_full_batches_opened_after_it(ceiling):
+    """A canvas-8 builder passes its window while one call runs; then three
+    full batches of canvas 16 (two rows each) seal. Under a ceiling of one
+    call the builder is the oldest, so it takes the next slot ahead of them
+    (the rule before sent it after all three), and the ceiling held it
+    open meanwhile. With no ceiling nothing is held: every batch goes
+    when it seals, in the same order with the same rows and reasons."""
+    eng = FakeEngine(delay_s=0.25)
+    eng.max_calls_in_flight = ceiling
+    eng.max_rows = lambda canvas_s: 8 if canvas_s <= 8 else 2
+    b = Batcher(eng, max_batch=8, max_delay_ms=5, adaptive_delay=False, pipeline_depth=4)
+    b.start()
+    try:
+        futures = [b.submit(_canvas(0), (1, 1))]
+        time.sleep(0.03)
+        futures.append(b.submit(_canvas(1), (1, 1)))
+        time.sleep(0.03)
+        futures += [b.submit(_canvas(i, size=16), (1, 1)) for i in range(6)]
+        for f in futures:
+            f.result(timeout=10)
+        life = b.lifecycle_stats()
+    finally:
+        b.stop()
+    recs = b.batch_timeline()
+    assert [(r["key"][0], r["rows"], r["reason"]) for r in recs] == [
+        (8, 1, "window"), (8, 1, "window"), (16, 2, "full"), (16, 2, "full"), (16, 2, "full")]
+    assert life["by_reason"] == {"full": 3, "arena": 0, "window": 2, "flush": 0, "drain": 0}
+    assert (life["window_holds_total"] > 0) == (ceiling is not None)
