@@ -52,6 +52,7 @@ _ZOO: dict[str, ModelSpec] = {
         # functional, its sizes come from the model's JSON (``decoder``), and adapter.decoder_converted wraps it.
         ModelSpec("longcat_flash", None, 0, "patches", task="generate", num_classes=131072),
         ModelSpec("nemotron_h", None, 0, "patches", task="generate", num_classes=131072),
+        ModelSpec("brumby", None, 0, "patches", task="generate", num_classes=151936),
     ]
 }
 

@@ -27,6 +27,10 @@ import importlib
 import jax
 import jax.numpy as jnp
 
+from ..ops import experts as experts_op
+
+FFN_CHUNK = 4096   # tokens of one pass of a dense FFN: bounds its hidden activations (100 MB a chunk at width 12,288)
+
 
 def families() -> list[str]:
     """The zoo's ``task: "generate"`` entries, by name."""
@@ -72,6 +76,16 @@ def out(x, w):
 
 def mm(x, w):
     return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def dense_ffn(x, w_gate, w_up, w_down):
+    """A dense SwiGLU on ``x`` of any leading shape, float32 out for the
+    residual stream, in passes of ``FFN_CHUNK`` tokens where they divide."""
+    one = lambda z: experts_op.swiglu(z, w_gate, w_up, w_down)
+    flat = x.reshape(-1, x.shape[-1])
+    if flat.shape[0] <= FFN_CHUNK or flat.shape[0] % FFN_CHUNK:
+        return one(x)
+    return jax.lax.map(one, flat.reshape(-1, FFN_CHUNK, x.shape[-1])).reshape(x.shape)
 
 
 def top(hidden, final_norm, head, eps: float, topk: int):

@@ -153,16 +153,8 @@ def init_params(c: Config, seed: int = 0) -> dict[str, np.ndarray]:
 
 # ------------------------------------------------------------------ the blocks
 
-FFN_CHUNK = 4096   # tokens of one pass of a dense FFN: bounds its hidden activations (100 MB a chunk)
-
-
 def _ffn(x, p, a: int):
-    w = (p[f"ffn{a}/w_gate"], p[f"ffn{a}/w_up"], p[f"ffn{a}/w_down"])
-    one = lambda z: experts_op.swiglu(z, *w)          # float32 out, for the residual stream
-    flat = x.reshape(-1, x.shape[-1])
-    if flat.shape[0] <= FFN_CHUNK or flat.shape[0] % FFN_CHUNK:
-        return one(x)
-    return jax.lax.map(one, flat.reshape(-1, FFN_CHUNK, x.shape[-1])).reshape(x.shape)
+    return shared.dense_ffn(x, p[f"ffn{a}/w_gate"], p[f"ffn{a}/w_up"], p[f"ffn{a}/w_down"])
 
 
 def _mla_latents(c: Config, p, a: int, xn, positions):

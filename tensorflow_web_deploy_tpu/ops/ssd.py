@@ -65,10 +65,11 @@ def causal_conv(x, w, bias, lengths):
 
 def decays(dt, a, chunk: int):
     """``dt`` [B, T, H] float32 (zero at padding), ``a`` [H] -> (dt, cs) as
-    [B, H, T / chunk, chunk]: cs the running sum of ``dt * a`` inside each chunk."""
+    [B, H, T / chunk, chunk]: cs the running sum of ``dt * a`` inside each chunk.
+    ``a`` None: ``dt`` are the log-decays themselves (ops/retention.py's gates)."""
     b, t, h = dt.shape
     dt_c = dt.transpose(0, 2, 1).reshape(b, h, t // chunk, chunk)
-    return dt_c, jnp.cumsum(dt_c * a[None, :, None, None], axis=-1)
+    return dt_c, jnp.cumsum(dt_c if a is None else dt_c * a[None, :, None, None], axis=-1)
 
 
 def _widths(xbc, heads: int, head_dim: int, groups: int):

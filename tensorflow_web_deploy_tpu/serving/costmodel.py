@@ -506,9 +506,57 @@ def _nemotron_h_image_flops(decoder: dict, tokens: float) -> float:
     return 2.0 * (prefill + steps + head)
 
 
+def _brumby_cost(decoder: dict) -> dict:
+    """Walkers for the ``brumby`` family (every layer gated power retention
+    of degree 2 and a SwiGLU), held equal to
+    benchmark/reference/brumby_floors.py by a test; ``features`` is the
+    minimal symmetric map, ``d (d + 1) / 2`` products a head.
+
+    - ``layer_params``: a layer's query, key, value, gate, output and FFN
+      matrices, a multiply-add each a token;
+    - ``chunked_macs_per_token``: the retention core in its chunked form
+      (per query head the read-out against the state and half a chunk's
+      masked products, per key/value head the state's update);
+      ``attention_macs_per_token_sq`` and ``state_macs_per_token``: its
+      attention form (half the row's scores and weighted values a query
+      head, per token squared; the final state once, a token); a prefill
+      costs the lesser;
+    - ``step_macs_per_token``: one token through a layer's state (every
+      query head's read-out, every key/value head's update).
+    """
+    g = decoder.__getitem__
+    d, hq, hk, dh, layers = g("hidden_size"), g("num_attention_heads"), g("num_key_value_heads"), g("head_dim"), \
+        g("num_hidden_layers")
+    big = dh * (dh + 1) // 2
+    layer = d * (hq + 2 * hk) * dh + hq * dh * d + d * hk + 3 * d * g("intermediate_size")
+    return {
+        "features": big, "layer_params": layer,
+        "chunked_macs_per_token": hq * (big * dh + g("chunk_size") / 2 * 2 * dh) + hk * big * dh,
+        "attention_macs_per_token_sq": hq * dh,
+        "state_macs_per_token": hk * big * dh,
+        "step_macs_per_token": big * dh * (hq + hk),
+        "dense_params": g("patch") ** 2 * 3 * d + d * g("vocab_size") + layers * layer,
+        "param_count": (g("patch") ** 2 * 3 * d + 2 * d * g("vocab_size") + d
+                        + layers * (layer + 2 * d + 2 * dh + hk)),
+    }
+
+
+def _brumby_image_flops(decoder: dict, tokens: float) -> float:
+    """Prefill (matrices per token, the retention core in the cheaper of its
+    two forms), the further answer steps through the states, the head at every step."""
+    c, layers = _brumby_cost(decoder), decoder["num_hidden_layers"]
+    more = decoder["answer_steps"] - 1
+    core = min(c["chunked_macs_per_token"], c["attention_macs_per_token_sq"] * tokens + c["state_macs_per_token"])
+    prefill = tokens * (decoder["patch"] ** 2 * 3 * decoder["hidden_size"] + layers * (c["layer_params"] + core))
+    steps = more * layers * (c["layer_params"] + c["step_macs_per_token"])
+    head = decoder["answer_steps"] * decoder["hidden_size"] * decoder["vocab_size"]
+    return 2.0 * (prefill + steps + head)
+
+
 # A family's walkers, by its zoo name (models/decoder.py has what else a family keeps).
 _DECODER_WALKERS = {"longcat_flash": (_longcat_flash_cost, _longcat_flash_image_flops),
-                    "nemotron_h": (_nemotron_h_cost, _nemotron_h_image_flops)}
+                    "nemotron_h": (_nemotron_h_cost, _nemotron_h_image_flops),
+                    "brumby": (_brumby_cost, _brumby_image_flops)}
 
 
 def _walkers(decoder: dict, name: str | None):

@@ -429,7 +429,7 @@ def test_a_family_is_found_by_its_zoo_name_and_longcat_flash_still_through_the_w
     from tensorflow_web_deploy_tpu.utils.config import ModelConfig
 
     assert shared.family("nemotron_h") is nh and shared.family("longcat_flash") is lf
-    assert shared.families() == ["longcat_flash", "nemotron_h"]
+    assert shared.families() == ["longcat_flash", "nemotron_h", "brumby"]
     with pytest.raises(ValueError, match="no token decoder"):
         shared.family("resnet50")
     # a caller that holds only the sizes: the family whose Config states every one of them

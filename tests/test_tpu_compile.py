@@ -23,7 +23,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from tensorflow_web_deploy_tpu.ops import experts, gqa, mla, ssd
+from tensorflow_web_deploy_tpu.ops import experts, gqa, mla, retention, ssd
 from tensorflow_web_deploy_tpu.ops.depthwise import fused_depthwise_bn
 from tensorflow_web_deploy_tpu.ops.image import unpack_ragged
 from tensorflow_web_deploy_tpu.ops.pallas_preprocess import preprocess_i420
@@ -168,3 +168,47 @@ def test_expert_gmm_compiles_for_v5e_at_the_second_decoders_widths(v5e, k, n):
         s(experts.CHUNK, k), s(64, k, n), s(experts.CHUNK // experts.ROW_TILE, dt=jnp.int32),
         s(1, dt=jnp.int32)).compile()
     assert compiled.as_text().count(KERNEL) == 1 and "expert_gmm" in compiled.as_text()
+
+
+# The third decoder's kernels at the published widths (40 query over 8 key/value heads of 128: 8,704 features a
+# head, a state of 35.9 MB a row and layer) and at the benchmark's three length buckets.
+@pytest.mark.parametrize("rows,slots", [(16, 1024), (4, 2304), (4, 4096)])
+def test_retention_prefill_compiles_for_v5e(v5e, rows, slots):
+    """Under the 16 MiB of scoped VMEM: the state and normaliser in float32
+    (4.7 MB), their bfloat16 copy, and one chunk's features of keys and of
+    one query head (2.2 MB each)."""
+    s = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    compiled = jax.jit(lambda q, k, v, g, n: retention.pallas_prefill(q, k, v, g, n, chunk=128)).lower(
+        s(rows, slots, 8, 5, 128), s(rows, slots, 8, 128), s(rows, slots, 8, 128), s(rows, slots, 8, dt=jnp.float32),
+        s(rows, dt=jnp.int32)).compile()
+    assert compiled.as_text().count(KERNEL) == 1 and "retention_prefill" in compiled.as_text()
+
+
+def test_retention_step_compiles_for_v5e_and_updates_the_states_in_place(v5e):
+    s = lambda *shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    n_feat = retention.feature_count(128)
+    compiled = jax.jit(lambda q, k, v, g, st, z: retention.pallas_step(q, k, v, g, st, z), donate_argnums=(4, 5)).lower(
+        s(16, 8, 5, 128), s(16, 8, 128), s(16, 8, 128), s(16, 8), s(16, 8, 128, n_feat), s(16, 8, 1, n_feat)).compile()
+    assert compiled.as_text().count(KERNEL) == 1 and "retention_step" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 16 * 8 * 128 * n_feat * 4 and m.temp_size_in_bytes < 64 << 20
+
+
+def test_the_brumby_call_holds_each_state_once(v5e, monkeypatch):
+    """``answer`` at the published widths, 16 rows of 256 slots, with the
+    kernels' path: a layer more adds one layer's states (16 rows x 35.9 MB)
+    to the call's temporaries, not two. The 63 steps carry them through a
+    ``lax.scan`` and ``retention_step`` writes them where they were read."""
+    from tensorflow_web_deploy_tpu.models import brumby
+
+    monkeypatch.setattr(retention, "_on_tpu", lambda: True)
+    s = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    temps = []
+    for layers in (1, 2):
+        c = brumby.Config(num_hidden_layers=layers)
+        params = {k: s(*v) for k, v in brumby.param_shapes(c).items()}
+        compiled = jax.jit(lambda p, x, n: brumby.answer(c, p, x, n, 5)).lower(
+            params, s(16, 256, 3072), s(16, dt=jnp.int32)).compile()
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+    states = 16 * 8 * (128 + 1) * retention.feature_count(128) * 4
+    assert states <= temps[1] - temps[0] < 1.5 * states, (temps, states)
