@@ -88,13 +88,33 @@ def dense_ffn(x, w_gate, w_up, w_down):
     return jax.lax.map(one, flat.reshape(-1, FFN_CHUNK, x.shape[-1])).reshape(x.shape)
 
 
+def select_top(x, k: int):
+    """The ``k`` largest of ``x`` along its last axis and their indices,
+    equal to ``jax.lax.top_k(x, k)`` bit for bit where ``x`` holds no NaN
+    and no -0 (a softmax's probabilities hold neither; ``lax.top_k`` ranks
+    +0 above -0, this takes them as one value): of equal values the lower
+    index comes first. Nothing is sorted: ``k`` passes over ``x``, each
+    the largest value still left, then the lowest index that holds it; what
+    is left is what comes after the last pick in that order."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    scores, ids = [], []
+    for _ in range(k):
+        left = True if not ids else (x < scores[-1]) | ((x == scores[-1]) & (pos > ids[-1]))
+        best = jnp.max(jnp.where(left, x, -jnp.inf), axis=-1, keepdims=True)
+        scores.append(best)
+        ids.append(jnp.min(jnp.where(left & (x == best), pos, x.shape[-1]), axis=-1, keepdims=True))
+    return jnp.concatenate(scores, axis=-1), jnp.concatenate(ids, axis=-1)
+
+
 def top(hidden, final_norm, head, eps: float, topk: int):
-    """Final norm, head, softmax over every id held, the ``topk`` largest."""
+    """Final norm, head, softmax over every id held in float32, the
+    ``topk`` largest probabilities and their ids by :func:`select_top`:
+    exact, with no sort of the vocabulary, the lower id first of equal
+    probabilities (as ``jax.lax.top_k``)."""
     with jax.named_scope("head"):
         hn = rmsnorm(hidden, final_norm, eps, head.dtype)
         logits = jnp.dot(hn, head, preferred_element_type=jnp.float32)
-        scores, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), min(topk, logits.shape[-1]))
-        return scores, ids.astype(jnp.int32)
+        return select_top(jax.nn.softmax(logits, axis=-1), min(topk, logits.shape[-1]))
 
 
 def layer_params(params: dict, l: int) -> dict:
