@@ -458,13 +458,18 @@ class Batcher:
         # Cumulative lifecycle counters (/stats → batcher.lifecycle), all
         # monotonic, all updated under self._cond: _hand_off and _batch_done
         # take it anyway, _launch takes it once more for its stamp. A
-        # batch's four phases (open → seal → launch → launched → done) sum
-        # to t_done - t_open; fetch_wait is the part of inflight that its
-        # completion thread spent on it. The starved clock runs while no
-        # batch stands between its t_launch and its t_done on any replica:
-        # stamped when that count goes 1→0, added when it goes 0→1 (exact
-        # to the time a completion thread takes from stamping t_done to
-        # _batch_done's lock).
+        # batch's phases (open → seal → launch → done) sum to t_done -
+        # t_open; inflight is t_launched → t_done. Where the engine stamps
+        # the batch's flight (engine.Flight), [t_launch, t_done] splits
+        # into four more that tile it: the H2D copy (→ t_h2d_done), the
+        # wait behind earlier calls on the device (→ t_dev_start), the
+        # device's own work (→ t_ready) and the D2H (→ t_done); a failed
+        # dispatch or fetch adds to none of them. The starved clock runs
+        # while no batch stands between its t_launch and its t_done on any
+        # replica: stamped when that count goes 1→0, added when it goes 0→1
+        # (exact to the time a completion thread takes from stamping t_done
+        # to _batch_done's lock). The h2d-bound clock runs while some
+        # batch's copy is in flight and no batch is in its device phase.
         self._life = {
             "batches_total": 0,
             "by_reason": dict.fromkeys(SEAL_REASONS, 0),
@@ -473,8 +478,10 @@ class Batcher:
             # free for it (_pick_action_locked)
             "window_holds_total": 0,
             "open_s_total": 0.0, "launch_wait_s_total": 0.0,
-            "enqueue_s_total": 0.0, "inflight_s_total": 0.0,
-            "fetch_wait_s_total": 0.0,
+            "inflight_s_total": 0.0,
+            "h2d_s_total": 0.0, "device_queue_s_total": 0.0,
+            "device_s_total": 0.0, "d2h_s_total": 0.0,
+            "h2d_bound_s_total": 0.0, "stamps_late_total": 0,
             "h2d_bytes_total": 0, "d2h_bytes_total": 0,
             "unpack_kernel_batches_total": 0,
             "starved_s_total": 0.0,
@@ -484,6 +491,13 @@ class Batcher:
         }
         self._launched_now = 0
         self._starved_since = time.monotonic()
+        # The h2d-bound clock, from the phases of batches that are done:
+        # t_launch of each batch launched and not done (nothing can begin
+        # before the earliest of them), the copy and device intervals not
+        # yet swept, and how far the clock has been swept.
+        self._launched: dict[int, float] = {}
+        self._phases: list[tuple[float, float, bool]] = []
+        self._phases_swept = self._starved_since
         # Padding-waste accounting per (canvas bucket, batch bucket):
         # [batches, rows real, rows dispatched, real px (Σ h·w of committed
         # rows), canvas px (batch bucket × canvas²)]. Two waste axes: row
@@ -1282,8 +1296,9 @@ class Batcher:
         # The batch record: identity, then the lifecycle's stamps in order
         # (all time.monotonic(); None until reached). The engine fills
         # t_put (both device_puts returned: the copy is *enqueued*), t_pre
-        # (unpack enqueued), h2d_bytes, d2h_bytes and unpack_kernel (the
-        # ragged unpack ran the Mosaic kernel) where it is handed the
+        # (unpack enqueued), the flight's t_h2d_done, t_dev_start, t_ready
+        # and late (engine.Flight), h2d_bytes, d2h_bytes and unpack_kernel
+        # (the ragged unpack ran the Mosaic kernel) where it is handed the
         # record; trace_ids are the request spans that rode.
         rec = {
             "seq": 0, "key": b.key, "rows": len(ready), "bucket": None,
@@ -1292,7 +1307,8 @@ class Batcher:
                                  if l.span is not None}),
             "t_open": b.opened_at, "t_seal": time.monotonic(),
             "t_launch": None, "t_put": None, "t_pre": None,
-            "t_launched": None, "t_fetch": None, "t_done": None,
+            "t_launched": None, "t_h2d_done": None, "t_dev_start": None,
+            "t_ready": None, "t_fetch": None, "t_done": None, "late": (),
             "h2d_bytes": None, "d2h_bytes": None, "unpack_kernel": False,
         }
         with self._cond:
@@ -1317,18 +1333,27 @@ class Batcher:
             self._sealed_total += 1
             self._cond.notify_all()  # lease() waiters + next seal decision
 
-    def _batch_done(self, rec: dict):
-        """One in-flight batch left the pipeline (fetched or failed, its
-        ``t_done`` stamped): free its ((bucket, bulk), replica) depth slot,
-        close its lifecycle counters and wake the sealer — the wakeup that
-        also re-evaluates the bulk gate."""
+    def _batch_done(self, rec: dict, ok: bool = True):
+        """One in-flight batch left the pipeline (fetched, or failed when
+        not ``ok``; its ``t_done`` stamped): free its ((bucket, bulk),
+        replica) depth slot, close its lifecycle counters and wake the
+        sealer — the wakeup that also re-evaluates the bulk gate."""
         mkey = (rec["key"], rec["bulk"])
         with self._cond:
             life, t_done = self._life, rec["t_done"]
-            life["enqueue_s_total"] += rec["t_launched"] - rec["t_launch"]
             life["inflight_s_total"] += t_done - rec["t_launched"]
-            if rec["t_fetch"] is not None:
-                life["fetch_wait_s_total"] += t_done - rec["t_fetch"]
+            self._launched.pop(rec["seq"], None)
+            if ok and rec["t_ready"] is not None:
+                t_h2d, t_dev, t_ready = (rec["t_h2d_done"], rec["t_dev_start"],
+                                         rec["t_ready"])
+                life["h2d_s_total"] += t_h2d - rec["t_launch"]
+                life["device_queue_s_total"] += t_dev - t_h2d
+                life["device_s_total"] += t_ready - t_dev
+                life["d2h_s_total"] += t_done - t_ready
+                life["stamps_late_total"] += len(rec["late"])
+                self._phases += [(rec["t_launch"], t_h2d, False),
+                                 (t_dev, t_ready, True)]
+            self._sweep_h2d_bound_locked()
             life["h2d_bytes_total"] += rec["h2d_bytes"] or 0
             life["d2h_bytes_total"] += rec["d2h_bytes"] or 0
             life["unpack_kernel_batches_total"] += bool(rec["unpack_kernel"])
@@ -1349,6 +1374,33 @@ class Batcher:
             if mkey[1]:
                 self._bulk_inflight -= 1
             self._cond.notify_all()
+
+    def _sweep_h2d_bound_locked(self):
+        """Bring the h2d-bound clock up to the earliest ``t_launch`` of a
+        batch still in flight (or to now, with none): every copy or device
+        phase that can lie before that is a done batch's, and known."""
+        horizon = min(self._launched.values(), default=time.monotonic())
+        lo = self._phases_swept
+        if horizon <= lo:
+            return
+        # +1/-1 at each end of a phase clipped to [lo, horizon]; the clock
+        # runs where some copy and no device phase is open.
+        edges = []
+        for a, b, dev in self._phases:
+            a, b = max(a, lo), min(b, horizon)
+            if a < b:
+                edges += [(a, 1, dev), (b, -1, dev)]
+        edges.sort()
+        open_ = [0, 0]  # copies, device phases
+        t_prev, bound = lo, 0.0
+        for t, step, dev in edges:
+            if open_[0] and not open_[1]:
+                bound += t - t_prev
+            open_[dev] += step
+            t_prev = t
+        self._life["h2d_bound_s_total"] += bound
+        self._phases = [p for p in self._phases if p[1] > horizon]
+        self._phases_swept = horizon
 
     # ------------------------------------------------------------ launching
 
@@ -1373,6 +1425,7 @@ class Batcher:
             if self._launched_now == 0:
                 life["starved_s_total"] += max(0.0, t0 - self._starved_since)
             self._launched_now += 1
+            self._launched[rec["seq"]] = t0
         for l in ready:
             if l.span is not None:
                 # add_max: a multi-image request's legs ride concurrent
@@ -1407,14 +1460,14 @@ class Batcher:
                 if getattr(b.slab, "is_ragged", False):
                     # Ragged wire: ship the tight arena prefix + meta; the
                     # engine's jitted unpack stage rebuilds the canvases on
-                    # device (spans gain device_preprocess there).
+                    # device.
                     handle = self.engine.dispatch_ragged(b.slab, n,
                                                          spans=spans, **kw)
                 elif traced:
-                    # The engine stamps device_transfer/device_dispatch
-                    # itself (it owns the host→device transfer); spans=
-                    # keeps staging-API fakes and embedders with the plain
-                    # signature working.
+                    # The engine stamps the batch's flight into rec, which
+                    # the completion thread turns into the spans' device
+                    # stages; spans= (the replica note) keeps staging-API
+                    # fakes and embedders with the plain signature working.
                     handle = self.engine.dispatch_staged(b.slab, n,
                                                          spans=spans, **kw)
                 else:
@@ -1446,7 +1499,7 @@ class Batcher:
             # memory. Any aliased device read of dropped outputs is
             # harmless: nobody fetches them.
             self._recycle(b)
-            self._batch_done(rec)
+            self._batch_done(rec, ok=False)
             return
         rec["t_launched"] = time.monotonic()
         rec["bucket"] = bucket
@@ -1517,19 +1570,27 @@ class Batcher:
                 log.exception("fetch of batch of %d failed", len(ready))
                 self._fail(ready, e)
                 rec["t_done"] = time.monotonic()
-                self._batch_done(rec)
+                self._batch_done(rec, ok=False)
                 continue
             now = time.monotonic()
             rec["t_done"] = now
-            t_launch, t_launched = rec["t_launch"], rec["t_launched"]
+            t_launch, t_ready = rec["t_launch"], rec["t_ready"]
+            if t_ready is not None:
+                # The flight's own stamps: the copy, the wait behind
+                # earlier calls plus the device's work, the copy back.
+                t_h2d = rec["t_h2d_done"]
+                stages = (("device_transfer", t_h2d - t_launch),
+                          ("device_execute", t_ready - t_h2d),
+                          ("device_d2h", now - t_ready))
+            else:
+                stages = (("device_execute", now - rec["t_launched"]),)
             for l, oi in zip(ready, idxs):
                 row = tuple(o[oi] for o in outs)
                 if l.span is not None:
                     # Stamp BEFORE resolving the future: once set_result
-                    # runs, the HTTP worker owns the span again. Execute
-                    # time excludes the transfer — that is the separate
-                    # device_transfer stage stamped at launch.
-                    l.span.add_max("device_execute", now - t_launched)
+                    # runs, the HTTP worker owns the span again.
+                    for name, dur in stages:
+                        l.span.add_max(name, dur)
                 try:
                     l.future.set_result(row)
                 except Exception:
@@ -1651,9 +1712,11 @@ class Batcher:
     def lifecycle_stats(self) -> dict:
         """The ``/stats → batcher.lifecycle`` block: the cumulative
         counters above plus ``now_s``, the clock they were read at, and the
-        starved clock brought up to it, so that a share is a delta over a
-        delta of two reads."""
+        starved clock brought up to it (the h2d-bound clock as far as the
+        earliest batch in flight), so that a share is a delta over a delta
+        of two reads."""
         with self._cond:
+            self._sweep_h2d_bound_locked()
             now = time.monotonic()
             out = {**self._life, "by_reason": dict(self._life["by_reason"])}
             if self._launched_now == 0:
@@ -1666,9 +1729,11 @@ class Batcher:
         ``t_open`` → ``t_seal`` (assembly/decode window; ``reason`` says
         why it sealed) → ``t_launch`` → ``t_put`` (both ``device_put``s
         returned) → ``t_pre`` (unpack enqueued) → ``t_launched`` (execute
-        enqueued, D2H started) → ``t_fetch`` (a completion thread turned to
-        it) → ``t_done`` (outputs on host), with ``h2d_bytes``/``d2h_bytes``
-        and the ``trace_ids`` that rode. In-flight batches carry None for
+        enqueued, D2H started) → ``t_done`` (outputs on host); the flight's
+        ``t_h2d_done`` (copy landed), ``t_dev_start`` and ``t_ready``
+        (outputs computed) with ``late``; ``t_fetch`` (a completion thread
+        turned to it); ``h2d_bytes``/``d2h_bytes`` and the ``trace_ids``
+        that rode. In-flight batches carry None for
         stages not reached yet. The raw material for overlap analysis —
         bench.py's ``pipeline`` block computes busy-time(decode ∥ execute)
         from exactly this."""

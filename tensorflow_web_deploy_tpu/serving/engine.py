@@ -27,6 +27,7 @@ import contextlib
 import dataclasses
 import logging
 import os
+import queue
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -55,7 +56,7 @@ _NO_LOCK = contextlib.nullcontext()
 # layout, postprocess), so executables cached by an older build can
 # never serve a newer build's traffic. The config-derived key components
 # cover operator-visible knobs; this covers the code itself.
-SERVE_FN_VERSION = 1
+SERVE_FN_VERSION = 2
 
 
 class StagingSlab:
@@ -320,6 +321,146 @@ def _batch_ids(rec: dict | None) -> dict:
     return {} if rec is None else {"seq": rec["seq"], "rows": rec["rows"]}
 
 
+class Flight:
+    """One call's flight on its device, stamped (``time.monotonic()``) where
+    each phase ends: ``t_h2d_done`` the inputs' copy landed, ``t_dev_start``
+    the device turned to the call (the later of the copy's end and the
+    previous call's ``t_ready``: a device runs its calls in order),
+    ``t_ready`` the outputs are computed, before their copy to the host.
+    ``late`` names the stamps that a thread took only after the event had
+    happened: upper bounds, not times. The stamps are written into ``rec``
+    too, the batcher's record of the batch, where there is one."""
+
+    __slots__ = ("rec", "prev", "ann", "t_h2d_done", "t_dev_start",
+                 "t_ready", "late")
+
+    def __init__(self, rec: dict | None, ann):
+        self.rec, self.ann, self.prev = rec, ann, None
+        self.t_h2d_done = self.t_dev_start = self.t_ready = None
+        self.late: tuple[str, ...] = ()
+
+    @property
+    def device_s(self) -> float:
+        """The device phase: the device's own time on the call."""
+        return 0.0 if self.t_ready is None else self.t_ready - self.t_dev_start
+
+    def _set(self, key: str, t: float, late: bool = False):
+        setattr(self, key, t)
+        if late:
+            self.late += (key,)
+        if self.rec is not None:
+            self.rec[key] = t
+            self.rec["late"] = self.late
+
+
+class FlightLog:
+    """The calls of one device stream in the order they were enqueued, and
+    the threads that stamp their flights: a few waiter threads of this
+    log's own take each copy as it is handed over and wait for the call's
+    inputs to land (copies of consecutive calls overlap and may land out of
+    order); the thread that fetches a call waits for its outputs to be
+    computed before it converts them (:meth:`land`). Each was waiting
+    already when its event happened, so a stamp is the event's time; one
+    whose event had happened when its thread turned to it is ``late``.
+
+    The copy's profiler annotation ``twd.h2d_flight`` (stats ``seq``,
+    ``rows``, ``h2d_bytes``) opens on the launch thread as the ``device_put``
+    starts and closes where ``t_h2d_done`` is stamped. Stamps are taken
+    under ``lock`` (the engine's route lock), so that whichever thread
+    stamps first stamps once."""
+
+    # Copies waited on at once (the photos cell has had three in flight).
+    WAITERS = 4
+
+    def __init__(self, lock, name: str = "h2d-watch"):
+        self._lock = lock
+        self._last: Flight | None = None
+        self._copies: queue.Queue = queue.Queue()
+        self._waiters = [threading.Thread(target=self._watch, name=name,
+                                          daemon=True)
+                         for _ in range(self.WAITERS)]
+        for t in self._waiters:
+            t.start()
+
+    def start(self, rec: dict | None, label: str, nbytes: int) -> Flight:
+        """A dispatch is about to put ``nbytes`` of inputs: its flight."""
+        ann = stage(None, "h2d_flight", label, **_batch_ids(rec),
+                    h2d_bytes=int(nbytes))
+        return Flight(rec, ann.__enter__())
+
+    def copying(self, f: Flight, bufs) -> None:
+        """Hand the arrays ``device_put`` just returned to a waiter."""
+        self._copies.put((f, tuple(bufs)))
+
+    def close(self) -> None:
+        for _ in self._waiters:
+            self._copies.put(None)
+        for t in self._waiters:
+            t.join(timeout=5)
+
+    def enqueued(self, f: Flight) -> None:
+        """The call is on the device's queue, behind the one before it
+        (called outside the replica's dispatch guard: the route lock ranks
+        above it)."""
+        with self._lock:
+            f.prev, self._last = self._last, f
+
+    def land(self, f: Flight, outs) -> None:
+        """Wait until the call's outputs are computed and stamp it."""
+        leaves = jax.tree.leaves(outs)
+        late = all(o.is_ready() for o in leaves)
+        try:
+            for o in leaves:
+                o.block_until_ready()
+        finally:
+            with self._lock:
+                self._land_locked(f, late)
+
+    def _copied_locked(self, f: Flight, late: bool):
+        f.ann.__exit__(None, None, None)
+        f._set("t_h2d_done", f.ann.t1, late)
+
+    def _land_locked(self, f: Flight, late: bool):
+        # The device runs its calls in order: when this call's outputs are
+        # computed, so are those of the calls before it, and their copies
+        # have landed. Whatever of theirs is unstamped yet is stamped now.
+        chain = []
+        while f is not None and f.t_ready is None:
+            chain.append(f)
+            f = f.prev
+        for g in chain:
+            if g.t_h2d_done is None:
+                self._copied_locked(g, True)
+        now = time.monotonic()
+        for i, g in enumerate(reversed(chain)):
+            behind = i < len(chain) - 1
+            prev = g.prev
+            g._set("t_dev_start", g.t_h2d_done if prev is None
+                   else max(g.t_h2d_done, prev.t_ready))
+            g._set("t_ready", now, late or behind)
+            g.prev = None
+
+    def _watch(self):
+        while True:
+            item = self._copies.get()
+            if item is None:
+                return
+            self._wait_copy(*item)
+            del item  # the inputs' device buffers go with it
+
+    def _wait_copy(self, f: Flight, bufs: tuple):
+        late = True
+        try:
+            late = all(b.is_ready() for b in bufs)
+            for b in bufs:
+                b.block_until_ready()
+        except Exception:
+            pass  # a copy that failed fails its call: the fetch says so
+        with self._lock:
+            if f.t_h2d_done is None:
+                self._copied_locked(f, late)
+
+
 class _DeviceBatch:
     """Slab-shaped handle for :meth:`InferenceEngine.dispatch_device` —
     a DEVICE-RESIDENT batch (DAG glue output) that never had a host
@@ -352,7 +493,7 @@ class _Replica:
     __slots__ = ("index", "mesh", "params", "serve", "exe", "data_sharding",
                  "replicated", "dispatch_guard", "serialize",
                  "dispatches_total", "dispatches_inflight",
-                 "slab_bytes_inflight", "busy_s", "econ",
+                 "slab_bytes_inflight", "busy_s", "econ", "flights",
                  "param_device_ids", "param_bytes")
 
     def __init__(self, index: int, mesh):
@@ -386,17 +527,20 @@ class _Replica:
         self.dispatches_total = 0
         self.dispatches_inflight = 0
         self.slab_bytes_inflight = 0
-        # Cumulative dispatch→fetch seconds: per-replica busy attribution
-        # for /stats (interval SUM, so depth>1 overlap can push a window's
-        # delta past wall clock — readers cap the fraction at 1).
+        # Cumulative device-phase seconds (Flight.device_s): per-replica
+        # busy attribution for /stats. The device runs its calls one after
+        # another, so a window's delta never passes its wall clock.
         self.busy_s = 0.0
         # Device-economics counters, keyed (canvas bucket, batch bucket):
         # [batches, rows staged, rows dispatched (= bucket × batches),
-        # cumulative dispatch→fetch seconds]. The measured half of the
+        # cumulative device-phase seconds, tight rows]. The measured half of the
         # roofline attribution (serving/costmodel.py supplies the analytic
         # half); bounded by the compiled bucket grid, so it can never grow
         # past len(canvas_buckets) × len(batch_buckets) entries.
         self.econ: dict[tuple[int, int], list] = {}
+        # This stream's calls in enqueue order, stamped where each phase of
+        # their flight ends (InferenceEngine._flights makes it on first use).
+        self.flights: FlightLog | None = None
 
 
 class InferenceEngine:
@@ -909,20 +1053,13 @@ class InferenceEngine:
 
             return serve_packed
 
-        # Donate the packed input buffer on real accelerators: the uint8
-        # wire buffer is consumed by the first reshape/convert, so donation
-        # lets XLA reuse its HBM for activations instead of holding both —
-        # free memory headroom at pipeline depth > 1, where several batches'
-        # inputs are device-resident at once. The host-side slab is never
-        # aliased (device_put copies), so nothing observable changes. CPU
-        # backends skip it: XLA-CPU can't honor the donation and would log
-        # a warning per compiled shape.
-        donate = (1,) if jax.default_backend() != "cpu" else ()
+        # The packed buffer is not donated: the replica's FlightLog waits on
+        # it for the copy's end, and a donated buffer is deleted by the call
+        # it feeds.
         for rep in self._replicas:
             rep.serve = jax.jit(
                 make_packed(serve_for(rep)),
                 in_shardings=(rep.replicated, rep.data_sharding),
-                donate_argnums=donate,
             )
 
     # ------------------------------------------------------- AOT executables
@@ -1356,16 +1493,15 @@ class InferenceEngine:
         """Dispatch a filled staging slab (async); returns an opaque handle
         for :meth:`fetch_outputs`. ``replica`` pins the dispatch stream
         (the batcher routes at seal time); None routes here via
-        :meth:`route_replica`. ``spans`` (request trace spans) get two
-        stages stamped — ``device_transfer`` (the host→device ship of the
-        slab) and ``device_dispatch`` (execute enqueue + async D2H start) —
-        plus a ``replica`` note, so per-chip attribution survives into the
-        access log and flight recorder. PJRT transfers are asynchronous, so
-        the transfer stamp is the enqueue cost and the wire time folds into
-        ``device_execute``. ``rec`` is the batcher's record of this batch
-        (Batcher._hand_off): its ``seq`` and ``rows`` name the profiler
-        annotations (``twd.h2d``, ``twd.serve_enqueue``, ``twd.d2h_start``),
-        and ``t_put`` and ``h2d_bytes`` are written into it.
+        :meth:`route_replica`. ``spans`` (request trace spans) get a
+        ``replica`` note, so per-chip attribution survives into the access
+        log and flight recorder; their device stages are the batcher's,
+        from the stamps of the batch's flight. ``rec`` is the batcher's
+        record of this batch (Batcher._hand_off): its ``seq`` and ``rows``
+        name the profiler annotations (``twd.h2d``, ``twd.h2d_flight``,
+        ``twd.serve_enqueue``, ``twd.d2h_start``), and ``t_put``,
+        ``h2d_bytes`` and the flight's stamps (:class:`Flight`) are written
+        into it.
 
         Dispatch and fetch are split so the batcher's pipeline can overlap
         batch N+1's transfer/compute with batch N's execute and device→host
@@ -1379,7 +1515,6 @@ class InferenceEngine:
         outputs starts at dispatch time so the fetch side pays neither
         compute wait nor transfer round-trip latency when it finally blocks.
         """
-        t0 = time.monotonic()
         slab.pad_from(n)
         # The slot-lease batcher acquires top-capacity slabs before it knows
         # the final batch size, so dispatch re-buckets: ship only the prefix
@@ -1397,8 +1532,8 @@ class InferenceEngine:
             rep.slab_bytes_inflight += slab.total_bytes
         guard = rep.dispatch_guard if rep.serialize else _NO_LOCK
         try:
-            outs, t_put, nbytes = self._dispatch_on(
-                rep, guard, slab, bucket, _batch_ids(rec))
+            outs, t_put, nbytes, flight = self._dispatch_on(
+                rep, guard, slab, bucket, rec)
         except BaseException:
             # Roll the LIVE accounting back: a failed dispatch never
             # reaches fetch_outputs, and leaked in-flight counts would make
@@ -1410,49 +1545,61 @@ class InferenceEngine:
                 rep.dispatches_inflight -= 1
                 rep.slab_bytes_inflight -= slab.total_bytes
             raise
-        t_disp = time.monotonic()
         if rec is not None:
             rec["t_put"], rec["h2d_bytes"] = t_put, nbytes
         for s in spans:
-            s.add_max("device_transfer", t_put - t0)
-            s.add_max("device_dispatch", t_disp - t_put)
             s.note("replica", r)
-        return outs, (n, slab, r, t_disp, bucket)
+        return outs, (n, slab, r, flight, bucket)
+
+    def _flights(self, rep: _Replica) -> FlightLog:
+        if rep.flights is None:
+            with self._route_lock:
+                if rep.flights is None:
+                    rep.flights = FlightLog(self._route_lock,
+                                            f"h2d-watch-{rep.index}")
+        return rep.flights
 
     def _dispatch_on(self, rep: _Replica, guard, slab: StagingSlab,
-                     bucket: int, ids: dict):
+                     bucket: int, rec: dict | None):
         """The guarded device work of one dispatch: host→device transfer +
         execute enqueue + async D2H start on ``rep``'s stream, each under
-        its profiler annotation (``ids``: the batch's seq and rows).
-        Returns (outputs, when the last ``device_put`` returned, bytes
-        shipped)."""
+        its profiler annotation (named by ``rec``'s seq and rows). Returns
+        (outputs, when the last ``device_put`` returned, bytes shipped, the
+        call's :class:`Flight`)."""
         serve = self._serve_exe_for(rep, slab.key[0], bucket)
         label = f"c{canvas_side(slab.key[0])} b{bucket}"
+        ids = _batch_ids(rec)
+        flights = self._flights(rep)
         with guard:
             if self.cfg.packed_io:
                 buf = slab.buf if bucket == slab.bucket else slab.buf[:bucket]
                 nbytes = buf.nbytes
+                flight = flights.start(rec, label, nbytes)
                 with stage(None, "h2d", label, **ids) as put:
                     # twdlint: disable=no-blocking-under-lock(the per-replica dispatch guard EXISTS to hold device enqueue: two concurrent multi-device XLA:CPU dispatches into ONE replica interleave per-device partitions and deadlock the collective rendezvous; disjoint replicas never contend, and the guard is a nullcontext off CPU / on single-device replicas)
-                    buf_d = jax.device_put(buf, rep.data_sharding)
+                    bufs = (jax.device_put(buf, rep.data_sharding),)
+                flights.copying(flight, bufs)
                 with stage(None, "serve_enqueue", label, **ids):
-                    outs = serve(rep.params, buf_d)
+                    outs = serve(rep.params, *bufs)
             else:
                 trim = bucket != slab.bucket
                 canvases = slab.canvases[:bucket] if trim else slab.canvases
                 hws = slab.hws[:bucket] if trim else slab.hws
                 nbytes = canvases.nbytes + hws.nbytes
+                flight = flights.start(rec, label, nbytes)
                 with stage(None, "h2d", label, **ids) as put:
                     # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as the packed branch — the guarded region is exactly the device enqueue)
                     canvases_d = jax.device_put(canvases, rep.data_sharding)
                     # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as the packed branch)
                     hws_d = jax.device_put(hws, rep.data_sharding)
+                flights.copying(flight, (canvases_d, hws_d))
                 with stage(None, "serve_enqueue", label, **ids):
                     outs = serve(rep.params, canvases_d, hws_d)
             with stage(None, "d2h_start", label, **ids):
                 for leaf in jax.tree.leaves(outs):
                     leaf.copy_to_host_async()
-        return outs, put.t1, nbytes
+        flights.enqueued(flight)
+        return outs, put.t1, nbytes, flight
 
     def _ragged_unpack(self, rep: _Replica, canvas_s: int, bucket: int,
                        rows: int, counts: dict | None = None):
@@ -1525,13 +1672,11 @@ class InferenceEngine:
         """Dispatch a filled ragged arena (async) — the tight-wire sibling
         of :meth:`dispatch_staged`. Ships the arena's used prefix (see
         :meth:`RaggedSlab.rows_shipped`) plus the meta table, enqueues the
-        jitted device-side unpack, then the replica's serve fn; the handle
-        feeds the SAME :meth:`fetch_outputs`. Spans gain a
-        ``device_preprocess`` stage between transfer and dispatch — the
-        enqueue of the unpack program (annotation ``twd.unpack_enqueue``);
-        ``rec`` as in :meth:`dispatch_staged`, with ``t_pre`` and
+        jitted device-side unpack (annotation ``twd.unpack_enqueue``), then
+        the replica's serve fn; the handle feeds the SAME
+        :meth:`fetch_outputs`. ``spans`` and ``rec`` as in
+        :meth:`dispatch_staged`, with ``t_pre`` (the unpack enqueued) and
         ``unpack_kernel`` (the unpack ran the Mosaic kernel) besides."""
-        t0 = time.monotonic()
         bucket = self.pick_batch_bucket(n)
         r = self.route_replica() if replica is None else int(replica)
         rep = self._replicas[r]
@@ -1541,9 +1686,8 @@ class InferenceEngine:
             rep.slab_bytes_inflight += slab.total_bytes
         guard = rep.dispatch_guard if rep.serialize else _NO_LOCK
         try:
-            outs, t_put, t_pre, nbytes, kernel = self._dispatch_ragged_on(
-                rep, guard, slab, bucket, _batch_ids(rec)
-            )
+            outs, t_put, t_pre, nbytes, kernel, flight = \
+                self._dispatch_ragged_on(rep, guard, slab, bucket, rec)
         except BaseException:
             # Same live-accounting rollback as dispatch_staged; the totals
             # stay (Prometheus counters must never decrease).
@@ -1551,24 +1695,21 @@ class InferenceEngine:
                 rep.dispatches_inflight -= 1
                 rep.slab_bytes_inflight -= slab.total_bytes
             raise
-        t_disp = time.monotonic()
         if rec is not None:
             rec["t_put"], rec["t_pre"], rec["h2d_bytes"] = t_put, t_pre, nbytes
             rec["unpack_kernel"] = kernel
         for s in spans:
-            s.add_max("device_transfer", t_put - t0)
-            s.add_max("device_preprocess", t_pre - t_put)
-            s.add_max("device_dispatch", t_disp - t_pre)
             s.note("replica", r)
-        return outs, (n, slab, r, t_disp, bucket)
+        return outs, (n, slab, r, flight, bucket)
 
     def _dispatch_ragged_on(self, rep: _Replica, guard, slab: RaggedSlab,
-                            bucket: int, ids: dict):
+                            bucket: int, rec: dict | None):
         """Guarded device work of one ragged dispatch: ship arena prefix +
         meta, enqueue unpack, enqueue serve, start the async D2H copy, each
         under its profiler annotation. Returns (outputs, when the second
         ``device_put`` returned, when the unpack was enqueued, bytes
-        shipped, whether the unpack is the kernel)."""
+        shipped, whether the unpack is the kernel, the call's
+        :class:`Flight`)."""
         rows = slab.rows_shipped(bucket)
         unpack, arena_sh, kernel = self._ragged_unpack(
             rep, slab.canvas_s, bucket, rows)
@@ -1578,12 +1719,17 @@ class InferenceEngine:
             arena = arena.view(np.uint32)  # the same bytes on the wire
         meta = slab.meta if bucket == slab.bucket else slab.meta[:bucket]
         label = f"c{slab.canvas_s} b{bucket}"
+        ids = _batch_ids(rec)
+        flights = self._flights(rep)
+        nbytes = arena.nbytes + meta.nbytes
         with guard:
+            flight = flights.start(rec, label, nbytes)
             with stage(None, "h2d", label, **ids) as put:
                 # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on — the guarded region is exactly the device enqueue)
                 arena_d = jax.device_put(arena, arena_sh)
                 # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on)
                 meta_d = jax.device_put(meta, rep.replicated)
+            flights.copying(flight, (arena_d, meta_d))
             with stage(None, "unpack_enqueue", label, **ids) as pre:
                 canvases_d, hws_d = unpack(arena_d, meta_d)
             with stage(None, "serve_enqueue", label, **ids):
@@ -1591,7 +1737,8 @@ class InferenceEngine:
             with stage(None, "d2h_start", label, **ids):
                 for leaf in jax.tree.leaves(outs):
                     leaf.copy_to_host_async()
-        return outs, put.t1, pre.t1, arena.nbytes + meta.nbytes, kernel
+        flights.enqueued(flight)
+        return outs, put.t1, pre.t1, nbytes, kernel, flight
 
     def dispatch_batch(self, canvases: np.ndarray, hws: np.ndarray,
                        replica: int | None = None):
@@ -1609,25 +1756,30 @@ class InferenceEngine:
         into per-output views using the traced tail shapes). Completing the
         fetch proves the device consumed the inputs, so the batch's staging
         slab becomes pool-eligible here — actual return waits for any
-        straggling slot lessee via the slab's refcount. The blocking
-        conversion lies under the annotation ``twd.fetch``, named by
-        ``rec`` (the batcher's record of the batch), which also receives
-        ``d2h_bytes``."""
-        outs, (n, slab, r, t_disp, bucket) = handle
+        straggling slot lessee via the slab's refcount. The wait for the
+        outputs to be computed (which stamps the flight's ``t_ready``), then
+        the blocking conversion, lie under the annotation ``twd.fetch``,
+        named by ``rec`` (the batcher's record of the batch), which also
+        receives ``d2h_bytes``."""
+        outs, (n, slab, r, flight, bucket) = handle
+        rep = self._replicas[r]
         wait = stage(None, "fetch", f"c{canvas_side(slab.key[0])} b{bucket}",
                      **_batch_ids(rec))
         try:
+            with wait:
+                self._flights(rep).land(flight, outs)
+                host = (np.asarray(outs) if self.cfg.packed_io
+                        else jax.tree.map(np.asarray, outs))
+            nbytes = sum(o.nbytes for o in jax.tree.leaves(host))
+            self.note_d2h(nbytes)
+            if rec is not None:
+                rec["d2h_bytes"] = nbytes
             if self.cfg.packed_io:
                 # The conversion transfers the FULL compiled bucket (the
                 # device array is one buffer); the slice to n happens on
                 # host — which is exactly why the DAG executor's partial
                 # row fetches beat this path on D2H bytes/image.
-                with wait:
-                    packed_full = np.asarray(outs)
-                self.note_d2h(packed_full.nbytes)
-                if rec is not None:
-                    rec["d2h_bytes"] = packed_full.nbytes
-                packed = packed_full[:n]
+                packed = host[:n]
                 result = []
                 off = 0
                 for shape, dt in self._out_tails:
@@ -1639,59 +1791,58 @@ class InferenceEngine:
                     result.append(chunk.astype(dt) if dt != np.float32 else chunk)
                     off += size
                 return tuple(result)
-            with wait:
-                outs = jax.tree.map(lambda o: np.asarray(o), outs)
-            nbytes = sum(o.nbytes for o in jax.tree.leaves(outs))
-            self.note_d2h(nbytes)
-            if rec is not None:
-                rec["d2h_bytes"] = nbytes
             if self.counter_names:
                 # The model's last output is the call's counters, not a
                 # row a request: the batcher sums them into /stats.
-                *outs, counted = outs
-                outs = tuple(outs)
+                *host, counted = host
+                host = tuple(host)
                 if rec is not None:
                     rec["model_counters"] = dict(
                         zip(self.counter_names, (float(v) for v in counted)))
-            outs = jax.tree.map(lambda o: o[:n], outs)
-            return outs if isinstance(outs, tuple) else (outs,)
+            host = jax.tree.map(lambda o: o[:n], host)
+            return host if isinstance(host, tuple) else (host,)
         finally:
-            rep = self._replicas[r]
-            busy = max(0.0, time.monotonic() - t_disp)
-            ekey = (canvas_side(slab.key[0]), bucket)
-            with self._route_lock:
-                rep.dispatches_inflight -= 1
-                rep.slab_bytes_inflight -= slab.total_bytes
-                rep.busy_s += busy
-                # Economics cell for this (canvas, batch-bucket): batches,
-                # rows staged, rows the compiled shape dispatched, device
-                # seconds — the measured inputs of the roofline gauges.
-                cell = rep.econ.get(ekey)
-                if cell is None:
-                    cell = rep.econ[ekey] = [0, 0, 0, 0.0, 0.0]
-                cell[0] += 1
-                cell[1] += n
-                # Ragged batches ship quantized arena rows, not the full
-                # bucket — the whole point of the wire; the economics
-                # padding gauges must see what actually crossed it. The
-                # tight-rows term (exact used bytes, before the shipped-
-                # prefix quantization) is the same-unit numerator the
-                # wire-padding fraction needs: requests (cell[1]) count
-                # images, which on this wire occupy FEWER rows than they
-                # number, so rows/rows_dispatched would go negative.
-                if getattr(slab, "is_ragged", False):
-                    cell[2] += slab.rows_shipped(bucket)
-                    cell[4] += slab.used / slab.row_bytes
-                else:
-                    # Full-canvas dispatch: every real image occupies
-                    # exactly one canvas row, so the payload IS n tight
-                    # rows. Without this, warmup/healthcheck batches (and
-                    # any classic-path dispatch on a ragged engine) would
-                    # read as pure padding in the ragged aggregate.
-                    cell[2] += bucket
-                    cell[4] += n
-                cell[3] += busy
-            slab.finish_fetch()
+            self._close_flight(rep, n, slab, bucket, flight)
+
+    def _close_flight(self, rep: _Replica, n: int, slab, bucket: int,
+                      flight: Flight):
+        """A dispatched batch is done with (fetched, or released by the DAG
+        path): its replica's in-flight accounting, the device phase into
+        ``busy_s`` and the economics cell, and the slab's pool-return."""
+        ekey = (canvas_side(slab.key[0]), bucket)
+        with self._route_lock:
+            rep.dispatches_inflight -= 1
+            rep.slab_bytes_inflight -= slab.total_bytes
+            rep.busy_s += flight.device_s
+            # Economics cell for this (canvas, batch-bucket): batches, rows
+            # staged, rows the compiled shape dispatched, device seconds —
+            # the measured inputs of the roofline gauges.
+            cell = rep.econ.get(ekey)
+            if cell is None:
+                cell = rep.econ[ekey] = [0, 0, 0, 0.0, 0.0]
+            cell[0] += 1
+            cell[1] += n
+            # Ragged batches ship quantized arena rows, not the full
+            # bucket — the whole point of the wire; the economics padding
+            # gauges must see what actually crossed it. The tight-rows term
+            # (exact used bytes, before the shipped-prefix quantization) is
+            # the same-unit numerator the wire-padding fraction needs:
+            # requests (cell[1]) count images, which on this wire occupy
+            # FEWER rows than they number, so rows/rows_dispatched would go
+            # negative.
+            if getattr(slab, "is_ragged", False):
+                cell[2] += slab.rows_shipped(bucket)
+                cell[4] += slab.used / slab.row_bytes
+            else:
+                # Full-canvas dispatch: every real image occupies exactly
+                # one canvas row, so the payload IS n tight rows. Without
+                # this, warmup/healthcheck batches (and any classic-path
+                # dispatch on a ragged engine) would read as pure padding
+                # in the ragged aggregate.
+                cell[2] += bucket
+                cell[4] += n
+            cell[3] += flight.device_s
+        slab.finish_fetch()
 
     # ------------------------------------------------- DAG (device-resident)
 
@@ -1714,7 +1865,7 @@ class InferenceEngine:
         (the same tail walk fetch_outputs does on host). The caller still
         owes the handle a :meth:`fetch_outputs` or
         :meth:`release_dispatch` — this only *reads* the device arrays."""
-        outs, (n, slab, r, t_disp, bucket) = handle
+        outs = handle[0]
         if not self.cfg.packed_io:
             return outs if isinstance(outs, tuple) else (outs,)
         result = []
@@ -1731,30 +1882,15 @@ class InferenceEngine:
         fetch — the DAG path, where the caller converted only the row
         slices it needed (via :meth:`device_outputs` + its own
         ``np.asarray``, accounted through :meth:`note_d2h`) and the bulky
-        padded outputs never cross to the host. Mirrors fetch_outputs'
-        finally block exactly: replica in-flight/busy accounting, the
-        economics cell, and the slab's pool-return."""
-        _outs, (n, slab, r, t_disp, bucket) = handle
+        padded outputs never cross to the host. Waits for the outputs to be
+        computed (the flight's device phase is what ``busy_s`` counts),
+        then closes it as :meth:`fetch_outputs` does."""
+        outs, (n, slab, r, flight, bucket) = handle
         rep = self._replicas[r]
-        busy = max(0.0, time.monotonic() - t_disp)
-        ekey = (canvas_side(slab.key[0]), bucket)
-        with self._route_lock:
-            rep.dispatches_inflight -= 1
-            rep.slab_bytes_inflight -= slab.total_bytes
-            rep.busy_s += busy
-            cell = rep.econ.get(ekey)
-            if cell is None:
-                cell = rep.econ[ekey] = [0, 0, 0, 0.0, 0.0]
-            cell[0] += 1
-            cell[1] += n
-            if getattr(slab, "is_ragged", False):
-                cell[2] += slab.rows_shipped(bucket)
-                cell[4] += slab.used / slab.row_bytes
-            else:
-                cell[2] += bucket
-                cell[4] += n
-            cell[3] += busy
-        slab.finish_fetch()
+        try:
+            self._flights(rep).land(flight, outs)
+        finally:
+            self._close_flight(rep, n, slab, bucket, flight)
 
     def dispatch_device(self, canvases, hws: np.ndarray,
                         replica: int | None = None, spans=()):
@@ -1768,7 +1904,6 @@ class InferenceEngine:
         :meth:`fetch_outputs` / :meth:`device_outputs` /
         :meth:`release_dispatch` all compose — a 3-stage DAG chains this
         method off its own device_outputs."""
-        t0 = time.monotonic() if spans else 0.0
         n = int(canvases.shape[0])
         row_shape = tuple(int(d) for d in canvases.shape[1:])
         bucket = self.pick_batch_bucket(n)
@@ -1797,17 +1932,18 @@ class InferenceEngine:
             rep.dispatches_total += 1
             rep.dispatches_inflight += 1
             rep.slab_bytes_inflight += slab.total_bytes
+        flights = self._flights(rep)
         try:
             with guard:
+                flight = flights.start(None, f"c{canvas_side(row_shape)} b{bucket}",
+                                       slab.total_bytes)
                 # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on — the guarded region is exactly the device enqueue; device_put here is a device-to-device reshard of the already-resident glue output)
-                batch_d = jax.device_put(batch, rep.data_sharding)
-                t_put = time.monotonic() if spans else 0.0
-                if self.cfg.packed_io:
-                    outs = serve(rep.params, batch_d)
-                else:
+                bufs = [jax.device_put(batch, rep.data_sharding)]
+                if not self.cfg.packed_io:
                     # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on)
-                    hws_d = jax.device_put(hws, rep.data_sharding)
-                    outs = serve(rep.params, batch_d, hws_d)
+                    bufs.append(jax.device_put(hws, rep.data_sharding))
+                flights.copying(flight, bufs)
+                outs = serve(rep.params, *bufs)
                 for leaf in jax.tree.leaves(outs):
                     leaf.copy_to_host_async()
         except BaseException:
@@ -1815,13 +1951,10 @@ class InferenceEngine:
                 rep.dispatches_inflight -= 1
                 rep.slab_bytes_inflight -= slab.total_bytes
             raise
-        t_disp = time.monotonic()
-        if spans:
-            for s in spans:
-                s.add_max("device_transfer", t_put - t0)
-                s.add_max("device_dispatch", t_disp - t_put)
-                s.note("replica", r)
-        return outs, (n, slab, r, t_disp, bucket)
+        flights.enqueued(flight)
+        for s in spans:
+            s.note("replica", r)
+        return outs, (n, slab, r, flight, bucket)
 
     def run_batch(self, canvases: np.ndarray, hws: np.ndarray,
                   replica: int | None = None) -> tuple[np.ndarray, ...]:
@@ -2045,6 +2178,8 @@ class InferenceEngine:
             rep.params = None
             rep.serve = None
             rep.exe.clear()
+            if rep.flights is not None:
+                rep.flights.close()
         self._params = None
         self._serve = None
         self._serve_raw = None

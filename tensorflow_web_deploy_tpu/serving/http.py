@@ -29,9 +29,10 @@ stdlib-only front end built for the serving hot path:
   ``X-Trace-Id``) and carries a Span (utils/tracing.py) through the whole
   path — header read, body read, slot lease (``lease_wait``),
   decode-into-slab (``image_decode``), staging commit (``staging_write``),
-  assembly wait (``queue_wait``), host→device ship (``device_transfer``),
-  execute enqueue (``device_dispatch``), device execute, postprocess,
-  serialize — stamped by this module, the batcher, and the engine.
+  assembly wait (``queue_wait``), the H2D copy (``device_transfer``), the
+  wait behind earlier calls plus the device's work (``device_execute``),
+  the D2H (``device_d2h``), postprocess, serialize — stamped by this
+  module, the batcher, and the engine.
 - **Content-addressed response cache + single-flight dedup** (serving/
   respcache.py, ``--cache-bytes``). Staging digests the upload's bytes
   (with the canvas bucket set and the wire) and consults the cache FIRST,
@@ -260,6 +261,37 @@ def _parse_multipart_files(body: bytes, content_type: str) -> list[tuple[str, by
     if not files and fallback is not None:
         return [fallback]
     return files
+
+
+def _lifecycle_metrics(p: PromText, life: dict, labels: dict) -> None:
+    """``/stats → batcher.lifecycle`` as Prometheus counters with the
+    model's labels, one family a unit: batches by seal reason, the phase
+    clocks (``open_s_total`` → ``phase="open"``), bytes each way, and every
+    other count (``stamps_late_total``, the counters the model's program
+    returns: ``tokens_real_total`` → ``counter="tokens_real"``)."""
+    for key, value in life.items():
+        if key == "by_reason":
+            for reason, n in value.items():
+                p.scalar("lifecycle_batches_total", n, mtype="counter",
+                         labels=dict(labels, reason=reason),
+                         help_="Batches launched, by why they sealed.")
+        elif key.endswith("_s_total"):
+            p.scalar("lifecycle_seconds_total", value, mtype="counter",
+                     labels=dict(labels, phase=key[:-len("_s_total")]),
+                     help_="Batches' seconds in each phase of their life "
+                     "(open, launch_wait, inflight; the flight's h2d, "
+                     "device_queue, device, d2h), and the starved and "
+                     "h2d_bound clocks.")
+        elif key.endswith("_bytes_total"):
+            p.scalar("lifecycle_bytes_total", value, mtype="counter",
+                     labels=dict(labels, direction=key[:-len("_bytes_total")]),
+                     help_="Bytes the batches copied, by direction.")
+        elif key.endswith("_total") and key != "batches_total":
+            p.scalar("lifecycle_counts_total", value, mtype="counter",
+                     labels=dict(labels, counter=key[:-len("_total")]),
+                     help_="Every other batcher.lifecycle count: window "
+                     "holds, unpack-kernel batches, late stamps, and what "
+                     "the model's program counts a call.")
 
 
 def _qs_last(qs: dict[str, list[str]], key: str) -> str | None:
@@ -613,9 +645,11 @@ class App:
                 # occupancy above.
                 snap["batcher"]["builders"] = batcher.builder_stats()
             if hasattr(batcher, "lifecycle_stats"):
-                # Where a batch's time went (open, launch wait, enqueue, in
-                # flight), why batches sealed, bytes each way, and how long
-                # no batch was launched at all: cumulative, read as deltas.
+                # Where a batch's time went (open, launch wait, in flight,
+                # and the flight's copy, wait behind earlier calls, device
+                # work and copy back), why batches sealed, bytes each way,
+                # and how long no batch was launched at all, or only copies
+                # flew: cumulative, read as deltas.
                 snap["batcher"]["lifecycle"] = batcher.lifecycle_stats()
         else:
             # Default model between versions (drained, or never adopted):
@@ -915,7 +949,7 @@ class App:
             p.scalar("model_inflight_requests", mv.inflight, labels=labels,
                      help_="HTTP requests currently holding this version.")
             # Per-replica placement attribution: in-flight dispatches, slab
-            # bytes on the wire/device, and cumulative dispatch→fetch busy
+            # bytes on the wire/device, and cumulative device-phase busy
             # seconds per {model, version, replica} — rate(busy_seconds)
             # over wall clock is each chip group's busy fraction, the
             # number loadgen's stage-utilization table renders per chip.
@@ -936,9 +970,11 @@ class App:
                          "in-flight batches (slab occupancy per replica).")
                 p.scalar("model_replica_busy_seconds_total",
                          rep["busy_s"], mtype="counter", labels=rl,
-                         help_="Cumulative dispatch-to-fetch seconds on "
-                         "this replica (interval sum; overlapped depth>1 "
-                         "batches can exceed wall clock).")
+                         help_="Cumulative device-phase seconds on this "
+                         "replica: each call from the device's turn to "
+                         "its outputs computed.")
+            if hasattr(mv.batcher, "lifecycle_stats"):
+                _lifecycle_metrics(p, mv.batcher.lifecycle_stats(), labels)
             self._econ_metrics(p, mv, peak_done)
         # Content-addressed response cache: aggregate counters/gauges plus
         # per-model usage labels — the observability half of the tentpole

@@ -9,11 +9,14 @@ upload's bytes with bucket set and wire + response-cache consult, before
 the lease and the decode), ``cache_wait`` (coalesced onto another request's
 in-flight computation for the same content key — single-flight dedup),
 ``staging_write`` (slot commit / fallback canvas copy),
-``queue_wait`` (commit → launch start), ``device_transfer`` (launch start →
-the ``device_put``s of the staged slab returned), ``device_preprocess``
-(ragged wire: enqueue of the unpack program), ``device_dispatch`` (execute
-enqueue + async D2H start), ``device_execute`` (launch end → outputs on
-host), ``postprocess``, ``serialize``. Under the pipelined batcher, one
+``queue_wait`` (commit → launch start), then the batch's flight as the
+engine stamps it (``serving/engine.py::Flight``): ``device_transfer``
+(launch start → the H2D copy landed), ``device_execute`` (copy landed →
+outputs computed: the wait behind earlier calls on the device, then the
+device's own work) and ``device_d2h`` (outputs computed → on the host);
+an engine that stamps no flight gets ``device_dispatch`` (launch → execute
+enqueued) and ``device_execute`` (→ outputs on host) instead. Then
+``postprocess``, ``serialize``. Under the pipelined batcher, one
 request's ``device_execute`` interval routinely overlaps ANOTHER
 request's ``image_decode``/``device_transfer`` — that concurrency is the
 point, and bench.py's ``pipeline`` block measures it from the batcher's
@@ -26,14 +29,15 @@ identities (``seq=``, ``rows=``, ``trace_id=``) as keyword arguments, which
 the profiler keeps as event stats. So a ``POST /debug/trace`` recording
 holds the program's own stages on every thread, next to the device's ops.
 An annotation's name is not always its span stage's: the engine's
-``twd.h2d`` / ``twd.unpack_enqueue`` / ``twd.serve_enqueue`` +
-``twd.d2h_start`` are what the batch's spans receive as ``device_transfer``
-/ ``device_preprocess`` / ``device_dispatch``, ``twd.fetch`` lies inside
-``device_execute``, and ``twd.await_batch`` / ``twd.seal_wait`` have no
-stage at all (another layer already stamps those intervals).
-``device_transfer`` is the *enqueue* of the host→device copy, not the copy:
-``jax.device_put`` returns before the bytes have crossed (PERF.md section 5
-has the chip's reading).
+``twd.h2d`` / ``twd.unpack_enqueue`` / ``twd.serve_enqueue`` /
+``twd.d2h_start`` time the *enqueues* (``jax.device_put`` returns before
+the bytes have crossed) and have no stage; ``twd.h2d_flight`` is the copy
+itself, opened as the ``device_put`` starts and closed by the thread that
+waited for it to land, so it ends where ``device_transfer`` does;
+``twd.fetch`` (the wait for the outputs, then their conversion) covers
+the end of ``device_execute`` and ``device_d2h``; ``twd.await_batch`` /
+``twd.seal_wait`` have no stage at all (another layer already stamps
+those intervals).
 
 A ``Span`` is created by the HTTP front end at request-accept time (or by
 the WSGI app itself for embedded callers), travels via the WSGI environ
@@ -232,8 +236,9 @@ def clock_marker() -> float:
 
 # Batch-record fields that ride a batch event's ``args`` beside the
 # identity ones (Batcher._hand_off names them all).
-_BATCH_ARGS = ("reason", "t_put", "t_pre", "t_fetch", "h2d_bytes",
-               "d2h_bytes", "trace_ids")
+_BATCH_ARGS = ("reason", "t_put", "t_pre", "t_h2d_done", "t_dev_start",
+               "t_ready", "late", "t_fetch", "h2d_bytes", "d2h_bytes",
+               "trace_ids")
 
 
 def _us(t: float) -> float:
@@ -291,7 +296,9 @@ def chrome_trace(models: list[dict], requests: list[tuple],
     stages: an ``assemble canvas=S`` track per canvas bucket (builder open
     → seal: the decode/commit window) and per-replica ``transfer``/
     ``execute``/``fetch`` tracks (launch → launched → done, and fetch
-    thread's turn → done). Bulk batches are tagged
+    thread's turn → done), beside the flight's ``copy`` (launch → the H2D
+    copy landed) and ``device`` (the device's turn → outputs computed)
+    tracks, where the engine stamped them. Bulk batches are tagged
     in the event name and args. ``requests`` is
     ``[(t0_mono, t_end_mono, span_dict)]`` (FlightRecorder.trace_records)
     — rendered as async events on a "requests" process so overlapping
@@ -345,6 +352,14 @@ def chrome_trace(models: list[dict], requests: list[tuple],
                 # moment it turned to this batch to the outputs on the host.
                 (f"replica {r} fetch", f"{tag}fetch b{rec.get('seq')}",
                  rec.get("t_fetch"), t_done),
+                # The flight's own phases: the copy in flight, and the
+                # device's work on the batch (the wait behind earlier calls
+                # lies between them).
+                (f"replica {r} copy", f"{tag}copy b{rec.get('seq')}",
+                 t_launch if rec.get("t_h2d_done") is not None else None,
+                 rec.get("t_h2d_done")),
+                (f"replica {r} device", f"{tag}device b{rec.get('seq')}",
+                 rec.get("t_dev_start"), rec.get("t_ready")),
             ]
             for tid, name, a, b in legs:
                 if a is None:

@@ -1,5 +1,6 @@
 """Batcher unit tests (SURVEY.md §4): max-batch, ordering, error isolation."""
 
+import queue
 import threading
 import time
 
@@ -495,10 +496,99 @@ def _union_s(intervals) -> float:
     return total
 
 
+class _Buf:
+    """A device array whose readiness the test (or FlightEngine's device
+    thread) controls."""
+
+    def __init__(self, done: bool = False):
+        self.done = threading.Event()
+        if done:
+            self.done.set()
+
+    def is_ready(self):
+        return self.done.is_set()
+
+    def block_until_ready(self):
+        assert self.done.wait(timeout=10)
+        return self
+
+
+class FlightEngine(FakeSlotEngine):
+    """FakeSlotEngine whose calls fly through the engine's real FlightLog:
+    each dispatch puts one input buffer and gets one output buffer back,
+    fakes whose readiness stands for the copy's end and the outputs'.
+
+    With ``dev_s`` set, a copy thread lands the copies one after another,
+    each ``copy_s`` after the later of its dispatch and the copy before (one
+    DMA stream), and a device thread runs the calls in order, each ``dev_s``
+    from the later of its copy's end and the call before. Without it, the
+    test releases each call's ``(copy, ready)`` pair from ``calls`` itself."""
+
+    supports_span_tracing = True
+
+    def __init__(self, bucket=2, dev_s=None, copy_s=0.0, fail_fetch=False):
+        super().__init__(bucket=bucket)
+        from tensorflow_web_deploy_tpu.serving.engine import FlightLog
+
+        self.log = FlightLog(threading.Lock(), "test-h2d-watch")
+        self.calls = queue.Queue()
+        self.dev_s, self.copy_s, self.fail_fetch = dev_s, copy_s, fail_fetch
+        if dev_s is not None:
+            self.copies = queue.Queue()
+            threading.Thread(target=self._copy, daemon=True).start()
+            threading.Thread(target=self._device, daemon=True).start()
+
+    def _copy(self):
+        while True:
+            copy = self.copies.get()
+            time.sleep(self.copy_s)
+            copy.done.set()
+
+    def _device(self):
+        while True:
+            copy, ready = self.calls.get()
+            copy.done.wait()
+            time.sleep(self.dev_s)
+            ready.done.set()
+
+    def dispatch_staged(self, slab, n, spans=(), rec=None):
+        handle = super().dispatch_staged(slab, n)
+        f = self.log.start(rec, "c8 b2", 1000)
+        copy, ready = _Buf(), _Buf()
+        self.log.copying(f, [copy])
+        self.log.enqueued(f)
+        if self.dev_s is not None:
+            self.copies.put(copy)
+        self.calls.put((copy, ready))
+        return handle, f, ready
+
+    def fetch_outputs(self, handle, rec=None):
+        handle, f, ready = handle
+        self.log.land(f, [ready])
+        if self.fail_fetch:
+            handle[0].finish_fetch()
+            raise RuntimeError("the outputs' copy failed")
+        return super().fetch_outputs(handle)
+
+
+PHASES = ("h2d_s_total", "device_queue_s_total", "device_s_total", "d2h_s_total")
+
+
+def _phases(rec):
+    return (rec["t_h2d_done"] - rec["t_launch"], rec["t_dev_start"] - rec["t_h2d_done"],
+            rec["t_ready"] - rec["t_dev_start"], rec["t_done"] - rec["t_ready"])
+
+
+def _settle(b):
+    deadline = time.monotonic() + 2
+    while b.inflight_batches and time.monotonic() < deadline:
+        time.sleep(0.001)
+
+
 def test_lifecycle_counters_never_decrease_and_reasons_sum_to_batches():
     """Read while a dozen batches of every seal reason but drain go by:
     each counter only grows, and the per-reason counts sum to the total."""
-    eng = FakeSlotEngine(bucket=4, delay_s=0.01)
+    eng = FlightEngine(bucket=4, dev_s=0.01, copy_s=0.005)
     b = Batcher(eng, max_batch=4, max_delay_ms=15, adaptive_delay=False, pipeline_depth=2)
     b.start()
     reads, stop = [b.lifecycle_stats()], threading.Event()
@@ -540,8 +630,10 @@ def test_starved_clock_plus_inflight_union_is_elapsed_time_and_phases_tile_a_bat
     """A scripted sequence: idle, one batch, idle, three batches that
     overlap in flight, idle. Between two reads of the block, the starved
     seconds plus the union of the batches' [t_launch, t_done] is the time
-    between the reads; and each batch's four phases sum to t_done - t_open."""
-    eng = FakeSlotEngine(bucket=2, delay_s=0.03)
+    between the reads; each batch's phases sum to t_done - t_open, and its
+    flight's four (copy, wait behind the calls before, device, copy back)
+    to t_done - t_launch, one by one and in the totals."""
+    eng = FlightEngine(bucket=2, dev_s=0.03, copy_s=0.01)
     b = Batcher(eng, max_batch=2, max_delay_ms=2, adaptive_delay=False, pipeline_depth=3)
     b.start()
     try:
@@ -555,10 +647,7 @@ def test_starved_clock_plus_inflight_union_is_elapsed_time_and_phases_tile_a_bat
         for f in futures:
             f.result(timeout=5)
         time.sleep(0.03)
-        # every batch is done once its last future resolved and _batch_done ran
-        deadline = time.monotonic() + 2
-        while b.inflight_batches and time.monotonic() < deadline:
-            time.sleep(0.001)
+        _settle(b)     # every batch is done once its last future resolved and _batch_done ran
         last = b.lifecycle_stats()
     finally:
         b.stop()
@@ -569,17 +658,234 @@ def test_starved_clock_plus_inflight_union_is_elapsed_time_and_phases_tile_a_bat
     starved = last["starved_s_total"] - first["starved_s_total"]
     assert starved + _union_s(flights) == pytest.approx(elapsed, abs=1e-3)
     assert _union_s(flights[1:]) < sum(z - a for a, z in flights[1:])     # they did overlap
-    # the four phases tile a batch, one by one and in the totals
-    phases = ("open_s_total", "launch_wait_s_total", "enqueue_s_total", "inflight_s_total")
+    # the phases tile a batch, one by one and in the totals
+    phases = ("open_s_total", "launch_wait_s_total", *PHASES)
     assert sum(last[k] - first[k] for k in phases) == pytest.approx(
         sum(r["t_done"] - r["t_open"] for r in recs), abs=1e-6)
     for r in recs:
-        assert (r["t_seal"] - r["t_open"]) + (r["t_launch"] - r["t_seal"]) \
-            + (r["t_launched"] - r["t_launch"]) + (r["t_done"] - r["t_launched"]) \
-            == pytest.approx(r["t_done"] - r["t_open"], abs=1e-9)
+        assert all(p >= 0 for p in _phases(r)), r
+        assert sum(_phases(r)) == pytest.approx(r["t_done"] - r["t_launch"], abs=1e-9)
         assert r["t_launched"] <= r["t_fetch"] <= r["t_done"]
-    fetch_wait = last["fetch_wait_s_total"] - first["fetch_wait_s_total"]
-    assert 4 * 0.03 <= fetch_wait <= last["inflight_s_total"] - first["inflight_s_total"] + 1e-9
+    for k, i in zip(PHASES, range(4)):
+        assert last[k] - first[k] == pytest.approx(sum(_phases(r)[i] for r in recs), abs=1e-9)
+    # the device ran four calls of 30 ms one after another; the three that
+    # came together waited behind each other
+    # (each stamp lands a thread's wake-up after its event: give the
+    # device phase 5 ms a call either way)
+    assert 4 * 0.025 <= last["device_s_total"] - first["device_s_total"] < 4 * 0.03 + 0.05
+    assert last["device_queue_s_total"] - first["device_queue_s_total"] >= 0.05
+    assert all(r["late"] == () for r in recs) and last["stamps_late_total"] == 0, [r["late"] for r in recs]
+
+
+def test_a_call_starts_on_the_device_when_its_copy_and_the_call_before_are_done():
+    """``t_dev_start`` is the later of the copy's end and the previous
+    call's ``t_ready``: batch 2's copy lands while batch 1 computes, so it
+    waits for batch 1; batch 3's lands after batch 2 is done, so it starts
+    with its copy. The test releases each event itself."""
+    eng = FlightEngine(bucket=1)
+    b = Batcher(eng, max_batch=1, max_delay_ms=1, adaptive_delay=False, pipeline_depth=3)
+    b.start()
+    try:
+        futures = [b.submit(_canvas(i), (1, 1)) for i in range(2)]
+        (copy1, ready1), (copy2, ready2) = eng.calls.get(timeout=5), eng.calls.get(timeout=5)
+        time.sleep(0.02)
+        copy1.done.set()
+        time.sleep(0.02)
+        copy2.done.set()         # lands while batch 1 is on the device
+        time.sleep(0.03)
+        ready1.done.set()
+        time.sleep(0.02)
+        ready2.done.set()
+        for f in futures:
+            f.result(timeout=5)
+        time.sleep(0.03)
+        f3 = b.submit(_canvas(3), (1, 1))
+        copy3, ready3 = eng.calls.get(timeout=5)
+        time.sleep(0.02)
+        copy3.done.set()
+        time.sleep(0.02)
+        ready3.done.set()
+        f3.result(timeout=5)
+        _settle(b)
+        life = b.lifecycle_stats()
+    finally:
+        b.stop()
+    r1, r2, r3 = b.batch_timeline()
+    assert r1["t_dev_start"] == r1["t_h2d_done"]                 # the device was free
+    assert r2["t_h2d_done"] < r1["t_ready"] == r2["t_dev_start"]  # it waited for batch 1
+    assert r2["t_dev_start"] - r2["t_h2d_done"] == pytest.approx(0.03, abs=0.015)
+    assert r3["t_h2d_done"] > r2["t_ready"] and r3["t_dev_start"] == r3["t_h2d_done"]
+    for r in (r1, r2, r3):
+        assert r["t_ready"] - r["t_dev_start"] == pytest.approx(0.02, abs=0.015) or r is r1
+        assert r["late"] == ()
+    assert life["device_queue_s_total"] == pytest.approx(r2["t_dev_start"] - r2["t_h2d_done"], abs=1e-9)
+
+
+def test_the_h2d_bound_clock_runs_only_while_a_copy_flies_and_the_device_has_no_call():
+    """Batch 1's copy flies 40 ms alone (the clock runs), then batch 1 is on
+    the device while batch 2's copy flies (it stops), then batch 2's copy
+    flies on alone after batch 1 is done (it runs again). The clock is the
+    measure of the copies' union less the device phases', from the stamps."""
+    eng = FlightEngine(bucket=1)
+    b = Batcher(eng, max_batch=1, max_delay_ms=1, adaptive_delay=False, pipeline_depth=3)
+    b.start()
+    try:
+        first = b.lifecycle_stats()
+        f1 = b.submit(_canvas(1), (1, 1))
+        copy1, ready1 = eng.calls.get(timeout=5)
+        time.sleep(0.04)
+        copy1.done.set()
+        f2 = b.submit(_canvas(2), (1, 1))
+        copy2, ready2 = eng.calls.get(timeout=5)
+        time.sleep(0.03)
+        ready1.done.set()
+        f1.result(timeout=5)
+        time.sleep(0.03)
+        copy2.done.set()
+        time.sleep(0.01)
+        ready2.done.set()
+        f2.result(timeout=5)
+        _settle(b)
+        last = b.lifecycle_stats()
+    finally:
+        b.stop()
+    r1, r2 = b.batch_timeline()
+    copies = [(r["t_launch"], r["t_h2d_done"]) for r in (r1, r2)]
+    device = [(r["t_dev_start"], r["t_ready"]) for r in (r1, r2)]
+    # copies less device phases: [t_launch1, t_h2d1] and [t_ready1, t_h2d2]
+    want = (r1["t_h2d_done"] - r1["t_launch"]) + (r2["t_h2d_done"] - r1["t_ready"])
+    assert _union_s(copies) - _union_s([(max(a, c), min(b_, d)) for a, b_ in copies
+                                        for c, d in device if max(a, c) < min(b_, d)]) \
+        == pytest.approx(want, abs=1e-9)
+    bound = last["h2d_bound_s_total"] - first["h2d_bound_s_total"]
+    assert bound == pytest.approx(want, abs=1e-9)
+    assert 0.04 + 0.03 <= bound < last["h2d_s_total"] - first["h2d_s_total"]
+
+
+def test_a_stamp_taken_after_its_event_is_counted_late():
+    """A copy that had landed before the watcher turned to it, and outputs
+    computed before the completion thread turned to them: both stamps are
+    upper bounds, named in the record and counted."""
+    eng = FlightEngine(bucket=1)
+    b = Batcher(eng, max_batch=1, max_delay_ms=1, adaptive_delay=False)
+    b.start()
+    try:
+        f = b.submit(_canvas(1), (1, 1))
+        copy, ready = eng.calls.get(timeout=5)
+        copy.done.set()
+        time.sleep(0.03)
+        f2 = b.submit(_canvas(2), (1, 1))   # on time: the threads were waiting
+        copy2, ready2 = eng.calls.get(timeout=5)
+        time.sleep(0.02)
+        copy2.done.set()
+        time.sleep(0.02)
+        ready.done.set()
+        f.result(timeout=5)
+        time.sleep(0.02)
+        ready2.done.set()
+        f2.result(timeout=5)
+        _settle(b)
+        life = b.lifecycle_stats()
+    finally:
+        b.stop()
+    r1, r2 = b.batch_timeline()
+    assert r2["late"] == ()
+    # the watcher needs no time to see a landed copy: the first copy is late
+    # only if it had landed when the watcher turned to it, which the test
+    # cannot force; the count is the records' own
+    assert life["stamps_late_total"] == len(r1["late"]) + len(r2["late"])
+
+    # a copy landed and outputs computed before anyone asked: both late
+    class AtOnce(FlightEngine):
+        def dispatch_staged(self, slab, n, spans=(), rec=None):
+            handle = FakeSlotEngine.dispatch_staged(self, slab, n)
+            f = self.log.start(rec, "c8 b1", 1000)
+            self.log.copying(f, [_Buf(done=True)])
+            self.log.enqueued(f)
+            time.sleep(0.05)
+            return handle, f, _Buf(done=True)
+
+    b2 = Batcher(AtOnce(bucket=1), max_batch=1, max_delay_ms=1, adaptive_delay=False)
+    b2.start()
+    try:
+        b2.submit(_canvas(1), (1, 1)).result(timeout=5)
+        _settle(b2)
+        life2 = b2.lifecycle_stats()
+    finally:
+        b2.stop()
+    (rec,) = b2.batch_timeline()
+    assert set(rec["late"]) == {"t_h2d_done", "t_ready"} and life2["stamps_late_total"] == 2
+    assert sum(_phases(rec)) == pytest.approx(rec["t_done"] - rec["t_launch"], abs=1e-9)
+
+
+@pytest.mark.parametrize("where", ["dispatch", "fetch"])
+def test_a_failed_dispatch_or_fetch_adds_to_no_phase(where):
+    """A batch whose dispatch raised, or whose fetch raised after its
+    outputs were computed, counts as a batch and adds to none of the four
+    phases or the h2d-bound clock."""
+    eng = FlightEngine(bucket=1, dev_s=0.01, copy_s=0.01, fail_fetch=where == "fetch")
+    if where == "dispatch":
+        def no_device(slab, n, spans=(), rec=None):
+            raise RuntimeError("no device")
+        eng.dispatch_staged = no_device
+    b = Batcher(eng, max_batch=1, max_delay_ms=1, adaptive_delay=False)
+    b.start()
+    try:
+        first = b.lifecycle_stats()
+        with pytest.raises(RuntimeError):
+            b.submit(_canvas(1), (1, 1)).result(timeout=5)
+        _settle(b)
+        last = b.lifecycle_stats()
+    finally:
+        b.stop()
+    (rec,) = b.batch_timeline()
+    assert last["batches_total"] == 1
+    for k in (*PHASES, "h2d_bound_s_total", "stamps_late_total"):
+        assert last[k] == first[k], k
+    assert (rec["t_ready"] is not None) == (where == "fetch")
+
+
+def test_a_flight_log_stamps_the_calls_before_a_landed_one_late():
+    """The device runs its calls in order: when call 2's outputs are
+    computed and call 1 is unstamped yet (its thread has not run), call 1 is
+    stamped then, late, and so is a copy whose thread has not stamped it."""
+    from tensorflow_web_deploy_tpu.serving.engine import FlightLog
+
+    log = FlightLog(threading.Lock(), "test-h2d-watch")
+    recs = [{"seq": i, "rows": 1, "t_h2d_done": None, "t_dev_start": None,
+             "t_ready": None, "late": ()} for i in (1, 2)]
+    flights = [log.start(r, "c8 b1", 10) for r in recs]
+    bufs = [(_Buf(), _Buf()) for _ in flights]
+    for f, (copy, _) in zip(flights, bufs):
+        log.copying(f, [copy])
+        log.enqueued(f)
+    time.sleep(0.02)        # the copies' threads are waiting
+    bufs[0][0].done.set()
+    time.sleep(0.02)
+    assert recs[0]["t_h2d_done"] is not None and recs[0]["late"] == ()
+    bufs[1][0].done.set()
+    bufs[0][1].done.set()
+    bufs[1][1].done.set()
+    log.land(flights[1], [bufs[1][1]])
+    log.land(flights[0], [bufs[0][1]])       # already stamped by call 2's landing
+    one, two = recs
+    assert one["t_ready"] == two["t_ready"] and "t_ready" in one["late"]
+    assert two["t_dev_start"] == max(two["t_h2d_done"], one["t_ready"])
+    assert "t_ready" in two["late"]          # its outputs were computed before land() asked
+    assert flights[0].prev is None and flights[1].prev is None
+    # copies land out of order: each has a thread of its own, so neither is late
+    late_first = [log.start({"seq": i, "rows": 1, "t_h2d_done": None, "t_dev_start": None,
+                             "t_ready": None, "late": ()}, "c8 b1", 10) for i in (3, 4)]
+    copies = [_Buf(), _Buf()]
+    for f, copy in zip(late_first, copies):
+        log.copying(f, [copy])
+    time.sleep(0.02)
+    copies[1].done.set()
+    time.sleep(0.02)
+    copies[0].done.set()
+    time.sleep(0.02)
+    assert late_first[0].late == late_first[1].late == ()
+    assert late_first[1].t_h2d_done < late_first[0].t_h2d_done
 
 
 @pytest.mark.parametrize("says", [None, "odd", "all"])
@@ -766,3 +1072,61 @@ def test_a_builder_past_its_window_goes_before_full_batches_opened_after_it(ceil
         (8, 1, "window"), (8, 1, "window"), (16, 2, "full"), (16, 2, "full"), (16, 2, "full")]
     assert life["by_reason"] == {"full": 3, "arena": 0, "window": 2, "flush": 0, "drain": 0}
     assert (life["window_holds_total"] > 0) == (ceiling is not None)
+
+
+def test_a_flight_log_under_contention_stamps_each_flight_once_and_in_device_order():
+    """Stress: 8 threads land 200 calls of one FlightLog in a shuffled order
+    while the copies' threads stamp them, with a tiny switch interval. A
+    lost or doubled stamp would break the invariants: each record agrees
+    with its flight, every call starts on the device no earlier than its
+    copy's end and the previous call's ``t_ready``, and readiness stamps
+    run in device order."""
+    import random
+    import sys
+
+    from tensorflow_web_deploy_tpu.serving.engine import FlightLog
+
+    rng = random.Random(7)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    log = FlightLog(threading.Lock(), "test-h2d-watch")
+    try:
+        recs, flights, outs = [], [], []
+        for i in range(200):
+            rec = {"seq": i, "rows": 1, "t_h2d_done": None, "t_dev_start": None, "t_ready": None, "late": ()}
+            f = log.start(rec, "c8 b1", 10)
+            copy, ready = _Buf(), _Buf()
+            log.copying(f, [copy])
+            log.enqueued(f)
+            recs.append(rec), flights.append(f), outs.append((copy, ready))
+        order = list(range(200))
+        rng.shuffle(order)
+        work = iter(order)
+        lock = threading.Lock()
+
+        def lander():
+            while True:
+                with lock:
+                    i = next(work, None)
+                if i is None:
+                    return
+                outs[i][0].done.set()
+                outs[i][1].done.set()
+                log.land(flights[i], [outs[i][1]])
+
+        threads = [threading.Thread(target=lander) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(switch)
+    for i, (rec, f) in enumerate(zip(recs, flights)):
+        assert (rec["t_h2d_done"], rec["t_dev_start"], rec["t_ready"], rec["late"]) == \
+            (f.t_h2d_done, f.t_dev_start, f.t_ready, f.late)
+        assert rec["t_h2d_done"] <= rec["t_dev_start"] <= rec["t_ready"]
+        assert len(set(f.late)) == len(f.late) and f.prev is None
+        if i:
+            assert rec["t_dev_start"] == max(rec["t_h2d_done"], recs[i - 1]["t_ready"])
+            assert recs[i - 1]["t_ready"] <= rec["t_ready"]

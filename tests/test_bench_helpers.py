@@ -62,9 +62,11 @@ def test_preprocess_bench(tiny_engine):
 
 
 def test_dispatch_stamps_transfer_split_and_inflight_accounting(tiny_engine):
-    """The pipelined dispatch split: device_transfer (host→device ship)
-    and device_dispatch (execute enqueue) are stamped separately, and the
-    engine counts dispatched-but-unfetched batches."""
+    """The pipelined dispatch split: the batch's record gets the copy's
+    end, the device's turn and the outputs' readiness stamped in order
+    (the spans' device stages are the batcher's, from these), the span its
+    replica, and the engine counts dispatched-but-unfetched batches and the
+    device phase as the replica's busy time."""
     from tensorflow_web_deploy_tpu.utils.tracing import Span
 
     row_shape = tiny_engine.canvas_shape(1, 48)[1:]
@@ -73,13 +75,18 @@ def test_dispatch_stamps_transfer_split_and_inflight_accounting(tiny_engine):
         np.zeros((4, *row_shape), np.uint8), np.full((4, 2), 48, np.int32)
     )
     span = Span("pipe-split")
-    handle = tiny_engine.dispatch_staged(slab, 4, spans=[span])
+    rec = {"seq": 1, "rows": 4, "t_put": None, "t_h2d_done": None, "t_dev_start": None,
+           "t_ready": None, "late": (), "h2d_bytes": None, "d2h_bytes": None}
+    busy0 = tiny_engine.staging_stats()["replicas"][0]["busy_s"]
+    handle = tiny_engine.dispatch_staged(slab, 4, spans=[span], rec=rec)
     stats = tiny_engine.staging_stats()
     assert stats["dispatches_inflight"] == 1
-    tiny_engine.fetch_outputs(handle)
+    tiny_engine.fetch_outputs(handle, rec=rec)
     stats = tiny_engine.staging_stats()
     assert stats["dispatches_inflight"] == 0
     assert stats["dispatches_total"] >= 1
-    assert "device_transfer" in span.stages
-    assert "device_dispatch" in span.stages
-    assert all(v >= 0 for v in span.stages.values())
+    assert span.meta["replica"] == 0 and span.stages == {}
+    assert rec["t_put"] <= rec["t_h2d_done"] <= rec["t_dev_start"] <= rec["t_ready"]
+    assert rec["h2d_bytes"] > 0 and rec["d2h_bytes"] > 0
+    busy = stats["replicas"][0]["busy_s"] - busy0
+    assert busy == pytest.approx(rec["t_ready"] - rec["t_dev_start"], abs=1e-3)

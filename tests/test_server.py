@@ -617,8 +617,8 @@ def test_span_stages_cover_end_to_end_latency(cls_server, rng):
     stages = span["stages_ms"]
     assert len(stages) >= 8, f"expected >= 8 stages, got {sorted(stages)}"
     assert {"http_read", "body_read", "image_decode", "queue_wait",
-            "staging_write", "device_dispatch", "device_execute",
-            "postprocess", "serialize"} <= set(stages)
+            "staging_write", "device_transfer", "device_execute",
+            "device_d2h", "postprocess", "serialize"} <= set(stages)
     total = span["total_ms"]
     assert total > 0
     assert sum(stages.values()) >= 0.8 * total, (stages, total)

@@ -151,25 +151,33 @@ def test_a_recording_joins_batches_spans_and_annotations_on_one_clock(server, tm
               and r["t_done"] and r["t_done"] <= profile["t_stop"]]
     assert len(inside) >= 3, profile["batches"]
     by_seq = {name: {st["seq"]: (start / 1e9 + offset_s, dur / 1e9) for start, dur, st in events[name]}
-              for name in ("twd.h2d", "twd.unpack_enqueue", "twd.serve_enqueue", "twd.d2h_start", "twd.fetch")}
+              for name in ("twd.h2d", "twd.h2d_flight", "twd.unpack_enqueue", "twd.serve_enqueue",
+                           "twd.d2h_start", "twd.fetch")}
+    flight_stats = {st["seq"]: st for _, _, st in events["twd.h2d_flight"]}
     late = []
     for rec in inside:
         seq = rec["seq"]
         h2d_start, h2d_dur = by_seq["twd.h2d"][seq]
         fetch_start, fetch_dur = by_seq["twd.fetch"][seq]
+        # the copy's own annotation: opened as the device_put starts, closed
+        # where the copy's end is stamped, by the thread that waited for it
+        flight_start, flight_dur = by_seq["twd.h2d_flight"][seq]
+        assert flight_start <= h2d_start + 1e-4
+        assert flight_stats[seq]["h2d_bytes"] == rec["h2d_bytes"] and flight_stats[seq]["rows"] == rec["rows"]
         # Through the offset an annotation and the record's stamp beside it
         # agree within 5 ms. Between the two clock reads lie a few lines of
         # Python: on a loaded machine the interpreter may hand the thread's
         # turn away there (its switch interval is 5 ms), so one batch of a
         # recording may be late; a wrong offset would move them all.
         for apart in (h2d_start - rec["t_launch"], h2d_start + h2d_dur - rec["t_put"],
-                      fetch_start - rec["t_fetch"]):
+                      fetch_start - rec["t_fetch"], flight_start + flight_dur - rec["t_h2d_done"]):
             assert -1e-4 < apart, (rec, apart)
             if apart >= 5e-3:
                 late.append((seq, apart))
         assert fetch_start + fetch_dur <= rec["t_done"] + 1e-3
         assert rec["t_open"] <= rec["t_seal"] <= rec["t_launch"] <= rec["t_put"] <= rec["t_pre"] \
             <= rec["t_launched"] <= rec["t_done"] and rec["t_launched"] <= rec["t_fetch"] <= rec["t_done"]
+        assert rec["t_launch"] <= rec["t_h2d_done"] <= rec["t_dev_start"] <= rec["t_ready"] <= rec["t_done"]
         assert rec["reason"] in SEAL_REASONS
         assert rec["h2d_bytes"] > 0 and rec["d2h_bytes"] > 0
         assert seq in by_seq["twd.unpack_enqueue"] and seq in by_seq["twd.serve_enqueue"] \
@@ -202,7 +210,15 @@ def test_a_recording_joins_batches_spans_and_annotations_on_one_clock(server, tm
     _, _, raw = _http(port, "GET", "/debug/trace?last_s=30")
     doc = json.loads(raw)
     fetch_legs = [e for e in doc["traceEvents"] if e.get("cat") == "batch" and " fetch" in e["tid"]]
-    assert fetch_legs and all({"reason", "h2d_bytes", "trace_ids", "t_put"} <= set(e["args"]) for e in fetch_legs)
+    assert fetch_legs and all({"reason", "h2d_bytes", "trace_ids", "t_put", "t_h2d_done", "t_ready"}
+                              <= set(e["args"]) for e in fetch_legs)
+    # the copy and the device phase are drawn as intervals of each batch
+    for leg, a, z in (("copy", "t_launch", "t_h2d_done"), ("device", "t_dev_start", "t_ready")):
+        drawn = [e for e in doc["traceEvents"] if e.get("cat") == "batch" and e["tid"].endswith(f" {leg}")]
+        assert drawn and all(e["ts"] == pytest.approx(e["args"][a] * 1e6, abs=0.1) if a in e["args"] else True
+                             for e in drawn)
+        assert all(e["dur"] == pytest.approx(max(0.1, (e["args"][z] - e["args"].get(a, e["ts"] / 1e6)) * 1e6),
+                                              abs=0.3) for e in drawn)
     begun = [e for e in doc["traceEvents"] if e.get("ph") == "b" and e["args"].get("trace_id") in ids]
     assert begun and all(e["args"]["batches"] for e in begun)
 
@@ -270,9 +286,9 @@ def test_scopes_name_the_phases_and_the_modules_keep_their_names(server):
 
 
 def test_trace_batches_reads_a_recording_and_names_what_lies_over_a_gap(server, tmp_path, capsys):
-    """tools/trace_batches.py on a CPU recording (clock and annotations; the
-    CPU has no device plane, so no gaps), then its gap and H2D tables on
-    hand-made device lines."""
+    """tools/trace_batches.py on a CPU recording (clock, annotations and the
+    batches' stamped phases; the CPU has no device plane, so no gaps and no
+    programs), then its gap and join tables on hand-made device lines."""
     from tools import trace_batches as T
 
     port, _ = server
@@ -284,7 +300,12 @@ def test_trace_batches_reads_a_recording_and_names_what_lies_over_a_gap(server, 
     doc = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert doc["clock"]["markers"] == 2 and doc["clock"]["offset_drift_s"] < 1e-3
     assert 0.14 < doc["clock"]["recorded_s"] < 1.0
-    assert doc["twd_events"] > 20 and doc["idle_gaps"] == [] and doc["h2d"] == []
+    assert doc["twd_events"] > 20 and doc["idle_gaps"] == [] and doc["unjoined"] == 0
+    profile = json.loads(raw)["profile"]
+    stamped = {b["seq"] for b in profile["batches"] if b["t_ready"] is not None}
+    assert stamped and {r["seq"] for r in doc["batches"]} == stamped
+    for r in doc["batches"]:
+        assert r["programs"] == [] and min(r["h2d_ms"], r["device_queue_ms"], r["device_ms"], r["d2h_ms"]) >= 0
 
     # device busy 0-10 ms and 60-70 ms: one gap of 50 ms, under batch 7's fetch and a seal wait
     twd = sorted([(0.001, 0.004, "twd.h2d c4096 b32", {"seq": 7, "rows": 30}),
@@ -300,8 +321,66 @@ def test_trace_batches_reads_a_recording_and_names_what_lies_over_a_gap(server, 
     assert [(r["name"], r["seq"]) for r in gap["batch_spans"]] == [("twd.fetch c4096 b32", 7)]
     assert [r["name"] for r in gap["under"]] == ["twd.fetch c4096 b32", "twd.seal_wait c4096"]
     assert gap["under"][0]["share"] == 1.0 and gap["under"][1]["share"] == pytest.approx(0.4)
-    modules = [(0.0005, 0.0009, "jit__lambda(3)"),          # a batch enqueued before the recording
-               (0.0300, 0.0600, "jit__lambda(3)"), (0.0600, 0.0700, "jit_serve(5)")]
-    (row,) = T.h2d(twd, modules)
-    assert row["seq"] == 7 and row["h2d_ms"] == pytest.approx(3.0)
-    assert row["h2d_end_to_unpack_start_ms"] == pytest.approx(26.0) and row["unpack_ms"] == pytest.approx(30.0)
+    # each program joins the batch whose outputs' stamp is the first at or
+    # after its end: batch 6 ends with the recording's first serve, batch 7
+    # with the second; a program after the last stamp joins none
+    modules = [(0.0005, 0.0009, "jit_serve(5)"),
+               (0.0300, 0.0600, "jit__lambda(3)"), (0.0600, 0.0700, "jit_serve(5)"),
+               (0.0800, 0.0850, "jit__lambda(3)")]
+    stamps = {"t_launch": 4.95, "t_h2d_done": 4.96, "t_dev_start": 4.98, "t_ready": 5.0012, "t_done": 5.002,
+              "late": ()}
+    batches = [{"seq": 6, **stamps},
+               {**batches[0], "t_h2d_done": 5.004, "t_dev_start": 5.030, "t_ready": 5.0701, "t_done": 5.072,
+                "late": ("t_ready",)},
+               {"seq": 8, "t_launch": 5.2, "t_ready": None}]
+    rows, unjoined = T.join(modules, T.clock(twd)["offset_s"], batches)
+    assert unjoined == 1 and [r["seq"] for r in rows] == [6, 7]
+    six, seven = rows
+    assert [p["name"] for p in six["programs"]] == ["jit_serve(5)"] and six["stamp_lag_ms"] == pytest.approx(0.3)
+    assert [(p["name"], p["ms"]) for p in seven["programs"]] == [("jit__lambda(3)", 30.0), ("jit_serve(5)", 10.0)]
+    assert seven["stamp_lag_ms"] == pytest.approx(0.1) and seven["late"] == ["t_ready"]
+    assert (seven["h2d_ms"], seven["device_queue_ms"], seven["device_ms"], seven["d2h_ms"]) == \
+        pytest.approx((3.0, 26.0, 40.1, 1.9))
+
+
+def test_metrics_carry_the_lifecycle_counters(server):
+    """``GET /metrics`` exports ``/stats -> batcher.lifecycle`` with the
+    model's labels: batches by reason, the phase clocks (the flight's four
+    among them), bytes each way and the other counts, late stamps too."""
+    import re
+
+    port, _ = server
+    rng = np.random.RandomState(9)
+    assert _http(port, "POST", "/predict", *_multipart([_jpeg(rng, 40, 50)]))[0] == 200
+    _, _, raw = _http(port, "GET", "/stats")
+    life = json.loads(raw)["batcher"]["lifecycle"]
+    _, _, text = _http(port, "GET", "/metrics")
+    text = text.decode()
+    samples = {}   # (family, the label that is not the model's) -> value
+    for line in text.splitlines():
+        m = re.match(r"tpu_serve_lifecycle_(\w+)\{(.*)\} (\S+)$", line)
+        if m:
+            labels = dict(re.findall(r'(\w+)="([^"]*)"', m[2]))
+            assert (labels.pop("model"), labels.pop("version")) == ("mobilenet_v2", "1")
+            ((_, what),) = labels.items()
+            samples[m[1], what] = float(m[3])
+    assert "# TYPE tpu_serve_lifecycle_seconds_total counter" in text
+    for phase in ("open", "launch_wait", "inflight", "h2d", "device_queue", "device", "d2h", "h2d_bound",
+                  "starved"):
+        assert ("seconds_total", phase) in samples, phase
+    assert samples["seconds_total", "h2d"] >= life["h2d_s_total"] > 0
+    assert samples["bytes_total", "h2d"] >= life["h2d_bytes_total"] > 0
+    reasons = {k: v for k, v in samples.items() if k[0] == "batches_total"}
+    assert len(reasons) == len(SEAL_REASONS) and sum(reasons.values()) >= life["batches_total"] > 0
+    for counter in ("stamps_late", "window_holds", "unpack_kernel_batches"):
+        assert ("counts_total", counter) in samples, counter
+    # what a decoder's program counts a call rides the same family
+    from tensorflow_web_deploy_tpu.serving.http import _lifecycle_metrics
+    from tensorflow_web_deploy_tpu.utils.metrics import PromText
+
+    p = PromText()
+    _lifecycle_metrics(p, {"tokens_real_total": 7.0, "answer_steps_cached_total": 15.0, "now_s": 1.0},
+                       {"model": "nemotron_h", "version": 1})
+    assert p.render().splitlines()[2:] == [
+        'tpu_serve_lifecycle_counts_total{counter="tokens_real",model="nemotron_h",version="1"} 7',
+        'tpu_serve_lifecycle_counts_total{counter="answer_steps_cached",model="nemotron_h",version="1"} 15']

@@ -1150,9 +1150,9 @@ def replica_utilization(stats_before: dict | None, stats_after: dict | None,
                         wall_s: float) -> list[dict]:
     """Per-chip busy fractions from the default model's ``/stats``
     "staging" replicas block (placement routing): each replica's
-    dispatch→fetch ``busy_s`` delta over the window ÷ wall, capped at 1.0
-    (pipeline depth > 1 overlaps a replica's own batches, so the interval
-    sum can exceed wall clock). Empty for single-stream placements —
+    device-phase ``busy_s`` delta over the window ÷ wall, capped at 1.0
+    (the two reads need not fall where a call's phase does). Empty for
+    single-stream placements —
     there is nothing to disperse."""
     after = ((stats_after or {}).get("staging") or {}).get("replicas") or []
     if len(after) < 2 or not wall_s or wall_s <= 0:

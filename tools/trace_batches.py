@@ -14,11 +14,16 @@ JSON object:
   gap covered: ``under`` the longest overlaps, ``batch_spans`` those that name
   a batch) and, where ``--stats`` gives ``/stats -> profile``, the batches
   that stood between ``t_launch`` and ``t_done`` during it (``launched``);
-- ``h2d``: per batch ``seq``, the ``twd.h2d`` annotation (the ``device_put``s)
-  beside the start of that batch's unpack program on the device: if the
-  program starts later than ``twd.h2d`` ends by about the copy's length, the
-  annotation (and the ``device_transfer`` stage) times the enqueue, not the
-  copy.
+- ``batches``: where ``--stats`` gives ``/stats -> profile``, each batch
+  whose outputs were computed inside the recording, with the phases of its
+  flight as the program stamped them (``h2d_ms``, ``device_queue_ms``,
+  ``device_ms``, ``d2h_ms``; ``late`` names an upper bound) and the device
+  programs it ran: every ``XLA Modules`` event is joined to the batch whose
+  ``t_ready`` is the first at or after its end, on the ``twd.clock`` offset
+  (a device runs its calls in order, and ``t_ready`` is stamped by a thread
+  that was waiting for that call). ``stamp_lag_ms`` is how long after its
+  last program's end the stamp came; ``unjoined`` counts the programs that
+  end after the last stamp of the recording.
 
 The benchmark's ``xplane.py`` names a gap by whichever host event covers most
 of it; this tool looks at ``twd.`` events alone and keeps their stats.
@@ -27,6 +32,7 @@ of it; this tool looks at ``twd.`` events alone and keeps their stats.
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
 import sys
 from pathlib import Path
@@ -35,7 +41,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.xplane import MODULES_LINE, OPS_LINE, union  # noqa: E402
 
-UNPACK_MODULE = "jit__lambda"
+# A program's end on the device and the stamp of its outputs on the host lie
+# on one clock; the stamp comes after the end, give or take the planes' skew.
+STAMP_SLACK_S = 5e-4
 
 
 def load(path: Path) -> tuple[list[tuple], list[tuple], list[tuple]]:
@@ -101,27 +109,40 @@ def idle_gaps(twd, ops, top: int, offset_s: float | None, batches: list[dict]) -
     return rows
 
 
-def h2d(twd, modules) -> list[dict]:
-    """Each batch's ``twd.h2d`` beside its unpack program's start on the
-    device. The device runs programs in the order they were enqueued, so the
-    unpack calls that begin after the first ``twd.unpack_enqueue`` of the
-    recording pair off with the enqueues in order."""
-    puts = {st["seq"]: (s, e) for s, e, name, st in twd if name.startswith("twd.h2d") and "seq" in st}
-    enqueues = sorted((s, st["seq"]) for s, _, name, st in twd
-                      if name.startswith("twd.unpack_enqueue") and "seq" in st)
-    if not enqueues:
-        return []
-    runs = [(s, e) for s, e, name in modules if name.startswith(UNPACK_MODULE) and s >= enqueues[0][0]]
-    rows = []
-    for (enq_s, seq), (run_s, run_e) in zip(enqueues, runs):
-        if seq not in puts:
+def join(modules, offset_s: float | None, batches: list[dict]) -> tuple[list[dict], int]:
+    """(the batches whose ``t_ready`` lies in the recording, each with the
+    programs joined to it; how many programs joined none)."""
+    if offset_s is None:
+        return [], 0
+    stamped = sorted(((b["t_ready"] - offset_s, b) for b in batches if b.get("t_ready") is not None),
+                     key=lambda x: x[0])
+    lo = modules[0][0] if modules else float("-inf")
+    rows = {b["seq"]: _phases(b) for at, b in stamped if at >= lo}
+    ends = [at for at, _ in stamped]
+    unjoined = 0
+    for s, e, name in modules:
+        i = bisect.bisect_left(ends, e - STAMP_SLACK_S)
+        if i == len(ends):
+            unjoined += 1
             continue
-        put_s, put_e = puts[seq]
-        rows.append({"seq": seq, "h2d_ms": round(1e3 * (put_e - put_s), 3),
-                     "h2d_end_to_unpack_start_ms": round(1e3 * (run_s - put_e), 3),
-                     "h2d_start_to_unpack_start_ms": round(1e3 * (run_s - put_s), 3),
-                     "unpack_ms": round(1e3 * (run_e - run_s), 3)})
-    return rows
+        at, b = stamped[i]
+        row = rows.get(b["seq"])
+        if row is None:
+            continue
+        row["programs"].append({"name": name, "start_s": round(s, 6), "ms": round(1e3 * (e - s), 3)})
+        row["stamp_lag_ms"] = round(1e3 * (at - e), 3)
+    return list(rows.values()), unjoined
+
+
+def _phases(b: dict) -> dict:
+    """A batch record's flight as the program stamped it, in ms."""
+    def ms(a, z):
+        return None if b.get(z) is None else round(1e3 * (b[z] - b[a]), 3)
+
+    return {"seq": b["seq"], "rows": b.get("rows"), "h2d_ms": ms("t_launch", "t_h2d_done"),
+            "device_queue_ms": ms("t_h2d_done", "t_dev_start"), "device_ms": ms("t_dev_start", "t_ready"),
+            "d2h_ms": ms("t_ready", "t_done"), "late": list(b.get("late") or ()), "programs": [],
+            "stamp_lag_ms": None}
 
 
 def main(argv=None) -> int:
@@ -136,13 +157,10 @@ def main(argv=None) -> int:
         doc = json.loads(args.stats.read_text())
         batches = (doc.get("profile") or doc).get("batches", [])
     ck = clock(twd)
-    by_seq = {b["seq"]: b for b in batches}
-    rows = h2d(twd, modules)
-    for r in rows:
-        if by_seq.get(r["seq"], {}).get("h2d_bytes"):
-            r["h2d_mb"] = round(by_seq[r["seq"]]["h2d_bytes"] / 1e6, 1)
+    rows, unjoined = join(modules, ck["offset_s"], batches)
     print(json.dumps({"clock": ck, "twd_events": len(twd), "device_ops": len(ops),
-                      "idle_gaps": idle_gaps(twd, ops, args.top, ck["offset_s"], batches), "h2d": rows}))
+                      "idle_gaps": idle_gaps(twd, ops, args.top, ck["offset_s"], batches),
+                      "batches": rows, "unjoined": unjoined}))
     return 0
 
 
