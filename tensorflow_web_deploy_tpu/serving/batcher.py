@@ -44,6 +44,11 @@ a CPU pipeline:
     completion pool   blocks on outputs, resolves futures; postprocess/
                       serialize then run on the awaiting HTTP workers
 
+On a ragged arena that ships by pages (a replica of one device,
+engine.RaggedSlab), the copy starts before the seal: each slot that leaves
+PENDING settles, and every page it completes goes to the engine's shipper
+thread at once (``_settle_locked``), so the launch copies only the tail.
+
 ``pipeline_depth`` bounds dispatched-but-unfetched batches PER canvas
 bucket (sealed batches of one row shape can't starve another's), and the
 sealer blocks on the condition variable at the cap — a bucket's one open
@@ -231,7 +236,7 @@ class _Builder:
 
     __slots__ = ("key", "slab", "capacity", "leases", "opened_at", "deadline",
                  "accepting", "dispatched", "n_pending", "n_ready", "n_holes",
-                 "replica", "bulk", "tenant", "reason")
+                 "replica", "bulk", "tenant", "reason", "seq")
 
     def __init__(self, key, slab, capacity: int, deadline: float,
                  bulk: bool = False):
@@ -259,6 +264,10 @@ class _Builder:
         # moment the batch takes its pipeline-depth slot (0 for engines
         # without replica routing).
         self.replica = 0
+        # The batch's seq, taken when its first arena page is handed to the
+        # engine's shipper (so the early copies' annotations carry it), else
+        # at hand-off.
+        self.seq: int | None = None
 
 
 class Batcher:
@@ -483,6 +492,10 @@ class Batcher:
             "device_s_total": 0.0, "d2h_s_total": 0.0,
             "h2d_bound_s_total": 0.0, "stamps_late_total": 0,
             "h2d_bytes_total": 0, "d2h_bytes_total": 0,
+            # a ragged arena shipped by pages: the prefix's pages, and the
+            # pages and bytes whose copy started before the batch's t_launch
+            "h2d_early_bytes_total": 0, "h2d_pages_early_total": 0,
+            "h2d_pages_total": 0,
             "unpack_kernel_batches_total": 0,
             "starved_s_total": 0.0,
             # what the engine's model counts a call (a token decoder's
@@ -846,6 +859,7 @@ class Batcher:
                 lease.committed_at = time.monotonic()
                 b.n_pending -= 1
                 b.n_ready += 1
+                self._settle_locked(lease)
                 if lease.slab_held:
                     b.slab.drop_lease()  # writing is done
                     lease.slab_held = False
@@ -869,6 +883,7 @@ class Batcher:
                 b.n_holes += 1
                 self._dec_pending_locked(b)
                 self._holes_total += 1
+                self._settle_locked(lease)
                 try:
                     lease.future.set_exception(
                         RuntimeError("slot lease released"))
@@ -885,6 +900,21 @@ class Batcher:
                 self._holes_total += 1
                 self._cond.notify_all()
             # READY + dispatched: too late — the result is simply dropped.
+
+    def _settle_locked(self, lease: SlotLease):
+        """``lease`` left PENDING (committed, released or force-expired):
+        on an arena that ships by pages (engine.RaggedSlab.settle), hand the
+        pages this completes to the engine's shipper, which copies them to
+        the device while the batch is still open. Queues work, never waits."""
+        b = lease.builder
+        if not getattr(b.slab, "paged", False):
+            return
+        pages = b.slab.settle(lease.index)
+        if pages:
+            if b.seq is None:
+                self._batch_seq += 1
+                b.seq = self._batch_seq
+            self.engine.ship_pages(b.slab, pages, b.seq)
 
     def flush_bulk(self) -> None:
         """Seal every open bulk builder NOW. The job runner calls this
@@ -922,6 +952,7 @@ class Batcher:
                 self._dec_pending_locked(b)
                 self._lease_timeouts_total += 1
                 self._holes_total += 1
+                self._settle_locked(lease)
                 expired = True
                 try:
                     lease.future.set_exception(LeaseExpired(
@@ -968,16 +999,20 @@ class Batcher:
             # contract as _expire_locked's notify).
             self._cond.notify_all()
 
-    def _pick_replica_locked(self, mkey) -> int | None:
+    def _pick_replica_locked(self, mkey, bound: int | None = None) -> int | None:
         """Routing decision for one sealed interactive batch of ``mkey`` =
         (canvas-bucket key, bulk flag): among replicas with pipeline-depth
         headroom for this bucket, the least-loaded by the engine's
         in-flight dispatch count, round-robin cursor order breaking ties —
         so balanced load walks the chips cyclically and an unbalanced one
-        self-corrects. None = every replica is at depth."""
+        self-corrects. An arena ``bound`` to a replica by its early pages
+        goes there alone. None = every replica is at depth."""
         n = self._n_replicas
         if self._calls_full_locked():
             return None
+        if bound is not None:
+            return (bound if self._inflight_by_key.get((mkey, bound), 0)
+                    < self.pipeline_depth else None)
         if n == 1:
             return (0 if self._inflight_by_key.get((mkey, 0), 0)
                     < self.pipeline_depth else None)
@@ -989,11 +1024,14 @@ class Batcher:
         start = self._rr
         return min(cands, key=lambda r: (loads[r], (r - start) % n))
 
-    def _pick_bulk_replica_locked(self) -> int:
+    def _pick_bulk_replica_locked(self, bound: int | None = None) -> int:
         """Bulk batches are depth-gated globally (the gate below), not per
         (bucket, replica) — routing just spreads them least-loaded so a
-        job fills whichever chip group interactive traffic uses least."""
+        job fills whichever chip group interactive traffic uses least
+        (an arena ``bound`` by its early pages goes to its replica)."""
         n = self._n_replicas
+        if bound is not None:
+            return bound
         if n == 1:
             return 0
         loads = self.engine.replica_loads()
@@ -1157,6 +1195,8 @@ class Batcher:
                     # gated time.
                     self._bulk_gated_since = None
                 return ("discard", b)
+            # The replica an arena's early pages went to (None: unbound).
+            bound = getattr(b.slab, "replica", None)
             if b.bulk:
                 if not draining and not self._bulk_gate_open_locked(
                         now, tenant=b.tenant, rows=b.n_ready):
@@ -1166,7 +1206,7 @@ class Batcher:
                     # drain the gate lifts so stop() can flush.
                     self._bulk_gate_holds += 1
                     continue
-                replica = self._pick_bulk_replica_locked()
+                replica = self._pick_bulk_replica_locked(bound)
             else:
                 # Per-bucket pipeline gate: while this bucket already has
                 # pipeline_depth batches dispatched-and-unfetched, hold the
@@ -1176,12 +1216,12 @@ class Batcher:
                 # device is the bottleneck. The launch handoff itself never
                 # blocks — transfer of batch N+1 starts the moment its
                 # builder seals, it does NOT wait for batch N's fetch.
-                replica = self._pick_replica_locked((b.key, False))
+                replica = self._pick_replica_locked((b.key, False), bound)
                 if draining and replica is None:
                     # Drain must make progress even with every replica at
                     # depth: overshoot the gate round-robin rather than
                     # strand the builder (completions are still fetching).
-                    replica = self._rr % self._n_replicas
+                    replica = bound if bound is not None else self._rr % self._n_replicas
             if replica is not None:
                 self._closing.remove(b)
                 b.dispatched = True
@@ -1310,6 +1350,7 @@ class Batcher:
             "t_launched": None, "t_h2d_done": None, "t_dev_start": None,
             "t_ready": None, "t_fetch": None, "t_done": None, "late": (),
             "h2d_bytes": None, "d2h_bytes": None, "unpack_kernel": False,
+            "h2d_pages": None, "h2d_pages_early": None, "h2d_early_bytes": None,
         }
         with self._cond:
             self._dec_pending_locked(b, len(ready))
@@ -1317,8 +1358,10 @@ class Batcher:
             if b.bulk:
                 self._bulk_sealed_total += 1
                 self._bulk_images_total += len(ready)
-            self._batch_seq += 1
-            rec["seq"] = self._batch_seq
+            if b.seq is None:
+                self._batch_seq += 1
+                b.seq = self._batch_seq
+            rec["seq"] = b.seq
             self._timeline.append(rec)
             life = self._life
             life["batches_total"] += 1
@@ -1356,6 +1399,9 @@ class Batcher:
             self._sweep_h2d_bound_locked()
             life["h2d_bytes_total"] += rec["h2d_bytes"] or 0
             life["d2h_bytes_total"] += rec["d2h_bytes"] or 0
+            life["h2d_early_bytes_total"] += rec["h2d_early_bytes"] or 0
+            life["h2d_pages_early_total"] += rec["h2d_pages_early"] or 0
+            life["h2d_pages_total"] += rec["h2d_pages"] or 0
             life["unpack_kernel_batches_total"] += bool(rec["unpack_kernel"])
             # What the model itself counted in this call (a token decoder:
             # tokens, token slots, the router's picks, decode steps).

@@ -41,7 +41,7 @@ from ..ops import detection, quant
 from ..ops.image import make_preprocess_fn, pad_to_canvas, rgb_to_yuv420_canvas
 from ..parallel import mesh as mesh_lib
 from ..utils.config import ModelConfig, ServerConfig
-from ..utils.locks import named_lock
+from ..utils.locks import named_condition, named_lock
 from ..utils.tracing import canvas_side, stage
 from . import aotcache
 from .placement import parse_placement
@@ -57,6 +57,33 @@ _NO_LOCK = contextlib.nullcontext()
 # never serve a newer build's traffic. The config-derived key components
 # cover operator-visible knobs; this covers the code itself.
 SERVE_FN_VERSION = 2
+
+# Bytes of one page of a ragged arena on a one-device replica: the unit in
+# which an open batch's arena is copied to the device as its rows commit
+# (RaggedSlab.settle). A multiple of 4, so that a page ships as the uint32
+# words the unpack kernel reads. The tail a launch still copies is mostly
+# the rows quantization's padding past the last image (up to bucket/8
+# canvas rows), not the page, so a smaller page saves little there and
+# costs a copy call and an operand of the unpack program a page.
+PAGE_BYTES = 8 << 20
+
+# A page's way to the device: not ready, handed to the replica's shipper,
+# being put, on the device.
+_PAGE_IDLE, _PAGE_QUEUED, _PAGE_SHIPPING, _PAGE_SHIPPED = range(4)
+
+
+def _join_pages(pages):
+    """An arena prefix from its pages, on the device (inside the unpack
+    program): one read and one write of the prefix."""
+    return pages[0] if len(pages) == 1 else jnp.concatenate(pages)
+
+
+def page_sizes(nbytes: int) -> list[int]:
+    """The pages an arena prefix of ``nbytes`` travels as, in arena order:
+    whole pages, then the rest. One list per prefix length, so each
+    compiled unpack variant keeps exactly one shape."""
+    n = -(-nbytes // PAGE_BYTES)
+    return [PAGE_BYTES] * (n - 1) + [nbytes - (n - 1) * PAGE_BYTES]
 
 
 class StagingSlab:
@@ -208,15 +235,27 @@ class RaggedSlab:
     lease dies before commit keeps valid=0: unpack emits a zero canvas
     with hw=(1,1) — the classic hole semantics, one pixel the output
     consumers never observe (every result is sliced to the real batch).
-    """
+
+    A ``paged`` arena (an engine whose replicas are one device each) is
+    also cut into pages of :data:`PAGE_BYTES`. A page is ready once every
+    byte of it is allocated and every slot that overlaps it is settled
+    (committed, a hole, or force-expired: an expired row's bytes are
+    don't-care, as its meta row says); :meth:`settle` names the pages a
+    slot's settling makes ready, the replica's shipper copies them to the
+    device while the batch is still open (:meth:`claim`, :meth:`landed`),
+    and the launch takes what has landed and copies the rest
+    (:meth:`take_pages`). ``replica`` is the replica the arena was bound to
+    when its first page was handed over (None before that)."""
 
     is_ragged = True
 
     __slots__ = ("key", "bucket", "canvas_s", "row_bytes", "arena_bytes",
                  "buf", "meta", "used", "slots", "total_bytes",
-                 "_lease_lock", "_leases", "_fetch_done", "_idle_cb")
+                 "_lease_lock", "_leases", "_fetch_done", "_idle_cb",
+                 "paged", "replica", "_gen", "_closed", "_ends",
+                 "_unsettled", "_page_state", "_pages_d", "_page_t")
 
-    def __init__(self, canvas_s: int, bucket: int):
+    def __init__(self, canvas_s: int, bucket: int, paged: bool = False):
         # key[0] = ("ragged", s) is a 2-tuple, so utils.tracing.canvas_side
         # reads the canvas bucket out of it exactly as it does for classic
         # (s, s, 3) row-shape keys — economics keying needs no branch, and
@@ -232,10 +271,25 @@ class RaggedSlab:
         self.used = 0
         self.slots = 0
         self.total_bytes = self.buf.nbytes + self.meta.nbytes
-        self._lease_lock = named_lock("slab.lease_lock")
+        # A condition: the launch waits on it for pages being put.
+        self._lease_lock = named_condition("slab.lease_lock")
         self._leases = 0
         self._fetch_done = True
         self._idle_cb = None
+        self.paged = bool(paged)
+        self._gen = 0
+        self._reset_pages()
+
+    def _reset_pages(self):
+        """A new cycle's page table (under the lease lock, or unshared)."""
+        n = -(-self.arena_bytes // PAGE_BYTES) if self.paged else 0
+        self.replica = None
+        self._closed = False
+        self._ends: list[int] = []  # slot i's arena bytes end at _ends[i]
+        self._unsettled = [0] * n   # slots overlapping the page, unsettled
+        self._page_state = [_PAGE_IDLE] * n
+        self._pages_d: list = [None] * n
+        self._page_t = [0.0] * n    # when the page's copy started
 
     # ------------------------------------------------------------- slot API
 
@@ -251,8 +305,74 @@ class RaggedSlab:
         self.slots = i + 1
         self.used = off + need
         self.meta[i, 0] = off
+        if self.paged:
+            self._ends.append(off + need)
+            for p in range(off // PAGE_BYTES, (off + need - 1) // PAGE_BYTES + 1):
+                self._unsettled[p] += 1
         # h/w/valid stay 0 until write_hw: an abandoned lease is a hole.
         return i, self.buf[off : off + need]
+
+    def settle(self, i: int) -> list[int]:
+        """Slot ``i``'s lessee will write no more that counts: committed,
+        released or force-expired (once a slot, under the batcher's
+        condition, as :meth:`alloc`). Returns the pages this makes ready,
+        marked handed over: every byte allocated, every overlapping slot
+        settled. A ready page is whole and lies below ``used``, so no later
+        slot can overlap it: each page is handed over at most once."""
+        if not self.paged:
+            return []
+        off, end = int(self.meta[i, 0]), self._ends[i]
+        ready = []
+        for p in range(off // PAGE_BYTES, (end - 1) // PAGE_BYTES + 1):
+            self._unsettled[p] -= 1
+            if not self._unsettled[p] and (p + 1) * PAGE_BYTES <= self.used:
+                ready.append(p)
+        if ready:
+            with self._lease_lock:
+                if self._closed:
+                    return []
+                for p in ready:
+                    self._page_state[p] = _PAGE_QUEUED
+        return ready
+
+    def claim(self, gen: int, pages) -> list[tuple[int, np.ndarray]]:
+        """The shipper's half: of ``pages`` (handed over in cycle ``gen``),
+        those still waiting, marked as being put, with their bytes."""
+        with self._lease_lock:
+            if gen != self._gen or self._closed:
+                return []
+            mine = [p for p in pages if self._page_state[p] == _PAGE_QUEUED]
+            for p in mine:
+                self._page_state[p] = _PAGE_SHIPPING
+        return [(p, self.buf[p * PAGE_BYTES:(p + 1) * PAGE_BYTES]) for p in mine]
+
+    def landed(self, gen: int, pages, arrays, t0: float) -> None:
+        """The shipper put ``pages`` (``arrays``, their device copies; None
+        if the put failed, and the launch copies them) from ``t0`` on."""
+        with self._lease_lock:
+            if gen == self._gen:
+                for k, p in enumerate(pages):
+                    if arrays is None:
+                        self._page_state[p] = _PAGE_IDLE
+                    else:
+                        self._page_state[p] = _PAGE_SHIPPED
+                        self._pages_d[p] = arrays[k]
+                        self._page_t[p] = t0
+            self._lease_lock.notify_all()
+
+    def take_pages(self, n: int) -> dict[int, tuple]:
+        """At launch (or release): hand nothing more to the shipper, wait
+        for the pages it is putting, and take the first ``n`` pages' device
+        copies, ``{page: (array, when its copy started)}``. The arena's
+        device pages are the batch's from here: none stays on the slab."""
+        with self._lease_lock:
+            self._closed = True
+            while _PAGE_SHIPPING in self._page_state:
+                self._lease_lock.wait()
+            got = {p: (self._pages_d[p], self._page_t[p]) for p in range(n)
+                   if self._page_state[p] == _PAGE_SHIPPED}
+            self._pages_d = [None] * len(self._pages_d)
+        return got
 
     def write_hw(self, i: int, hw: tuple[int, int]):
         """Commit slot ``i``: stamp its decoded (h, w) and mark it valid —
@@ -281,11 +401,15 @@ class RaggedSlab:
     def arm(self, idle_cb):
         """Start one cycle (same contract as :meth:`StagingSlab.arm`) and
         reset the arena: cursors to zero, meta cleared — stale offsets from
-        the previous batch must never alias a new batch's holes."""
+        the previous batch must never alias a new batch's holes. A new
+        cycle's page table too: a shipper still holding the last cycle's
+        pages finds another ``_gen`` and keeps none."""
         with self._lease_lock:
             self._leases = 0
             self._fetch_done = False
             self._idle_cb = idle_cb
+            self._gen += 1
+            self._reset_pages()
         self.used = 0
         self.slots = 0
         self.meta[:] = 0
@@ -461,6 +585,57 @@ class FlightLog:
                 self._copied_locked(f, late)
 
 
+class PageShipper:
+    """The early copies of one replica's ragged arenas: a thread of its own
+    puts the pages that filled while their batch was open
+    (:meth:`RaggedSlab.settle`), so that neither a decode worker nor the
+    batcher's lock ever waits for a copy. Each put lies under the profiler
+    annotation ``twd.h2d_early`` (stats ``seq``, ``pages``, ``bytes``).
+
+    Pages go only to a replica of one device, where there is no XLA:CPU
+    dispatch guard to take (``_Replica.serialize`` needs several)."""
+
+    def __init__(self, sharding, name: str):
+        self._sharding = sharding
+        self._q: queue.Queue = queue.Queue()
+        self._thread = threading.Thread(target=self._run, name=name, daemon=True)
+        self._thread.start()
+
+    def put(self, slab: RaggedSlab, pages, seq, words: bool) -> None:
+        """Queue ``pages`` of ``slab`` (its batch ``seq``; as uint32 words
+        where the unpack is the kernel). Never blocks."""
+        self._q.put((slab, slab._gen, tuple(pages), seq, words))
+
+    def close(self) -> None:
+        self._q.put(None)
+        self._thread.join(timeout=5)
+
+    def _run(self):
+        while True:
+            item = self._q.get()
+            if item is None:
+                return
+            self._ship(*item)
+            del item  # the slab goes with it
+
+    def _ship(self, slab: RaggedSlab, gen: int, pages, seq, words: bool):
+        claimed = slab.claim(gen, pages)
+        if not claimed:
+            return
+        mine = [p for p, _ in claimed]
+        bufs = [v.view(np.uint32) if words else v for _, v in claimed]
+        t0 = time.monotonic()
+        arrays = None
+        try:
+            with stage(None, "h2d_early", f"c{slab.canvas_s}", seq=seq,
+                       pages=len(mine), bytes=len(mine) * PAGE_BYTES):
+                arrays = jax.device_put(bufs, self._sharding)
+        except Exception:
+            log.exception("early copy of %d page(s) failed; the launch "
+                          "copies them", len(mine))
+        slab.landed(gen, mine, arrays, t0)
+
+
 class _DeviceBatch:
     """Slab-shaped handle for :meth:`InferenceEngine.dispatch_device` —
     a DEVICE-RESIDENT batch (DAG glue output) that never had a host
@@ -494,7 +669,7 @@ class _Replica:
                  "replicated", "dispatch_guard", "serialize",
                  "dispatches_total", "dispatches_inflight",
                  "slab_bytes_inflight", "busy_s", "econ", "flights",
-                 "param_device_ids", "param_bytes")
+                 "shipper", "param_device_ids", "param_bytes")
 
     def __init__(self, index: int, mesh):
         self.index = index
@@ -541,6 +716,9 @@ class _Replica:
         # This stream's calls in enqueue order, stamped where each phase of
         # their flight ends (InferenceEngine._flights makes it on first use).
         self.flights: FlightLog | None = None
+        # Puts ragged arenas' pages while their batches are open
+        # (InferenceEngine._shipper makes it on first use).
+        self.shipper: PageShipper | None = None
 
 
 class InferenceEngine:
@@ -573,6 +751,8 @@ class InferenceEngine:
     # (_calls_that_fit); None where that is unknown, and for no ceiling but
     # the batcher's per-bucket pipeline depth.
     max_calls_in_flight: int | None = None
+    # Whether ragged arenas ship by pages (set in __init__).
+    _paged = False
 
     def __init__(self, cfg: ServerConfig, mesh=None):
         # Ragged-wire gating: tight-arena packing exists only for the rgb
@@ -820,6 +1000,12 @@ class InferenceEngine:
         # the compile happens at first CALL, outside any lock.
         self._ragged_fns: dict[tuple, tuple] = {}
         self._ragged_lock = named_lock("engine.ragged_lock")
+        # Arenas ship by pages as their rows commit (RaggedSlab.settle)
+        # where every replica is one device, the case the unpack kernel
+        # asks for too; a replica over several devices copies an arena's
+        # prefix in one put at launch.
+        self._paged = self.ragged and all(
+            int(rep.mesh.devices.size) == 1 for rep in self._replicas)
 
         # AOT executable cache (serving/aotcache.py, ISSUE 18): warmup
         # deserializes previously compiled executables from disk instead
@@ -1317,7 +1503,7 @@ class InferenceEngine:
             )
         return self._acquire(
             (("ragged", int(canvas_s)), bucket),
-            lambda: RaggedSlab(canvas_s, bucket))
+            lambda: RaggedSlab(canvas_s, bucket, paged=self._paged))
 
     def _acquire(self, key: tuple, make):
         """Take a pooled slab of ``key`` or ``make()`` one, count it as out
@@ -1350,7 +1536,10 @@ class InferenceEngine:
     def release_staging(self, slab: StagingSlab):
         """Recycle a slab that was acquired but never dispatched (e.g. a
         batch builder sealed with only holes). Routed through the slab's
-        lease refcount, so stray lessees still hold it back."""
+        lease refcount, so stray lessees still hold it back. Pages it
+        shipped early are dropped."""
+        if getattr(slab, "paged", False):
+            slab.take_pages(0)
         slab.finish_fetch()
 
     def _release_staging(self, slab: StagingSlab):
@@ -1551,6 +1740,35 @@ class InferenceEngine:
             s.note("replica", r)
         return outs, (n, slab, r, flight, bucket)
 
+    def ship_pages(self, slab: RaggedSlab, pages, seq: int | None = None):
+        """Hand ``pages`` of an open batch's arena (ready by
+        :meth:`RaggedSlab.settle`) to the shipper of the replica the arena
+        is bound to; the first pages bind it (:meth:`route_replica`), and
+        :meth:`dispatch_ragged` launches it there. ``seq`` is the batch's,
+        for the annotation. Called under the batcher's condition: it only
+        queues work."""
+        from ..ops.image import unpack_kernel_applies
+
+        if slab.replica is None:
+            slab.replica = self.route_replica()
+        rep = self._replicas[slab.replica]
+        self._shipper(rep).put(slab, pages, seq,
+                               unpack_kernel_applies(slab.canvas_s, 1))
+
+    def _arena_pages(self, nbytes: int) -> list[int]:
+        """The sizes an arena prefix of ``nbytes`` ships in, in arena order:
+        its pages (:func:`page_sizes`) where arenas ship by pages, else the
+        prefix whole."""
+        return page_sizes(nbytes) if self._paged else [nbytes]
+
+    def _shipper(self, rep: _Replica) -> PageShipper:
+        if rep.shipper is None:
+            with self._route_lock:
+                if rep.shipper is None:
+                    rep.shipper = PageShipper(rep.data_sharding,
+                                              f"page-ship-{rep.index}")
+        return rep.shipper
+
     def _flights(self, rep: _Replica) -> FlightLog:
         if rep.flights is None:
             with self._route_lock:
@@ -1606,16 +1824,19 @@ class InferenceEngine:
         """The compiled device-side unpack stage for one (replica, canvas
         bucket, batch bucket, shipped-rows) shape: flat byte arena + meta →
         (canvases, hws) exactly as the host-padded wire would have staged
-        them, sharded for the replica's serve fn. Returns (executable,
-        arena input sharding, whether the arena ships as uint32 words for
-        the Mosaic kernel — ops.image.unpack_kernel_applies: a TPU, a
-        one-device mesh, a canvas of whole lane rows; bytes and the XLA
-        gather otherwise). AOT-compiled on first use (deserialize from
-        the executable cache when one is configured, else lower+compile,
-        with write-back) — compilation happens OUTSIDE the ragged lock,
-        which only memoizes the result. Warmup covers every quantized
-        rows variant; rows_shipped bounds them at ~8 per (canvas, bucket)
-        pair. ``counts`` (warmup's attribution dict) gets "compiled" /
+        them, sharded for the replica's serve fn. The program takes the
+        prefix as the tuple of its pages (:meth:`_arena_pages`: one, where
+        arenas do not ship by pages), in arena order, and joins them on the
+        device first. Returns (executable, arena input
+        sharding, whether the arena ships as uint32 words for the Mosaic
+        kernel — ops.image.unpack_kernel_applies: a TPU, a one-device mesh,
+        a canvas of whole lane rows; bytes and the XLA gather otherwise).
+        AOT-compiled on first use (deserialize from the executable cache
+        when one is configured, else lower+compile, with write-back) —
+        compilation happens OUTSIDE the ragged lock, which only memoizes
+        the result. Warmup covers every quantized rows variant;
+        rows_shipped bounds them at ~8 per (canvas, bucket) pair.
+        ``counts`` (warmup's attribution dict) gets "compiled" /
         "deserialized" bumped for a build."""
         key = (rep.index, int(canvas_s), bucket, rows)
         with self._ragged_lock:
@@ -1638,7 +1859,8 @@ class InferenceEngine:
             rep, "unpack", canvas_s, bucket, rows=rows,
             extra={"unpack_version": RAGGED_UNPACK_VERSION,
                    "arena_sharded": nbytes % ndev == 0,
-                   "arena_words": kernel},
+                   "arena_words": kernel,
+                   "page_bytes": PAGE_BYTES if self._paged else 0},
         )
         aot = self._aot_for(rep)
         exe = (aot.load(akey, rep.mesh.devices.flat)
@@ -1647,14 +1869,17 @@ class InferenceEngine:
             if counts is not None:
                 counts["deserialized"] = counts.get("deserialized", 0) + 1
         else:
+            arena = tuple(
+                jax.ShapeDtypeStruct((n // 4,), jnp.uint32) if kernel
+                else jax.ShapeDtypeStruct((n,), jnp.uint8)
+                for n in self._arena_pages(nbytes))
             fn = jax.jit(
-                lambda arena, meta: unpack_ragged(arena, meta, int(canvas_s)),
-                in_shardings=(arena_sh, rep.replicated),
+                lambda pages, meta: unpack_ragged(
+                    _join_pages(pages), meta, int(canvas_s)),
+                in_shardings=(tuple(arena_sh for _ in arena), rep.replicated),
                 out_shardings=(rep.data_sharding, rep.data_sharding),
             )
             t0 = time.perf_counter()
-            arena = (jax.ShapeDtypeStruct((nbytes // 4,), jnp.uint32) if kernel
-                     else jax.ShapeDtypeStruct((nbytes,), jnp.uint8))
             exe = fn.lower(
                 arena, jax.ShapeDtypeStruct((bucket, 4), jnp.int32),
             ).compile()
@@ -1671,14 +1896,21 @@ class InferenceEngine:
                         replica: int | None = None, rec: dict | None = None):
         """Dispatch a filled ragged arena (async) — the tight-wire sibling
         of :meth:`dispatch_staged`. Ships the arena's used prefix (see
-        :meth:`RaggedSlab.rows_shipped`) plus the meta table, enqueues the
+        :meth:`RaggedSlab.rows_shipped`; by pages, those not on the device
+        yet, where arenas ship by pages) plus the meta table, enqueues the
         jitted device-side unpack (annotation ``twd.unpack_enqueue``), then
         the replica's serve fn; the handle feeds the SAME
-        :meth:`fetch_outputs`. ``spans`` and ``rec`` as in
-        :meth:`dispatch_staged`, with ``t_pre`` (the unpack enqueued) and
-        ``unpack_kernel`` (the unpack ran the Mosaic kernel) besides."""
+        :meth:`fetch_outputs`. An arena bound to a replica by its first
+        early page launches there, whatever ``replica`` says. ``spans`` and
+        ``rec`` as in :meth:`dispatch_staged`, with ``t_pre`` (the unpack
+        enqueued), ``unpack_kernel`` (the unpack ran the Mosaic kernel),
+        ``h2d_pages`` (the prefix's pages), ``h2d_pages_early`` and
+        ``h2d_early_bytes`` (those whose copy started before the batch's
+        ``t_launch``) besides; ``h2d_bytes`` stays the whole prefix plus
+        the meta table."""
         bucket = self.pick_batch_bucket(n)
-        r = self.route_replica() if replica is None else int(replica)
+        r = (slab.replica if slab.replica is not None
+             else self.route_replica() if replica is None else int(replica))
         rep = self._replicas[r]
         with self._route_lock:
             rep.dispatches_total += 1
@@ -1686,7 +1918,7 @@ class InferenceEngine:
             rep.slab_bytes_inflight += slab.total_bytes
         guard = rep.dispatch_guard if rep.serialize else _NO_LOCK
         try:
-            outs, t_put, t_pre, nbytes, kernel, flight = \
+            outs, t_put, t_pre, nbytes, kernel, flight, (early, pages) = \
                 self._dispatch_ragged_on(rep, guard, slab, bucket, rec)
         except BaseException:
             # Same live-accounting rollback as dispatch_staged; the totals
@@ -1698,38 +1930,53 @@ class InferenceEngine:
         if rec is not None:
             rec["t_put"], rec["t_pre"], rec["h2d_bytes"] = t_put, t_pre, nbytes
             rec["unpack_kernel"] = kernel
+            rec["h2d_pages"], rec["h2d_pages_early"] = pages, early
+            rec["h2d_early_bytes"] = early * PAGE_BYTES
         for s in spans:
             s.note("replica", r)
         return outs, (n, slab, r, flight, bucket)
 
     def _dispatch_ragged_on(self, rep: _Replica, guard, slab: RaggedSlab,
                             bucket: int, rec: dict | None):
-        """Guarded device work of one ragged dispatch: ship arena prefix +
-        meta, enqueue unpack, enqueue serve, start the async D2H copy, each
-        under its profiler annotation. Returns (outputs, when the second
+        """Guarded device work of one ragged dispatch: ship arena prefix
+        (the pages not on the device yet, where it ships by pages) + meta,
+        enqueue unpack, enqueue serve, start the async D2H copy, each under
+        its profiler annotation. Returns (outputs, when the second
         ``device_put`` returned, when the unpack was enqueued, bytes
         shipped, whether the unpack is the kernel, the call's
-        :class:`Flight`)."""
+        :class:`Flight`, (pages whose copy started before ``t_launch``,
+        the prefix's pages; (0, 0) unpaged))."""
         rows = slab.rows_shipped(bucket)
         unpack, arena_sh, kernel = self._ragged_unpack(
             rep, slab.canvas_s, bucket, rows)
         serve = self._serve_exe_for(rep, slab.key[0], bucket)
-        arena = slab.buf[: rows * slab.row_bytes]
+        prefix = rows * slab.row_bytes
+        sizes = self._arena_pages(prefix)
+        # Only whole pages of the prefix can have gone early.
+        early = slab.take_pages(prefix // PAGE_BYTES) if slab.paged else {}
+        bufs = [slab.buf[p * PAGE_BYTES:p * PAGE_BYTES + n]
+                for p, n in enumerate(sizes) if p not in early]
         if kernel:
-            arena = arena.view(np.uint32)  # the same bytes on the wire
+            bufs = [b.view(np.uint32) for b in bufs]  # the same bytes on the wire
+        t_launch = (rec or {}).get("t_launch") or time.monotonic()
+        counted = (sum(t < t_launch for _, t in early.values()),
+                   len(sizes) if slab.paged else 0)
         meta = slab.meta if bucket == slab.bucket else slab.meta[:bucket]
         label = f"c{slab.canvas_s} b{bucket}"
         ids = _batch_ids(rec)
         flights = self._flights(rep)
-        nbytes = arena.nbytes + meta.nbytes
+        nbytes = prefix + meta.nbytes
         with guard:
             flight = flights.start(rec, label, nbytes)
             with stage(None, "h2d", label, **ids) as put:
                 # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on — the guarded region is exactly the device enqueue)
-                arena_d = jax.device_put(arena, arena_sh)
+                put_d = jax.device_put(bufs, arena_sh)
                 # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on)
                 meta_d = jax.device_put(meta, rep.replicated)
-            flights.copying(flight, (arena_d, meta_d))
+            tail = iter(put_d)
+            arena_d = tuple(early[p][0] if p in early else next(tail)
+                            for p in range(len(sizes)))
+            flights.copying(flight, (*arena_d, meta_d))
             with stage(None, "unpack_enqueue", label, **ids) as pre:
                 canvases_d, hws_d = unpack(arena_d, meta_d)
             with stage(None, "serve_enqueue", label, **ids):
@@ -1738,7 +1985,7 @@ class InferenceEngine:
                 for leaf in jax.tree.leaves(outs):
                     leaf.copy_to_host_async()
         flights.enqueued(flight)
-        return outs, put.t1, pre.t1, nbytes, kernel, flight
+        return outs, put.t1, pre.t1, nbytes, kernel, flight, counted
 
     def dispatch_batch(self, canvases: np.ndarray, hws: np.ndarray,
                        replica: int | None = None):
@@ -2017,17 +2264,23 @@ class InferenceEngine:
             meta0[:, 1:3] = 1
             guard = rep.dispatch_guard if rep.serialize else _NO_LOCK
             q = max(1, b // 8)
+            zeros: dict[int, jax.Array] = {}  # one device page a size
             for rows in range(q, b + 1, q):
                 unpack, arena_sh, kernel = self._ragged_unpack(rep, s, b, rows)
-                arena0 = np.zeros(rows * s * s * 3, np.uint8)
-                if kernel:
-                    arena0 = arena0.view(np.uint32)
+                sizes = self._arena_pages(rows * s * s * 3)
+                if not self._paged:
+                    zeros.clear()  # a whole arena a variant: one at a time
                 # Same XLA:CPU collective-rendezvous discipline as the
                 # request path: the unpack is a multi-device dispatch, and
                 # warmup now executes on several pool threads at once.
                 with guard:
-                    # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on — concurrent warmup threads must not interleave multi-device dispatches into one replica)
-                    arena_d = jax.device_put(arena0, arena_sh)
+                    for n in sizes:
+                        if n not in zeros:
+                            zero = np.zeros(n, np.uint8)
+                            # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on — concurrent warmup threads must not interleave multi-device dispatches into one replica)
+                            zeros[n] = jax.device_put(
+                                zero.view(np.uint32) if kernel else zero, arena_sh)
+                    arena_d = tuple(zeros[n] for n in sizes)
                     # twdlint: disable=no-blocking-under-lock(same per-replica XLA:CPU rendezvous serialization as _dispatch_on)
                     meta_d = jax.device_put(meta0, rep.replicated)
                     out = unpack(arena_d, meta_d)
@@ -2180,6 +2433,8 @@ class InferenceEngine:
             rep.exe.clear()
             if rep.flights is not None:
                 rep.flights.close()
+            if rep.shipper is not None:
+                rep.shipper.close()
         self._params = None
         self._serve = None
         self._serve_raw = None

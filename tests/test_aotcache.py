@@ -366,7 +366,8 @@ def test_kernel_unpack_roundtrips_and_version_2_entry_is_a_miss(tmp_path, monkey
     def unpack_with(eng):
         exe, _, kernel = eng._ragged_unpack(eng._replicas[0], 512, 2, 2)
         assert kernel
-        canvases, hws = exe(jnp.asarray(arena.view(np.uint32)), jnp.asarray(meta))
+        # One-device replicas take the arena as its pages: 1.5 MiB is one.
+        canvases, hws = exe((jnp.asarray(arena.view(np.uint32)),), jnp.asarray(meta))
         return np.asarray(canvases), np.asarray(hws)
 
     # An entry of the old program's version first: same key but the number.

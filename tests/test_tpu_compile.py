@@ -117,6 +117,27 @@ def test_ragged_unpack_compiles_for_v5e(v5e, canvas, bucket, rows):
     assert m.output_size_in_bytes < bucket * canvas * canvas * 3 + (1 << 20)
 
 
+# The same unpack where the arena arrives as its pages (a one-device replica
+# ships them as its rows commit): the pages are joined on the device, one
+# copy of the prefix in the temporaries, and the kernel is unchanged.
+@pytest.mark.parametrize("canvas,bucket,rows", [(1536, 4, 3), (4096, 32, 32), (4096, 32, 20)])
+def test_paged_ragged_unpack_compiles_for_v5e(v5e, canvas, bucket, rows):
+    from tensorflow_web_deploy_tpu.serving.engine import _join_pages, page_sizes
+
+    nbytes = rows * canvas * canvas * 3
+    pages = tuple(jax.ShapeDtypeStruct((n // 4,), jnp.uint32, sharding=v5e)
+                  for n in page_sizes(nbytes))
+    meta = jax.ShapeDtypeStruct((bucket, 4), jnp.int32, sharding=v5e)
+    compiled = jax.jit(
+        lambda p, m: unpack_ragged(_join_pages(p), m, canvas), out_shardings=(v5e, v5e)
+    ).lower(pages, meta).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1 and "while(" not in text
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < nbytes + (4 << 20)  # the joined prefix, once
+    assert m.argument_size_in_bytes < nbytes + (1 << 20)
+
+
 # The token decoder's kernels at the published widths (64 heads, keys of 128 +
 # 64 rotary, values of 128; experts 6144 x 2048) and at the benchmark's three
 # length buckets with the most rows a call holds of each.
